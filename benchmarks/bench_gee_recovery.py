@@ -14,6 +14,11 @@ Three scenarios, one JSON (``BENCH_serve.json``):
   scaling measured is replication, not intra-op threads), and measures
   aggregate read QPS at each replica count.  ``--min-scaling`` gates the
   2-replica speedup (CI uses 1.6; pass 0 on single-core boxes).
+
+Every child process is pinned to the CPU (several processes cannot share
+one accelerator), and each replica worker reports the platform it ran on:
+the rows and the JSON name it, so these numbers are never read as device
+numbers.
 * **load shedding** -- drives an in-process ``ReplicaRouter`` over
   bounded-queue services past saturation and checks every rejected read is
   *counted* (``shed + served == attempted``), never silently dropped.
@@ -137,6 +142,7 @@ def bench_kill_and_recover(args) -> dict:
     neighbor_recall = recall_at_k(ids_r, sc_r, ids_b, sc_b)
 
     row = {
+        "stream_platform": env["JAX_PLATFORMS"],
         "killed_mid_stream": killed,
         "watermark_ref": int(ref.inc.applied_seq),
         "watermark_recovered": int(rec.inc.applied_seq),
@@ -167,6 +173,8 @@ def _worker_main(args) -> None:
     """Subprocess body: recover a replica, handshake, serve reads for a
     fixed duration, report the count."""
     t0 = time.perf_counter()
+    import jax
+
     from repro.serve.replica import GEEReplica
 
     replica = GEEReplica.from_directory(
@@ -178,7 +186,8 @@ def _worker_main(args) -> None:
     # warm the jitted search path before the measured window
     replica.service.submit_rows(rows[0], args.k)
     replica.service.flush()
-    print(f"READY {(time.perf_counter() - t0) * 1e3:.1f}", flush=True)
+    print(f"READY {(time.perf_counter() - t0) * 1e3:.1f} "
+          f"{jax.default_backend()}", flush=True)
     if sys.stdin.readline().strip() != "GO":
         return
     served, i = 0, 0
@@ -203,13 +212,14 @@ def _measure_replicas(args, snapshot_dir: str, n_replicas: int) -> dict:
                               env=env, stdin=subprocess.PIPE,
                               stdout=subprocess.PIPE, text=True, bufsize=1)
              for i in range(n_replicas)]
-    recover_ms = []
+    recover_ms, platforms = [], set()
     try:
         for p in procs:                      # barrier: all replicas hydrated
             line = p.stdout.readline().split()
             if not line or line[0] != "READY":
                 raise SystemExit(f"replica worker failed to start: {line}")
             recover_ms.append(float(line[1]))
+            platforms.add(line[2])
         t0 = time.perf_counter()
         for p in procs:
             p.stdin.write("GO\n")
@@ -225,8 +235,8 @@ def _measure_replicas(args, snapshot_dir: str, n_replicas: int) -> dict:
         for p in procs:
             p.kill()
             p.wait()
-    return {"replicas": n_replicas, "served": served,
-            "qps": served / max(elapsed, 1e-9),
+    return {"replicas": n_replicas, "platform": ",".join(sorted(platforms)),
+            "served": served, "qps": served / max(elapsed, 1e-9),
             "recover_ms_mean": float(np.mean(recover_ms))}
 
 
@@ -236,7 +246,8 @@ def bench_saturation(args, snapshot_dir: str) -> dict:
     base = rows[0]["qps"]
     for r in rows:
         r["scaling_vs_1"] = r["qps"] / max(base, 1e-9)
-        print(f"replicas={r['replicas']}  qps={r['qps']:10,.0f}  "
+        print(f"replicas={r['replicas']} ({r['platform']})  "
+              f"qps={r['qps']:10,.0f}  "
               f"scaling={r['scaling_vs_1']:.2f}x  "
               f"recover={r['recover_ms_mean']:.0f} ms")
     two = next((r for r in rows if r["replicas"] == 2), None)

@@ -38,6 +38,7 @@ from repro.core.fold import (axis_size as _axis_size, combine_partials,
 from repro.core.gee import GEEOptions, class_weight_inv
 from repro.graph.containers import EdgeList
 from repro.graph.partition import shard_edges, shard_edges_to_ell
+from repro.kernels.platform import interpret_mode
 
 
 def _local_degrees(weight, src, num_nodes_pad: int, diag_aug: bool,
@@ -155,7 +156,7 @@ def gee_distributed(edges, labels, num_classes: int,
                 "pre_sharded edge arrays cannot feed local_backend='pallas' "
                 "(the ELL planes are packed from the unsharded edge list)")
         cols, vals = shard_edges_to_ell(edges, p, num_rows=n_pad)
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
         return _gee_distributed_pallas_jit(cols, vals, labels, num_classes,
                                            opts, mesh, tuple(axes), interpret)
     if local_backend != "segment_sum":

@@ -17,9 +17,9 @@ Conventions (shared by every backend, tested cross-backend to <= 1e-5):
 * Degrees invert the same way: ``d > 0 -> rsqrt(max(d, EPS_NORM))``,
   0 otherwise.
 * ``impl="auto"`` routes the row normalization to the Pallas
-  ``row_norm`` kernel only where it is profitable (a real TPU); off-TPU
-  the kernel would run in interpret mode, strictly slower than the
-  fused jnp form.
+  ``row_norm`` kernel on a TPU; on CPU the kernel would run in interpret
+  mode, strictly slower than the fused jnp form
+  (:func:`repro.kernels.platform.interpret_mode` decides).
 """
 
 from __future__ import annotations
@@ -46,7 +46,9 @@ ROW_NORM_IMPLS = ("auto", "jnp", "pallas")
 
 def _resolve_impl(impl: str) -> str:
     if impl == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "jnp"
+        from repro.kernels.platform import interpret_mode
+
+        return "jnp" if interpret_mode() else "pallas"
     if impl not in ("jnp", "pallas"):
         raise ValueError(f"unknown impl {impl!r}; pick one of "
                          f"{ROW_NORM_IMPLS}")
@@ -67,15 +69,16 @@ def row_l2_normalize(z: jax.Array, *, impl: str = "auto",
                      interpret: bool | None = None) -> jax.Array:
     """Row-L2-normalize [N, K]; zero rows stay zero.
 
-    ``impl="auto"`` picks the Pallas ``row_norm`` kernel when profitable
-    (TPU), the fused jnp form everywhere else.  ``interpret`` is
-    forwarded to the kernel (None = interpret off-TPU).
+    ``impl="auto"`` picks the Pallas ``row_norm`` kernel on TPU, the
+    fused jnp form on CPU.  ``interpret`` is forwarded to the kernel
+    (None = :func:`repro.kernels.platform.interpret_mode`).
     """
     if _resolve_impl(impl) == "pallas":
+        from repro.kernels.platform import interpret_mode
         from repro.kernels.row_norm import row_norm  # deferred: no cycle
 
         if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+            interpret = interpret_mode()
         return row_norm(z, eps=EPS_NORM, interpret=interpret)
     return row_l2_normalize_jnp(z)
 

@@ -45,12 +45,13 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.epilogue import apply_epilogue, finalize, inv_sqrt_degrees
 from repro.core.gee import GEEOptions, class_weight_inv
 from repro.distributed.compat import shard_map, shard_map_nocheck
 from repro.graph.prefetch import PlaneWindow, prefetch_windows
+from repro.kernels.platform import interpret_mode
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 
@@ -330,6 +331,14 @@ def _combine_sharded(z_parts, labels, winv, dinv, *, mesh: Mesh,
                      out_specs=P(axes, None))(z_parts, labels, winv, dinv)
 
 
+def default_mesh() -> Mesh:
+    """1-D ``("data",)`` mesh over every device of this process, in the
+    device order ``jax.make_mesh`` picks for the interconnect."""
+    return jax.make_mesh((jax.local_device_count(),), ("data",),
+                         axis_types=(AxisType.Auto,),
+                         devices=jax.local_devices())
+
+
 def _window_plane(window, num_shards: int, num_rows: int,
                   undirected: bool):
     """Host-side per-window ELL pack for the pallas local backend.
@@ -380,7 +389,7 @@ def gee_streamed_sharded(source, labels, num_classes: int,
     contract (<= 1e-5 max-abs under every option setting).
 
     ``mesh=None`` builds a 1-D ``("data",)`` mesh over all local
-    devices.  ``local_backend`` is ``"segment_sum"`` (default) or
+    devices (:func:`default_mesh`).  ``local_backend`` is ``"segment_sum"`` (default) or
     ``"pallas"`` (per-window ELL planes contracted by ``gee_spmm``).
     ``prefetch_windows`` stages reads, ELL packing and the sharded
     ``device_put`` on background threads so window *i+1*'s host costs
@@ -398,10 +407,13 @@ def gee_streamed_sharded(source, labels, num_classes: int,
         raise ValueError(f"unknown local_backend {local_backend!r}; "
                          f"pick one of {LOCAL_BACKENDS}")
     if mesh is None:
-        mesh = Mesh(np.asarray(jax.devices()), ("data",))
+        mesh = default_mesh()
         axes = ("data",)
     axes = tuple(axes)
     p = axis_size(mesh, axes)
+    # per-device partials are born sharded, one row per device: an
+    # unsharded zeros would first land whole on device 0
+    parts = NamedSharding(mesh, P(axes, None))
 
     n, k = source.num_nodes, int(num_classes)
     labels = jnp.asarray(labels, jnp.int32)
@@ -426,7 +438,7 @@ def gee_streamed_sharded(source, labels, num_classes: int,
     degree_windows = 0
 
     if opts.laplacian:
-        deg_parts = jnp.zeros((p, n_pad), jnp.float32)
+        deg_parts = jnp.zeros((p, n_pad), jnp.float32, device=parts)
         for i, w in enumerate(pf.windows(pad_to=g)):         # pass 1
             with tr.span("fold.window", phase="degrees", idx=i, shards=p,
                          edges=int(w.num_edges)):
@@ -445,10 +457,10 @@ def gee_streamed_sharded(source, labels, num_classes: int,
 
     t_scatter = time.perf_counter()
     scatter_windows = edges_folded = 0
-    z_parts = jnp.zeros((p, n_pad * k), jnp.float32)
+    z_parts = jnp.zeros((p, n_pad * k), jnp.float32, device=parts)
     if local_backend == "pallas":
-        interpret = jax.default_backend() != "tpu"
-        plane_sharding = NamedSharding(mesh, P(axes, None))
+        interpret = interpret_mode()
+        plane_sharding = parts
 
         def plane_stage(w):
             """Worker-thread stage: ELL plane pack + sharded device_put."""
@@ -501,4 +513,5 @@ def gee_streamed_sharded(source, labels, num_classes: int,
 
 __all__ = ["axis_size", "pad_nodes", "both_directions", "scatter_partial",
            "fold_degrees", "fold_z", "combine_partials", "stream_fold",
-           "gee_streamed_sharded", "finalize", "LOCAL_BACKENDS"]
+           "gee_streamed_sharded", "default_mesh", "finalize",
+           "LOCAL_BACKENDS"]

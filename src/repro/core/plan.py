@@ -89,13 +89,15 @@ _add_self_loops_jit = jax.jit(add_self_loops)
 
 
 def _block_tree(x):
-    """``jax.block_until_ready`` tolerant of host-only stage results
-    (chunk manifests, numpy triples): tracing-mode stage timings must not
-    crash on objects with nothing to wait for."""
-    try:
-        return jax.block_until_ready(x)
-    except Exception:
-        return x
+    """``jax.block_until_ready`` on the array leaves of a stage result.
+
+    Host-only results (chunk manifests, numpy triples) have nothing to
+    wait for and are skipped by leaf type, so a device fault raised while
+    waiting on a real array still surfaces."""
+    for leaf in jax.tree_util.tree_leaves(x):
+        if isinstance(leaf, jax.Array):
+            leaf.block_until_ready()
+    return x
 
 
 def _chunk_key(chunk_edges: int | None) -> int:
@@ -480,12 +482,11 @@ class GEEPlan:
             out.append(PlanStage("compute", "segment_scatter",
                                  detail="flat segment-sum, O(E)"))
         elif self.backend == "pallas":
-            # fused packs the *base* graph (diag-aug folds in as deg+1 +
-            # the in-kernel addend); staged packs the augmented graph
-            packed_aug = o.diag_aug and not self.fused
+            # both packings hold the *base* graph: diag-aug folds in as
+            # deg+1 plus the per-row addend
             out.append(PlanStage(
                 "prep", "bucketed_ell",
-                cached=p.is_cached(("bucketed_ell", packed_aug)),
+                cached=p.is_cached(("bucketed_ell", False)),
                 detail="degree-bucketed ELL packing (host, O(E))"))
             if self.fused:
                 out.append(PlanStage(
@@ -640,30 +641,26 @@ class GEEPlan:
                     lambda: epilogue.row_l2_normalize(z, impl=self.impl))
             return z
         if self.backend == "pallas":
+            # base-graph packing: diag-aug folds in as deg+1 + the per-row
+            # addend, so the augmented packing never builds
+            bell = self._stage(
+                "prep", "bucketed_ell",
+                p.is_cached(("bucketed_ell", False)),
+                lambda: p.bucketed_ell(False))
             if self.fused:
                 from repro.kernels.gee_fused import gee_fused_from_bucketed
 
-                # base-graph packing: diag-aug folds in as deg+1 + the
-                # in-kernel addend, so the augmented packing never builds
-                bell = self._stage(
-                    "prep", "bucketed_ell",
-                    p.is_cached(("bucketed_ell", False)),
-                    lambda: p.bucketed_ell(False))
                 return self._stage(
                     "compute", "gee_spmm_fused", False,
                     lambda: gee_fused_from_bucketed(
                         bell, jnp.asarray(labels), k, o))
             from repro.kernels.ops import gee_pallas_from_bucketed
 
-            bell = self._stage(
-                "prep", "bucketed_ell",
-                p.is_cached(("bucketed_ell", o.diag_aug)),
-                lambda: p.bucketed_ell(o.diag_aug))
             z = self._stage(
                 "compute", "gee_spmm", False,
                 lambda: gee_pallas_from_bucketed(
                     bell, jnp.asarray(labels), k,
-                    GEEOptions(laplacian=o.laplacian)))
+                    GEEOptions(laplacian=o.laplacian, diag_aug=o.diag_aug)))
             if o.correlation:      # epilogue honors this plan's impl choice
                 z = self._stage(
                     "epilogue", "row_l2_normalize", False,

@@ -1,31 +1,19 @@
-"""jax version compatibility shims for the distributed modules.
+"""The one ``shard_map`` entry point for the distributed modules.
 
-The repo targets a range of jax versions: ``shard_map`` graduated from
-``jax.experimental.shard_map`` to the top-level namespace, and its
-"don't check replication" kwarg was renamed ``check_rep`` -> ``check_vma``
-along the way.  Every caller goes through this module so the version split
-lives in exactly one place.
+The repo targets the installed JAX (0.9), where ``shard_map`` lives at the
+top level and replication checking is switched off with ``check_vma``.
+Every caller imports from here so that choice lives in one place.
 """
 
 from __future__ import annotations
 
-import inspect
-
-try:  # jax >= 0.4.35 keeps shard_map in experimental; newer jax exports it
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover - future jax moved it to the top level
-    from jax import shard_map
-
-if "check_vma" in inspect.signature(shard_map).parameters:
-    _NO_CHECK = {"check_vma": False}
-else:
-    _NO_CHECK = {"check_rep": False}
+from jax import shard_map
 
 
 def shard_map_nocheck(f, *, mesh, in_specs, out_specs):
-    """shard_map with replication/VMA checking disabled, any jax version."""
+    """shard_map with replication (VMA) checking disabled."""
     return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **_NO_CHECK)
+                     check_vma=False)
 
 
 __all__ = ["shard_map", "shard_map_nocheck"]

@@ -9,8 +9,8 @@ that the scatter is the only stage needing global memory -- so this
 module fuses the whole O(N*K) epilogue into the scatter's resident
 output tile:
 
-  * the contraction accumulates exactly like ``_gee_spmm_kernel``
-    (one-hot iota + ``dot_general`` batched over rows);
+  * the contraction is ``_gee_spmm_kernel``'s own
+    (:func:`repro.kernels.gee_spmm.contract_tile`);
   * at the *last* degree tile of each row tile -- while the output block
     is still in VMEM -- the kernel adds the diagonal-augmentation term
     ``z[i, y_i] += dinv_i^2 * winv[y_i]`` (the streaming backends' trick
@@ -29,10 +29,9 @@ applies the identical shared-epilogue arithmetic to those few rows as an
 O(#isolated * K) residual fixup.
 
 ``REPRO_GEE_FUSED=0/1`` overrides the plan-layer cost model
-(``repro.core.plan.select_fused``); unset defers to it.  Off-TPU the
-kernels run in interpret mode, so the cost model only selects the fused
-stage on a real MXU -- the pure-JAX/staged behavior of CPU CI is
-unchanged unless a test forces ``interpret=True`` explicitly.
+(``repro.core.plan.select_fused``); unset defers to it.  On CPU the
+kernels run in interpret mode (:mod:`repro.kernels.platform`), so the
+cost model only selects the fused stage on a TPU.
 """
 
 from __future__ import annotations
@@ -50,19 +49,19 @@ from repro.core.gee import GEEOptions, class_weight_inv
 from repro.graph.containers import ELL
 from repro.graph.ell import BucketedELL, ell_planes
 from repro.kernels.autotune import REGISTRY, ceil_to, pow2_bucket
-from repro.kernels.gee_spmm import (LANE, SUBLANE, _block_sizes_formula,
-                                    _TUNED_TABLE, measured_block_search,
+from repro.kernels.gee_spmm import (LANE, _block_sizes_formula, clamp_blocks,
+                                    contract_tile, measured_block_search,
                                     measure_enabled)
+from repro.kernels.platform import interpret_mode
 
 ENV_FUSED = "REPRO_GEE_FUSED"
 
 KERNEL_NAME = "gee_spmm_fused"
 # The fused kernel's tile geometry matches gee_spmm (the epilogue adds no
-# VMEM-resident operand bigger than the output block itself), so it seeds
-# from the same table and formula; measured entries are recorded under its
-# own name so on-device search can diverge where the epilogue tail matters.
-REGISTRY.register(KERNEL_NAME, table=_TUNED_TABLE,
-                  fallback=_block_sizes_formula)
+# VMEM-resident operand bigger than the output block itself), so it shares
+# the formula; measured entries are recorded under its own name so
+# on-device search can diverge where the epilogue tail matters.
+REGISTRY.register(KERNEL_NAME, fallback=_block_sizes_formula)
 
 
 def fused_override() -> bool | None:
@@ -74,10 +73,6 @@ def fused_override() -> bool | None:
     return raw not in ("0", "false", "False", "no")
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 # ---------------------------------------------------------------------------
 # block-size selection (shared autotune registry, own kernel name)
 # ---------------------------------------------------------------------------
@@ -85,7 +80,7 @@ def _interpret_default() -> bool:
 def choose_fused_block_sizes(n: int, max_degree: int,
                              num_classes: int) -> tuple[int, int, int]:
     """(block_rows, block_deg, deg_sub) for the fused kernel: recorded
-    measurement > seeded table > formula, with the opt-in measured search
+    measurement > formula, with the opt-in measured search
     (``REPRO_AUTOTUNE_MEASURE=1``) timing candidates through the fused
     kernel itself."""
     key = pow2_bucket(n, max_degree, num_classes)
@@ -93,11 +88,7 @@ def choose_fused_block_sizes(n: int, max_degree: int,
         measured_block_search(
             n, max_degree, num_classes, kernel=KERNEL_NAME,
             runner_factory=_fused_measure_runner)
-    block_rows, block_deg, deg_sub = REGISTRY.lookup(KERNEL_NAME, key)
-    block_rows = min(block_rows, ceil_to(max(n, 1), SUBLANE))
-    block_deg = min(block_deg, ceil_to(max(max_degree, 1), SUBLANE))
-    deg_sub = min(deg_sub, block_deg)
-    return block_rows, block_deg, deg_sub
+    return clamp_blocks(*REGISTRY.lookup(KERNEL_NAME, key), n, max_degree)
 
 
 def _fused_measure_runner(ylab, contrib, num_classes, interpret):
@@ -136,23 +127,8 @@ def _gee_fused_kernel(ylab_ref, contrib_ref, rowlab_ref, dadd_ref, out_ref, *,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    ylab = ylab_ref[...]                       # [R, D] int32
-    contrib = contrib_ref[...]                 # [R, D] f32
-    rows, deg = ylab.shape
-
-    acc = jnp.zeros((rows, num_classes_pad), jnp.float32)
-    for d0 in range(0, deg, deg_sub):
-        ds = min(deg_sub, deg - d0)            # final chunk may be ragged
-        yl = ylab[:, d0:d0 + ds]                               # [R, ds]
-        cb = contrib[:, d0:d0 + ds]                            # [R, ds]
-        iota = jax.lax.broadcasted_iota(
-            jnp.int32, (rows, ds, num_classes_pad), 2)
-        onehot = (yl[:, :, None] == iota).astype(jnp.float32)  # [R, ds, K]
-        acc = acc + jax.lax.dot_general(
-            cb, onehot,
-            dimension_numbers=(((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-    out_ref[...] += acc
+    out_ref[...] += contract_tile(ylab_ref[...], contrib_ref[...],
+                                  num_classes_pad, deg_sub)
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _epilogue():
@@ -186,7 +162,7 @@ def gee_spmm_fused(ylab: jax.Array, contrib: jax.Array, rowlab: jax.Array,
     """
     n, d = ylab.shape
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     if block_rows is None or block_deg is None or deg_sub is None:
         auto = choose_fused_block_sizes(n, d, num_classes)
         block_rows = auto[0] if block_rows is None else block_rows
@@ -267,7 +243,7 @@ def gee_fused_from_ell(ell: ELL, labels: jax.Array, num_classes: int,
     in-kernel ``dinv^2 * winv[y]`` addend, exactly like the streaming
     backends)."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     labels = jnp.asarray(labels, jnp.int32)
     n = ell.num_nodes
     vals, cols = ell.vals, ell.cols
@@ -308,7 +284,7 @@ def gee_fused_from_bucketed(bell: BucketedELL, labels: jax.Array,
     host-free in O(#isolated * K).
     """
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     labels = jnp.asarray(labels, jnp.int32)
     n = bell.num_nodes
     winv = class_weight_inv(labels, num_classes)

@@ -3,25 +3,35 @@
 TPU adaptation of the paper's CSR SpMM (DESIGN.md section 2, tier 2): CSR's
 pointer-walk is serial and gather-heavy -- hostile to the MXU.  We re-block
 the sparse structure as fixed-width ELL tiles and turn the scatter into a
-batched matvec that lands on the MXU:
+batched matvec:
 
     z[r, k] = sum_d contrib[r, d] * onehot(ylab[r, d])[k]
 
 Per grid step the kernel loads one (ROWS x DEG) tile of neighbor classes
 (``ylab``, int32) and contributions (``contrib``, f32) into VMEM, builds the
-one-hot mask in VREGs via an iota comparison (no K-sized table in memory),
-and contracts over the degree axis with ``jax.lax.dot_general`` batched over
-rows.  The K axis is padded to the 128-lane boundary so the contraction is
-hardware-aligned.
+one-hot mask via an iota comparison (no K-sized table in memory), and
+contracts over the degree axis as a batch-over-rows product
+``[R, 1, ds] x [R, ds, K] -> [R, 1, K]`` (:func:`contract_tile`).  The unit
+middle dim is what lets Mosaic lower it: a 2-D ``[R, ds]`` lhs batched over
+rows would keep no non-contracting dim, which Mosaic's matmul cannot
+express.  The product runs at f32 precision (``Precision.HIGHEST``) so the
+contributions are never rounded to bf16.  The K axis is padded to the
+128-lane boundary.
 
 Grid: (row_tiles, deg_tiles); the output block is revisited along the degree
-axis (accumulate pattern: initialize at j == 0, add afterwards).
+axis (accumulate pattern: initialize at j == 0, add afterwards).  A degree
+block is either the whole (padded) plane width or a multiple of 128 lanes,
+the two shapes Mosaic accepts for a last block dim (:func:`lane_block`).
 
 VMEM budget per step (defaults ROWS=256, DEG=128, K<=128):
-  ylab 256*128*4 = 128 KiB, contrib 128 KiB, onehot VREG-resident,
-  out 256*128*4 = 128 KiB  ->  < 0.5 MiB of ~16 MiB VMEM; the one-hot
-  [ROWS, DEG, K] f32 intermediate is 256*128*128*4 = 16 MiB worst case, so
-  the kernel contracts in DEG-sub-chunks of 8 to keep live VREG state small.
+  ylab 256*128*4 = 128 KiB, contrib 128 KiB, out 256*128*4 = 128 KiB
+  ->  < 0.5 MiB of ~16 MiB VMEM; the one-hot [ROWS, DEG, K] f32
+  intermediate is 256*128*128*4 = 16 MiB worst case, so the kernel
+  contracts in DEG-sub-chunks (``deg_sub``) to keep live state small.
+
+On CPU the kernel runs in Pallas interpret mode (tests only); on TPU it
+compiles to a Mosaic ``tpu_custom_call`` (``tests/test_tpu_compile.py``
+compiles it for a described v5e).
 """
 
 from __future__ import annotations
@@ -34,10 +44,14 @@ from jax.experimental import pallas as pl
 
 from repro.kernels.autotune import (REGISTRY, ceil_to, measure_enabled,
                                     pow2_at_least, pow2_bucket)
+from repro.kernels.platform import interpret_mode
 
 LANE = 128          # TPU lane width: last-dim alignment unit
 SUBLANE = 8         # f32 sublane height
-_VREG_BUDGET = 4 * 1024 * 1024   # cap for the [R, deg_sub, K] one-hot live set
+# cap for the [rows, deg_sub, K_pad] f32 one-hot of one contraction chunk;
+# at 2 MiB every resolved tile compiles for v5e within its scoped VMEM
+_ONEHOT_BUDGET = 2 * 1024 * 1024
+_DEG_SUB = 32       # slots per chunk: fewer chunks compile faster
 
 # Deprecated aliases: these helpers moved to ``repro.kernels.autotune``
 # (``ceil_to`` / ``pow2_at_least``); kept so external callers of the old
@@ -51,23 +65,9 @@ _pow2_at_least = pow2_at_least
 # ---------------------------------------------------------------------------
 #
 # Keyed on pow2-bucketed (N, max degree, K) so the cache stays tiny across a
-# sweep of graph sizes.  The table holds shapes that measured fastest on the
-# interpret-mode sweep in benchmarks/bench_gee_pallas.py; anything not listed
-# falls back to the VMEM-budget formula below.  Entries are
+# sweep of graph sizes.  Recorded on-device measurements win; otherwise the
+# VMEM-budget formula below decides.  Entries are
 # (n_bucket, deg_bucket, k_bucket) -> (block_rows, block_deg, deg_sub).
-
-_TUNED_TABLE = {
-    # small graphs: one row tile, narrow degree tiles
-    (256, 64, 4): (256, 64, 16),
-    (512, 64, 4): (256, 64, 16),
-    # SBM-sized graphs (paper's Fig. 3 grid), K <= 8
-    (1024, 128, 4): (256, 128, 16),
-    (4096, 256, 4): (256, 128, 16),
-    (16384, 512, 4): (512, 128, 16),
-    # wide-K regimes keep the one-hot intermediate small
-    (1024, 128, 128): (128, 128, 8),
-    (4096, 256, 128): (128, 128, 8),
-}
 
 
 def choose_block_sizes(n: int, max_degree: int,
@@ -77,8 +77,8 @@ def choose_block_sizes(n: int, max_degree: int,
     Resolved through the shared ``repro.kernels.autotune.REGISTRY``
     (memoized per pow2 bucket of the key, so a sweep over many graph sizes
     stays within a handful of cache entries): recorded measurements win,
-    then the seeded table, then the VMEM-budget formula.  The result is
-    clamped so tiles never exceed the actual (padded) plane.
+    then the VMEM-budget formula.  The result is clamped so tiles never
+    exceed the actual (padded) plane (:func:`clamp_blocks`).
 
     With ``REPRO_AUTOTUNE_MEASURE=1``, an unrecorded key first runs the
     on-device measured search (:func:`measured_block_search`); the winner
@@ -89,29 +89,40 @@ def choose_block_sizes(n: int, max_degree: int,
     key = pow2_bucket(n, max_degree, num_classes)
     if measure_enabled() and key not in REGISTRY.recorded(KERNEL_NAME):
         measured_block_search(n, max_degree, num_classes)
-    block_rows, block_deg, deg_sub = REGISTRY.lookup(KERNEL_NAME, key)
+    return clamp_blocks(*REGISTRY.lookup(KERNEL_NAME, key), n, max_degree)
+
+
+def clamp_blocks(block_rows: int, block_deg: int, deg_sub: int, n: int,
+                 width: int) -> tuple[int, int, int]:
+    """Clamp a (block_rows, block_deg, deg_sub) tile to an [n, width] plane
+    in a shape Mosaic accepts: rows to the 8-row padded height, and the
+    degree block either to the whole 8-padded width or to a multiple of
+    128 lanes (a narrower block over a wider plane is refused)."""
     block_rows = min(block_rows, ceil_to(max(n, 1), SUBLANE))
-    block_deg = min(block_deg, ceil_to(max(max_degree, 1), SUBLANE))
-    deg_sub = min(deg_sub, block_deg)
-    return block_rows, block_deg, deg_sub
+    block_deg = min(ceil_to(block_deg, LANE), ceil_to(max(width, 1), SUBLANE))
+    return block_rows, block_deg, min(deg_sub, block_deg)
+
+
+def _budget_rows(deg_sub: int, k: int, cap: int = 128) -> int:
+    """Most rows (a multiple of 8, at most ``cap``) whose [rows, deg_sub,
+    K_pad] one-hot fits ``_ONEHOT_BUDGET``."""
+    rows = _ONEHOT_BUDGET // (deg_sub * ceil_to(max(k, 1), LANE) * 4)
+    return max(SUBLANE, min(cap, rows) // SUBLANE * SUBLANE)
 
 
 def _block_sizes_formula(key: tuple[int, ...]) -> tuple[int, int, int]:
-    """VMEM-budget fallback on pow2-bucketed (N, D, K): row tiles cap at
-    256, degree tiles stop at one LANE, and deg_sub is sized so the
-    [rows, deg_sub, K] one-hot intermediate stays under _VREG_BUDGET."""
+    """VMEM-budget fallback on pow2-bucketed (N, D, K): degree tiles stop
+    at one LANE, chunks of ``_DEG_SUB`` slots, and row tiles (at most 128)
+    sized so the [rows, deg_sub, K] one-hot stays under _ONEHOT_BUDGET."""
     n_b, d_b, k_b = key
-    block_rows = min(256, ceil_to(n_b, SUBLANE))
     block_deg = min(LANE, ceil_to(d_b, SUBLANE))
-    k_pad = ceil_to(k_b, LANE)
-    deg_sub = max(_VREG_BUDGET // (block_rows * k_pad * 4), 1)
-    deg_sub = min(pow2_at_least(deg_sub + 1) // 2, block_deg, 32)
+    deg_sub = min(_DEG_SUB, block_deg)
+    block_rows = min(_budget_rows(deg_sub, k_b), ceil_to(n_b, SUBLANE))
     return block_rows, block_deg, deg_sub
 
 
 KERNEL_NAME = "gee_spmm"
-REGISTRY.register(KERNEL_NAME, table=_TUNED_TABLE,
-                  fallback=_block_sizes_formula)
+REGISTRY.register(KERNEL_NAME, fallback=_block_sizes_formula)
 
 
 def _choose_block_sizes_bucketed(n_b: int, d_b: int,
@@ -125,9 +136,10 @@ def _choose_block_sizes_bucketed(n_b: int, d_b: int,
 # on-device measured search (opt-in via REPRO_AUTOTUNE_MEASURE=1)
 # ---------------------------------------------------------------------------
 
-# the candidate ladder the measured search sweeps, before clamping
-_CANDIDATE_LADDER = ((64, 64, 8), (128, 128, 8), (256, 64, 16),
-                     (256, 128, 16), (512, 128, 16), (128, 256, 32))
+# the candidate ladder the measured search sweeps, before clamping (rows
+# are cut to the one-hot budget for the key's K)
+_CANDIDATE_LADDER = ((64, 128, 32), (128, 128, 32), (128, 128, 16),
+                     (64, 256, 32), (128, 256, 32))
 
 # synthetic operand caps: candidates rank the same on an 8k-row slice of a
 # huge bucket, and timing 7 shapes on the full plane would dwarf the run
@@ -141,18 +153,17 @@ def candidate_blocks(key: tuple[int, ...],
                      ) -> list[tuple[int, int, int]]:
     """The measured search's candidate set for one pow2-bucketed key:
     the current registry resolution first (so a recorded winner can only
-    beat or match what seeded table/formula would have picked), the
-    formula, then the ladder -- all clamped to the bucketed plane and
-    deduplicated preserving order (ties break toward the front)."""
+    beat or match what the formula would have picked), the formula, then
+    the ladder -- all clamped to the bucketed plane and deduplicated
+    preserving order (ties break toward the front)."""
     n_b, d_b, k_b = key
     raw = [tuple(registry.lookup(kernel or KERNEL_NAME, key)),
            _block_sizes_formula(key)]
-    raw += list(_CANDIDATE_LADDER)
+    raw += [(min(br, _budget_rows(ds, k_b, cap=br)), bd, ds)
+            for br, bd, ds in _CANDIDATE_LADDER]
     out: list[tuple[int, int, int]] = []
     for br, bd, ds in raw:
-        br = min(br, ceil_to(max(n_b, 1), SUBLANE))
-        bd = min(bd, ceil_to(max(d_b, 1), SUBLANE))
-        c = (br, bd, min(ds, bd))
+        c = clamp_blocks(br, bd, ds, n_b, d_b)
         if c not in out:
             out.append(c)
     return out
@@ -197,12 +208,38 @@ def measured_block_search(n: int, max_degree: int, num_classes: int, *,
     key = pow2_bucket(n, max_degree, num_classes)
     n_b, d_b, k_b = key
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     cands = candidate_blocks(key, registry=registry, kernel=kernel)
     ylab, contrib = _synthetic_planes(n_b, d_b, k_b)
     runner = runner_factory(ylab, contrib, k_b, interpret)
     return registry.measured_search(kernel, key, cands, runner,
                                     warmup=warmup, repeats=repeats)
+
+
+def contract_tile(ylab, contrib, num_classes_pad: int, deg_sub: int):
+    """``sum_d contrib[r, d] * [ylab[r, d] == k]`` for one [R, D] tile,
+    as an [R, K_pad] f32 block.
+
+    The degree axis is contracted ``deg_sub`` slots at a time so the
+    [R, deg_sub, K_pad] one-hot stays small.  Each chunk is a batch-over-
+    rows product with a unit middle dim, ``[R, 1, ds] x [R, ds, K]``,
+    which Mosaic lowers (a bare [R, ds] lhs would have no non-contracting
+    dim); HIGHEST precision keeps the f32 contributions exact.
+    """
+    rows, deg = ylab.shape
+    acc = jnp.zeros((rows, num_classes_pad), jnp.float32)
+    for d0 in range(0, deg, deg_sub):
+        ds = min(deg_sub, deg - d0)          # final chunk may be ragged
+        yl = ylab[:, d0:d0 + ds]                               # [R, ds]
+        cb = contrib[:, d0:d0 + ds]                            # [R, ds]
+        iota = jax.lax.broadcasted_iota(
+            jnp.int32, (rows, ds, num_classes_pad), 2)
+        onehot = (yl[:, :, None] == iota).astype(jnp.float32)  # [R, ds, K]
+        acc = acc + jnp.einsum(
+            "rqd,rdk->rqk", cb[:, None, :], onehot,
+            precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32)[:, 0, :]
+    return acc
 
 
 def _gee_spmm_kernel(ylab_ref, contrib_ref, out_ref, *, num_classes_pad: int,
@@ -214,37 +251,24 @@ def _gee_spmm_kernel(ylab_ref, contrib_ref, out_ref, *, num_classes_pad: int,
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    ylab = ylab_ref[...]                       # [R, D] int32
-    contrib = contrib_ref[...]                 # [R, D] f32
-    rows, deg = ylab.shape
-
-    acc = jnp.zeros((rows, num_classes_pad), jnp.float32)
-    # Sub-chunk the degree axis so the one-hot intermediate stays VREG-sized.
-    for d0 in range(0, deg, deg_sub):
-        ds = min(deg_sub, deg - d0)          # final chunk may be ragged
-        yl = ylab[:, d0:d0 + ds]                               # [R, ds]
-        cb = contrib[:, d0:d0 + ds]                            # [R, ds]
-        iota = jax.lax.broadcasted_iota(
-            jnp.int32, (rows, ds, num_classes_pad), 2)
-        onehot = (yl[:, :, None] == iota).astype(jnp.float32)  # [R, ds, K]
-        # Batched matvec over rows: contract the degree axis on the MXU.
-        acc = acc + jax.lax.dot_general(
-            cb, onehot,
-            dimension_numbers=(((1,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-    out_ref[...] += acc
+    out_ref[...] += contract_tile(ylab_ref[...], contrib_ref[...],
+                                  num_classes_pad, deg_sub)
 
 
 def gee_spmm(ylab: jax.Array, contrib: jax.Array, num_classes: int,
              block_rows: int | None = 256, block_deg: int | None = 128,
-             deg_sub: int | None = 8, interpret: bool = True) -> jax.Array:
+             deg_sub: int | None = 8,
+             interpret: bool | None = None) -> jax.Array:
     """ELL GEE contraction.  ylab [N, D] int32 (-1 pad), contrib [N, D] f32.
 
     Returns [N, num_classes] f32.  Padding slots (ylab == -1) match no class
     and contribute exactly 0, so padded and unpadded inputs agree bitwise.
     Pass ``None`` for any block size to let ``choose_block_sizes`` pick it
-    from the (N, max degree, K) heuristic table.
+    from the (N, max degree, K) heuristic table; ``interpret=None`` follows
+    :func:`repro.kernels.platform.interpret_mode`.
     """
+    if interpret is None:
+        interpret = interpret_mode()
     n, d = ylab.shape
     if block_rows is None or block_deg is None or deg_sub is None:
         auto = choose_block_sizes(n, d, num_classes)

@@ -4,9 +4,11 @@
 exactly (same options, same -1-label convention) but routes the contraction
 through the ``gee_spmm`` kernel and the correlation step through the shared
 epilogue's Pallas path (``repro.core.epilogue.row_l2_normalize`` with
-``impl="pallas"``, i.e. the ``row_norm`` kernel).
+``impl="pallas"``, i.e. the ``row_norm`` kernel).  Both drivers pack the
+*base* graph: diagonal augmentation folds in as degrees + 1 and the shared
+``diag_aug_epilogue`` term, as in the fused and streaming backends.
 On CPU the kernels run in interpret mode (Python evaluation of the kernel
-body); on TPU the same code compiles to Mosaic.
+body); on TPU they compile to Mosaic (see :mod:`repro.kernels.platform`).
 
 Two packing strategies feed the kernel:
 
@@ -23,16 +25,23 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.epilogue import inv_sqrt_degrees, row_l2_normalize
+from repro.core.epilogue import (diag_aug_epilogue, inv_sqrt_degrees,
+                                 row_l2_normalize)
 from repro.core.gee import GEEOptions, class_weight_inv
-from repro.graph.containers import ELL, EdgeList, add_self_loops
+from repro.graph.containers import ELL, EdgeList
 from repro.graph.ell import (BucketedELL, edges_to_bucketed_ell, edges_to_ell,
                              ell_planes)
 from repro.kernels.gee_spmm import choose_block_sizes, gee_spmm
+from repro.kernels.platform import interpret_mode
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+def _epilogue(z, labels, winv, dinv, opts: GEEOptions, interpret: bool):
+    """Diag-aug term, then the Pallas row norm (the staged epilogue)."""
+    if opts.diag_aug:
+        z = diag_aug_epilogue(z, labels, winv, dinv)
+    if opts.correlation:
+        z = row_l2_normalize(z, impl="pallas", interpret=interpret)
+    return z
 
 
 def gee_pallas_from_ell(ell: ELL, labels: jax.Array, num_classes: int,
@@ -40,26 +49,27 @@ def gee_pallas_from_ell(ell: ELL, labels: jax.Array, num_classes: int,
                         block_rows: int | None = None,
                         block_deg: int | None = None,
                         interpret: bool | None = None) -> jax.Array:
-    """GEE from a pre-built flat ELL tiling (device-side math only)."""
+    """GEE from a pre-built flat ELL tiling of the *base* graph
+    (device-side math only)."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     labels = jnp.asarray(labels, jnp.int32)
     n = ell.num_nodes
     vals, cols = ell.vals, ell.cols
+    winv = class_weight_inv(labels, num_classes)
 
+    dinv = jnp.ones((vals.shape[0],), jnp.float32)
     if opts.laplacian:
         deg = jnp.sum(vals, axis=1)                       # padded rows -> 0
+        if opts.diag_aug:
+            deg = deg + 1.0                               # the unpacked loop
         dinv = inv_sqrt_degrees(deg)
-        deg_dst = dinv[jnp.clip(cols, 0, n - 1)]
-        vals = vals * dinv[:vals.shape[0], None] * deg_dst
+        vals = vals * dinv[:, None] * dinv[jnp.clip(cols, 0, n - 1)]
 
-    ylab, contrib = ell_planes(cols, vals, labels,
-                               class_weight_inv(labels, num_classes))
+    ylab, contrib = ell_planes(cols, vals, labels, winv)
     z = gee_spmm(ylab, contrib, num_classes, block_rows=block_rows,
                  block_deg=block_deg, deg_sub=None, interpret=interpret)[:n]
-    if opts.correlation:
-        z = row_l2_normalize(z, impl="pallas", interpret=interpret)
-    return z
+    return _epilogue(z, labels, winv, dinv[:n], opts, interpret)
 
 
 def gee_pallas_from_bucketed(bell: BucketedELL, labels: jax.Array,
@@ -68,30 +78,32 @@ def gee_pallas_from_bucketed(bell: BucketedELL, labels: jax.Array,
                              block_rows: int | None = None,
                              block_deg: int | None = None,
                              interpret: bool | None = None) -> jax.Array:
-    """GEE from a degree-bucketed ELL tiling: one kernel launch per bucket,
-    partial outputs scattered into the [N+1]-row accumulator (row N is the
-    dump row for bucket padding).  Explicit block sizes override the
-    autotuner for every bucket; by default each bucket is tuned on its own
-    (rows, width, K)."""
+    """GEE from a degree-bucketed ELL tiling of the *base* graph: one kernel
+    launch per bucket, partial outputs scattered into the [N+1]-row
+    accumulator (row N is the dump row for bucket padding).  Explicit block
+    sizes override the autotuner for every bucket; by default each bucket
+    is tuned on its own (rows, width, K)."""
     if interpret is None:
-        interpret = _interpret_default()
+        interpret = interpret_mode()
     labels = jnp.asarray(labels, jnp.int32)
     n = bell.num_nodes
     winv = class_weight_inv(labels, num_classes)
 
-    dinv = None
+    dinv = jnp.ones((n,), jnp.float32)
     if opts.laplacian:
         # degree = total out-weight per node, assembled across buckets
         deg = jnp.zeros((n + 1,), jnp.float32)
         for b in bell.buckets:
             deg = deg.at[b.row_ids].add(jnp.sum(b.vals, axis=1))
         deg = deg[:n]
+        if opts.diag_aug:
+            deg = deg + 1.0                               # the unpacked loop
         dinv = inv_sqrt_degrees(deg)
 
     z = jnp.zeros((n + 1, num_classes), jnp.float32)
     for b in bell.buckets:
         vals = b.vals
-        if dinv is not None:
+        if opts.laplacian:
             safe_rows = jnp.minimum(b.row_ids, n - 1)
             vals = vals * dinv[safe_rows][:, None] \
                         * dinv[jnp.clip(b.cols, 0, n - 1)]
@@ -103,10 +115,7 @@ def gee_pallas_from_bucketed(bell: BucketedELL, labels: jax.Array,
                        block_deg=block_deg if block_deg is not None else bd,
                        deg_sub=None, interpret=interpret)
         z = z.at[b.row_ids].add(out)
-    z = z[:n]
-    if opts.correlation:
-        z = row_l2_normalize(z, impl="pallas", interpret=interpret)
-    return z
+    return _epilogue(z[:n], labels, winv, dinv, opts, interpret)
 
 
 def gee_pallas(edges: EdgeList, labels, num_classes: int,
@@ -120,8 +129,6 @@ def gee_pallas(edges: EdgeList, labels, num_classes: int,
     both (i,j) and (j,i) present -- see ``containers.symmetrize``).
     """
     labels = jnp.asarray(labels, jnp.int32)
-    if opts.diag_aug:
-        edges = add_self_loops(edges)
     if bucketed:
         bell = edges_to_bucketed_ell(edges)
         return gee_pallas_from_bucketed(bell, labels, num_classes, opts,

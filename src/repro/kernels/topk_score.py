@@ -14,8 +14,10 @@ Two access patterns cover every retrieval path:
     tile at a time; the norm terms are lane reductions on the same tiles.
   * ``gathered_scores``  -- per-query candidate matrices (the IVF path:
     each query gathers the members of its probed cells).  The kernel is a
-    batched matvec over the query axis, the same ``dot_general`` shape the
-    ``gee_spmm`` one-hot contraction uses.
+    matvec per query, a multiply and lane sum on the VPU.
+
+Dots run at f32 precision: the l2 scores of near neighbors differ far
+below bf16's resolution, so a bf16 MXU pass would reorder them.
 
 Both kernels mask *inside* the kernel: padding / invalid slots (cell-table
 ``-1`` entries, inactive centroids) score ``NEG_INF`` and therefore never
@@ -28,8 +30,8 @@ formula fallback, all behind an ``lru_cache`` so a sweep over many batch
 shapes stays within a handful of entries.
 
 On CPU the kernels run in interpret mode; ``impl="auto"`` therefore routes
-to the pure-JAX fallback (identical formulas, tested equivalent) anywhere
-but TPU.
+to the pure-JAX fallback (identical formulas, tested equivalent) on CPU and
+to the kernels on TPU (:func:`repro.kernels.platform.interpret_mode`).
 """
 
 from __future__ import annotations
@@ -43,6 +45,7 @@ from jax.experimental import pallas as pl
 
 from repro.kernels.autotune import (REGISTRY, ceil_to, pow2_at_least,
                                     pow2_bucket)
+from repro.kernels.platform import interpret_mode
 
 LANE = 128          # TPU lane width: last-dim alignment unit
 SUBLANE = 8         # f32 sublane height
@@ -65,10 +68,10 @@ def _check_metric(metric: str):
 
 
 def _resolve_impl(impl: str) -> str:
-    """'auto' -> pallas on TPU, pure-JAX fallback everywhere else (the
-    kernels would run in interpret mode off-TPU, strictly slower)."""
+    """'auto' -> pallas on TPU, the pure-JAX fallback on CPU (where the
+    kernels would run in interpret mode, strictly slower)."""
     if impl == "auto":
-        return "pallas" if jax.default_backend() == "tpu" else "jax"
+        return "jax" if interpret_mode() else "pallas"
     if impl not in ("pallas", "jax"):
         raise ValueError(f"unknown impl {impl!r}; 'auto', 'pallas' or 'jax'")
     return impl
@@ -168,13 +171,26 @@ def _scores_from_parts(dot, qn2, xn2, metric: str):
     return jnp.where(denom > 0, dot / jnp.maximum(denom, _COS_EPS), 0.0)
 
 
+def _pairwise_dot(q, x):
+    """[BQ, K] x [BM, K] -> [BQ, BM] at f32 precision: near neighbors differ
+    in the fifth decimal of an l2 score, far below bf16's resolution."""
+    return jax.lax.dot_general(q, x, (((1,), (1,)), ((), ())),
+                               precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def _gathered_dot(cand, q):
+    """[BQ, BM, K] . [BQ, K] -> [BQ, BM], the per-query candidate dot, as
+    an f32 multiply and lane sum on the VPU (no MXU rounding)."""
+    return jnp.sum(cand * q[:, None, :], axis=2)
+
+
 def _pairwise_kernel(q_ref, x_ref, valid_ref, out_ref, *, metric: str):
     """One (block_q, block_m) tile of the shared-database score matrix."""
     q = q_ref[...]                               # [BQ, K_pad] f32
     x = x_ref[...]                               # [BM, K_pad] f32
     v = valid_ref[...]                           # [1, BM] f32 (1 = live)
-    dot = jax.lax.dot_general(q, x, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
+    dot = _pairwise_dot(q, x)
     qn2 = jnp.sum(q * q, axis=1, keepdims=True)  # [BQ, 1]
     xn2 = jnp.sum(x * x, axis=1)[None, :]        # [1, BM]
     s = _scores_from_parts(dot, qn2, xn2, metric)
@@ -182,13 +198,12 @@ def _pairwise_kernel(q_ref, x_ref, valid_ref, out_ref, *, metric: str):
 
 
 def _gathered_kernel(cand_ref, q_ref, mask_ref, out_ref, *, metric: str):
-    """One (block_q, block_m) tile of per-query candidate scores: a batched
-    matvec over the query axis (the ``gee_spmm`` dot_general shape)."""
+    """One (block_q, block_m) tile of per-query candidate scores: a
+    matvec per query (:func:`_gathered_dot`)."""
     cand = cand_ref[...]                         # [BQ, BM, K_pad] f32
     q = q_ref[...]                               # [BQ, K_pad] f32
     m = mask_ref[...]                            # [BQ, BM] f32 (1 = live)
-    dot = jax.lax.dot_general(cand, q, (((2,), (1,)), ((0,), (0,))),
-                              preferred_element_type=jnp.float32)  # [BQ, BM]
+    dot = _gathered_dot(cand, q)                 # [BQ, BM]
     qn2 = jnp.sum(q * q, axis=1, keepdims=True)  # [BQ, 1]
     cn2 = jnp.sum(cand * cand, axis=2)           # [BQ, BM]
     s = _scores_from_parts(dot, qn2, cn2, metric)
@@ -217,7 +232,7 @@ def pairwise_scores(queries: jax.Array, database: jax.Array,
     if valid is None:
         valid = jnp.ones((m,), jnp.float32)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     if impl == "jax":
         return _pairwise_jax(queries, database, valid, metric)
     return _pairwise_pallas(queries, database, valid, metric, block_q,
@@ -280,7 +295,7 @@ def gathered_scores(queries: jax.Array, cand: jax.Array, mask: jax.Array, *,
         block_q = auto[0] if block_q is None else block_q
         block_m = auto[1] if block_m is None else block_m
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     if impl == "jax":
         return _gathered_jax(queries, cand, mask, metric)
     return _gathered_pallas(queries, cand, mask, metric, block_q, block_m,
@@ -334,13 +349,15 @@ def _gathered_pallas(queries, cand, mask, metric, block_q, block_m,
 # The staged serving path computes the full [Q, M] score matrix, writes it
 # out, then reads it back for ``masked_topk``.  The fused kernels below keep
 # a [Q_tile, k_slots] running top-k (scores + ids) in the revisited output
-# block instead: each m-tile's scores merge into it via ``lax.top_k`` over
-# the k_slots + block_m concatenation, so nothing M-sized ever leaves VMEM.
+# block instead: each m-tile's scores merge into it (:func:`_merge_topk`),
+# so nothing M-sized ever leaves VMEM.
 #
-# Tie behavior matches ``masked_topk`` exactly: the running entries come
-# first in the concatenation and always carry smaller global m than the
-# current tile (tiles arrive in ascending m), and ``lax.top_k`` is stable,
-# so equal scores keep ascending-m order -- the same order a full-row
+# Mosaic has no ``top_k``, so the merge is a selection loop of ``kk`` steps
+# (the live result width): row max, lowest position holding it, record,
+# mask.  Tie behavior matches ``masked_topk`` exactly: the running entries
+# are searched before the tile and always carry smaller global m (tiles
+# arrive in ascending m), and the lowest position wins among equal scores,
+# which is ``lax.top_k``'s stable order -- the same order a full-row
 # ``top_k`` produces.  The pure-JAX fallback *is* the staged compose
 # (scores + masked_topk), so CPU/CI results are identical by construction.
 
@@ -376,8 +393,48 @@ def _finalize_topk(scores, ids, q: int, kk: int, k: int):
     return ids, scores
 
 
+def _lowest_lane(hit, lanes):
+    """Per row, the lowest lane where ``hit`` holds (``lanes.shape[1]`` if
+    none), as an [rows, 1] int32 column."""
+    return jnp.min(jnp.where(hit, lanes, lanes.shape[1]), axis=1,
+                   keepdims=True)
+
+
+def _merge_topk(run_s, run_i, tile_s, tile_i, kk: int):
+    """Top ``kk`` of the running entries followed by one tile, in
+    ``lax.top_k`` order (descending score, lowest position first on ties).
+
+    ``run_*`` are the [R, k_slots] running blocks (ascending global m),
+    ``tile_*`` the [R, BM] tile; returns new [R, k_slots] blocks whose
+    slots past ``kk`` hold ``NEG_INF`` / -1.  Chosen entries are knocked
+    out with -inf, below the finite ``NEG_INF`` sentinel, so each step
+    picks a fresh one.
+    """
+    run_lane = jax.lax.broadcasted_iota(jnp.int32, run_s.shape, 1)
+    tile_lane = jax.lax.broadcasted_iota(jnp.int32, tile_s.shape, 1)
+    out_s = jnp.full(run_s.shape, NEG_INF, jnp.float32)
+    out_i = jnp.full(run_i.shape, -1, jnp.int32)
+    for t in range(kk):
+        best = jnp.maximum(jnp.max(run_s, axis=1, keepdims=True),
+                           jnp.max(tile_s, axis=1, keepdims=True))
+        pr = _lowest_lane(run_s == best, run_lane)
+        pt = _lowest_lane(tile_s == best, tile_lane)
+        from_run = pr < run_s.shape[1]
+        pick_r = run_lane == pr
+        pick_t = (tile_lane == pt) & ~from_run
+        chosen = jnp.where(
+            from_run,
+            jnp.sum(jnp.where(pick_r, run_i, 0), axis=1, keepdims=True),
+            jnp.sum(jnp.where(pick_t, tile_i, 0), axis=1, keepdims=True))
+        out_s = jnp.where(run_lane == t, best, out_s)
+        out_i = jnp.where(run_lane == t, chosen, out_i)
+        run_s = jnp.where(pick_r, -jnp.inf, run_s)
+        tile_s = jnp.where(pick_t, -jnp.inf, tile_s)
+    return out_s, out_i
+
+
 def _pairwise_topk_kernel(q_ref, x_ref, valid_ref, scores_ref, ids_ref, *,
-                          metric: str, block_m: int, k_slots: int):
+                          metric: str, block_m: int, kk: int):
     """One (q_tile, m_tile) step: score the tile, merge into the running
     top-k held in the revisited output blocks."""
     j = pl.program_id(1)
@@ -390,22 +447,17 @@ def _pairwise_topk_kernel(q_ref, x_ref, valid_ref, scores_ref, ids_ref, *,
     q = q_ref[...]                               # [BQ, K_pad] f32
     x = x_ref[...]                               # [BM, K_pad] f32
     v = valid_ref[...]                           # [1, BM] f32
-    dot = jax.lax.dot_general(q, x, (((1,), (1,)), ((), ())),
-                              preferred_element_type=jnp.float32)
+    dot = _pairwise_dot(q, x)
     qn2 = jnp.sum(q * q, axis=1, keepdims=True)
     xn2 = jnp.sum(x * x, axis=1)[None, :]
     s = jnp.where(v > 0, _scores_from_parts(dot, qn2, xn2, metric), NEG_INF)
     tile_ids = j * block_m + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    # running entries first: stable top_k keeps ascending-m tie order
-    merged_s = jnp.concatenate([scores_ref[...], s], axis=1)
-    merged_i = jnp.concatenate([ids_ref[...], tile_ids], axis=1)
-    top, pos = jax.lax.top_k(merged_s, k_slots)
-    scores_ref[...] = top
-    ids_ref[...] = jnp.take_along_axis(merged_i, pos, axis=1)
+    scores_ref[...], ids_ref[...] = _merge_topk(
+        scores_ref[...], ids_ref[...], s, tile_ids, kk)
 
 
 def _gathered_topk_kernel(cand_ref, q_ref, mask_ref, ids_ref, scores_out_ref,
-                          ids_out_ref, *, metric: str, k_slots: int):
+                          ids_out_ref, *, metric: str, kk: int):
     """Gathered-candidate twin: per-query candidate tiles carry their own
     database ids (the IVF table gather), merged the same way."""
     j = pl.program_id(1)
@@ -419,16 +471,12 @@ def _gathered_topk_kernel(cand_ref, q_ref, mask_ref, ids_ref, scores_out_ref,
     q = q_ref[...]                               # [BQ, K_pad] f32
     m = mask_ref[...]                            # [BQ, BM] f32
     tile_ids = ids_ref[...]                      # [BQ, BM] int32
-    dot = jax.lax.dot_general(cand, q, (((2,), (1,)), ((0,), (0,))),
-                              preferred_element_type=jnp.float32)
+    dot = _gathered_dot(cand, q)
     qn2 = jnp.sum(q * q, axis=1, keepdims=True)
     cn2 = jnp.sum(cand * cand, axis=2)
     s = jnp.where(m > 0, _scores_from_parts(dot, qn2, cn2, metric), NEG_INF)
-    merged_s = jnp.concatenate([scores_out_ref[...], s], axis=1)
-    merged_i = jnp.concatenate([ids_out_ref[...], tile_ids], axis=1)
-    top, pos = jax.lax.top_k(merged_s, k_slots)
-    scores_out_ref[...] = top
-    ids_out_ref[...] = jnp.take_along_axis(merged_i, pos, axis=1)
+    scores_out_ref[...], ids_out_ref[...] = _merge_topk(
+        scores_out_ref[...], ids_out_ref[...], s, tile_ids, kk)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "metric", "block_q",
@@ -449,7 +497,7 @@ def _pairwise_topk_pallas(queries, database, valid, k, metric, block_q,
     vp = vp.at[0, :m].set(valid.astype(jnp.float32))
     scores, ids = pl.pallas_call(
         functools.partial(_pairwise_topk_kernel, metric=metric,
-                          block_m=block_m, k_slots=k_slots),
+                          block_m=block_m, kk=kk),
         grid=(q_pad // block_q, m_pad // block_m),
         in_specs=[
             pl.BlockSpec((block_q, k_pad), lambda i, j: (i, 0)),
@@ -487,8 +535,7 @@ def _gathered_topk_pallas(queries, cand, mask, ids, k, metric, block_q,
     ip = jnp.full((q_pad, m_pad), -1, jnp.int32)
     ip = ip.at[:q, :m].set(ids.astype(jnp.int32))
     scores, out_ids = pl.pallas_call(
-        functools.partial(_gathered_topk_kernel, metric=metric,
-                          k_slots=k_slots),
+        functools.partial(_gathered_topk_kernel, metric=metric, kk=kk),
         grid=(q_pad // block_q, m_pad // block_m),
         in_specs=[
             pl.BlockSpec((block_q, block_m, k_pad), lambda i, j: (i, j, 0)),
@@ -534,7 +581,7 @@ def scored_topk(queries: jax.Array, database: jax.Array,
         if valid is None:
             valid = jnp.ones((m,), jnp.float32)
         if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+            interpret = interpret_mode()
         return _pairwise_topk_pallas(queries, database, valid, int(k),
                                      metric, block_q, block_m, interpret)
     scores = pairwise_scores(queries, database, valid, metric=metric,
@@ -565,7 +612,7 @@ def scored_topk_gathered(queries: jax.Array, cand: jax.Array,
             block_q = auto[0] if block_q is None else block_q
             block_m = auto[1] if block_m is None else block_m
         if interpret is None:
-            interpret = jax.default_backend() != "tpu"
+            interpret = interpret_mode()
         return _gathered_topk_pallas(queries, cand, mask, ids, int(k),
                                      metric, block_q, block_m, interpret)
     scores = gathered_scores(queries, cand, mask, metric=metric, impl=impl,

@@ -19,6 +19,8 @@ from repro.core.gee import GEEOptions, gee
 from repro.core.plan import GEEPlan, PreparedGraph
 from repro.graph.datasets import TABLE2, load
 from repro.graph.sbm import sample_sbm
+from repro.kernels.platform import interpret_mode
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import cli as obs_cli
 
 
@@ -71,6 +73,7 @@ def main(argv=None):
     obs_cli.add_flags(ap)
     args = ap.parse_args(argv)
     obs_cli.setup(args)
+    enable_compile_cache()
 
     opts = GEEOptions(laplacian=args.lap, diag_aug=args.diag,
                       correlation=args.cor)
@@ -156,8 +159,7 @@ def main(argv=None):
         if b == "python_loop" and edges.num_edges > 3_000_000:
             print(f"  {b:12s}: skipped (too slow at this size)")
             continue
-        if (b == "pallas" and args.compare
-                and jax.default_backend() != "tpu"):
+        if b == "pallas" and args.compare and interpret_mode():
             print(f"  {b:12s}: skipped (interpret mode off-TPU; "
                   f"run with --backend pallas to force)")
             continue

@@ -25,6 +25,7 @@ from repro.core.api import GEEEmbedder
 from repro.core.gee import GEEOptions
 from repro.graph.datasets import TABLE2, load
 from repro.graph.sbm import sample_sbm
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import cli as obs_cli
 from repro.search.service import GEEQueryService
 
@@ -82,6 +83,7 @@ def main(argv=None):
     obs_cli.add_flags(ap)
     args = ap.parse_args(argv)
     obs_cli.setup(args)
+    enable_compile_cache()
 
     opts = GEEOptions(laplacian=args.lap, diag_aug=args.diag,
                       correlation=args.cor)

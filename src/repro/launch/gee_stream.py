@@ -38,6 +38,7 @@ from repro.graph.datasets import TABLE2, load
 from repro.graph.delta import (edge_delta_from_numpy, label_delta_from_numpy,
                                symmetrize_delta)
 from repro.graph.sbm import sample_sbm
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import cli as obs_cli
 from repro.search.service import GEEDeltaServer
 
@@ -121,6 +122,7 @@ def main(argv=None):
         from repro.graph.prefetch import ENV_PREFETCH_WINDOWS
         os.environ[ENV_PREFETCH_WINDOWS] = str(args.prefetch_windows)
     obs_cli.setup(args)
+    enable_compile_cache()
 
     st = prepare_stream(args)
     name, edges, labels, k, opts = (st["name"], st["edges"], st["labels"],

@@ -47,6 +47,9 @@ from repro.kernels.topk_score import (fused_topk_enabled, pairwise_scores,
 from repro.obs import metrics as obs_metrics
 
 DEFAULT_PAD_MULTIPLE = 128
+# device bytes one IVF query batch may gather: [Q, nprobe * B, K] candidate
+# rows, lane-padded to 128 on the Pallas path; larger batches are split
+GATHER_BUDGET_BYTES = 1 << 30
 
 
 def index_stats_view(builds: int = 0) -> "obs_metrics.StatsView":
@@ -226,14 +229,36 @@ class ClassPartitionedIndex:
             self.stats["cells_probed"] += int(queries.shape[0]) * p
             self.stats["candidates_scored"] += (int(queries.shape[0]) * p
                                                 * self.bucket_capacity)
-            ids, scores = _ivf_search(
-                queries, self._z, self._centroids,
-                jnp.asarray(self._active, jnp.float32), self._table_device(),
-                k=int(k), nprobe=p, metric=self.metric, impl=self.impl,
-                fused=fused)
+            ids, scores = self._ivf_batches(queries, int(k), p, fused)
         if squeeze:
             return ids[0], scores[0]
         return ids, scores
+
+    def _ivf_batches(self, queries, k: int, nprobe: int, fused: bool):
+        """IVF search in query batches whose gathered candidates fit
+        ``GATHER_BUDGET_BYTES``; every batch but a lone short one has the
+        same (power-of-two) height, so the search traces at most twice."""
+        q = int(queries.shape[0])
+        per_query = nprobe * self.bucket_capacity * _ceil_to(self.dim, 128) * 4
+        batch = max(1, GATHER_BUDGET_BYTES // per_query)
+        batch = 1 << (batch.bit_length() - 1)           # power of two
+        run = functools.partial(
+            _ivf_search, z=self._z, centroids=self._centroids,
+            active=jnp.asarray(self._active, jnp.float32),
+            table=self._table_device(), k=k, nprobe=nprobe,
+            metric=self.metric, impl=self.impl, fused=fused)
+        if q <= batch:
+            return run(queries)
+        parts = []
+        for lo in range(0, q, batch):
+            chunk = queries[lo:lo + batch]
+            short = batch - chunk.shape[0]
+            if short:                                   # pad the tail batch
+                chunk = jnp.pad(chunk, ((0, short), (0, 0)))
+            ids, scores = run(chunk)
+            parts.append((ids[:batch - short], scores[:batch - short]))
+        return (jnp.concatenate([i for i, _ in parts]),
+                jnp.concatenate([s for _, s in parts]))
 
     def search_rows(self, rows, k: int = 10, *, nprobe: int | None = None,
                     brute_force: bool = False) -> tuple[jax.Array, jax.Array]:

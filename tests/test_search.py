@@ -381,3 +381,19 @@ def test_dirty_listener_rows_and_full_flag():
     n_events = len(events)
     inc.apply_labels(label_delta_from_numpy(np.array([3]), np.array([0])))
     assert len(events) == n_events                 # no-op flip: no event
+
+
+def test_ivf_search_batches_match_one_batch(monkeypatch):
+    """Query sets larger than the gather budget are split into equal
+    batches (tail padded); results match one unsplit search exactly."""
+    import repro.search.index as index_mod
+
+    s = sample_sbm(600, seed=5)
+    idx = ClassPartitionedIndex.build(_embed(s), s.labels, s.num_classes)
+    qs = _embed(s)[:37]
+    ids1, sc1 = idx.search(qs, 5)
+    per_query = idx.nprobe * idx.bucket_capacity * 128 * 4
+    monkeypatch.setattr(index_mod, "GATHER_BUDGET_BYTES", 8 * per_query)
+    ids8, sc8 = idx.search(qs, 5)                  # 5 batches of 8 rows
+    np.testing.assert_array_equal(np.asarray(ids8), np.asarray(ids1))
+    np.testing.assert_array_equal(np.asarray(sc8), np.asarray(sc1))
