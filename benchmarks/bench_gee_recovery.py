@@ -14,14 +14,13 @@ Three scenarios, one JSON (``BENCH_serve.json``):
   scaling measured is replication, not intra-op threads), and measures
   aggregate read QPS at each replica count.  ``--min-scaling`` gates the
   2-replica speedup (CI uses 1.6; pass 0 on single-core boxes).
-
-Every child process is pinned to the CPU (several processes cannot share
-one accelerator), and each replica worker reports the platform it ran on:
-the rows and the JSON name it, so these numbers are never read as device
-numbers.
 * **load shedding** -- drives an in-process ``ReplicaRouter`` over
   bounded-queue services past saturation and checks every rejected read is
   *counted* (``shed + served == attempted``), never silently dropped.
+
+Every child process is pinned to the CPU (several processes cannot share
+one accelerator), and each child reports the platform it ran on: the rows
+and the JSON name it, so these numbers are never read as device numbers.
 
   PYTHONPATH=src JAX_PLATFORMS=cpu python benchmarks/bench_gee_recovery.py \
       [--sbm 400] [--replicas 1,2] [--min-scaling 1.6] [--json BENCH_serve.json]
@@ -87,6 +86,9 @@ def bench_kill_and_recover(args) -> dict:
     r = _run_stream(args, ref_dir)
     if r.returncode != 0:
         raise SystemExit(f"reference stream failed:\n{r.stdout}\n{r.stderr}")
+    stream_platform = next((tok.split("=", 1)[1]
+                            for tok in r.stdout.split()
+                            if tok.startswith("platform=")), "unknown")
 
     cmd = [sys.executable, "-m", "repro.launch.gee_stream",
            *_stream_args(args, kill_dir)]
@@ -142,7 +144,7 @@ def bench_kill_and_recover(args) -> dict:
     neighbor_recall = recall_at_k(ids_r, sc_r, ids_b, sc_b)
 
     row = {
-        "stream_platform": env["JAX_PLATFORMS"],
+        "stream_platform": stream_platform,
         "killed_mid_stream": killed,
         "watermark_ref": int(ref.inc.applied_seq),
         "watermark_recovered": int(rec.inc.applied_seq),
@@ -152,7 +154,7 @@ def bench_kill_and_recover(args) -> dict:
         "t_recover_state": t_recover,
         "resumed": resumed_line.strip(),
     }
-    print(f"kill-and-recover: max|dZ|={max_err:.2e}  "
+    print(f"kill-and-recover ({stream_platform}): max|dZ|={max_err:.2e}  "
           f"neighbor recall={neighbor_recall:.3f}  "
           f"recover={t_recover*1e3:.1f} ms")
     if max_err > args.tol:

@@ -129,7 +129,7 @@ def phase_fit(ds, opts):
     import jax.numpy as jnp
 
     from repro.core.api import GEEEmbedder
-    from repro.core.plan import GEEPlan, PreparedGraph
+    from repro.core.plan import PreparedGraph
     from repro.kernels.gee_fused import gee_spmm_fused
     from repro.kernels.platform import interpret_mode
 
@@ -141,10 +141,6 @@ def phase_fit(ds, opts):
     ref_s = time.perf_counter() - t0
 
     prep = PreparedGraph.wrap(ds.edges)
-    plan = GEEPlan.build(prep, k, opts, backend="auto")
-    if (plan.backend, plan.fused) != ("pallas", True) or interpret_mode():
-        raise AssertionError(f"auto resolved to backend={plan.backend} "
-                             f"fused={plan.fused} on this TPU")
     bell, pack_s = _timed(lambda: prep.bucketed_ell(False))
     widest = max(bell.buckets, key=lambda b: b.cols.size)
     rows, width = widest.cols.shape
@@ -154,15 +150,20 @@ def phase_fit(ds, opts):
 
     results = {}
     for backend in ("auto", "sparse_jax"):
+        emb = GEEEmbedder(num_classes=k, options=opts, backend=backend)
+
         def fit():
-            return GEEEmbedder(num_classes=k, options=opts,
-                               backend=backend).fit(prep, labels).transform()
+            return emb.fit(prep, labels).transform()
         z, cold_s = _timed(fit)
         z, warm_s = _timed(fit)
+        plan = emb.plan                # what the timed fit actually ran
+        if backend == "auto" and ((plan.backend, plan.fused)
+                                  != ("pallas", True) or interpret_mode()):
+            raise AssertionError(f"auto resolved to backend={plan.backend} "
+                                 f"fused={plan.fused} on this TPU")
         err = _max_err(z, z_ref)
-        _emit(phase="a_fit", backend=backend,
-              resolved=plan.backend if backend == "auto" else backend,
-              fused=plan.fused if backend == "auto" else False,
+        _emit(phase="a_fit", backend=backend, resolved=plan.backend,
+              fused=plan.fused,
               buckets=len(bell.buckets) if backend == "auto" else None,
               pack_s=pack_s if backend == "auto" else None,
               cold_s=cold_s, warm_s=warm_s, compile_s_est=cold_s - warm_s,

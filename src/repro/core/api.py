@@ -69,6 +69,7 @@ class GEEEmbedder:
     _chunked: Optional[object] = dataclasses.field(default=None, repr=False)
     _labels: Optional[jax.Array] = dataclasses.field(default=None, repr=False)
     _z: Optional[jax.Array] = dataclasses.field(default=None, repr=False)
+    _plan: Optional[GEEPlan] = dataclasses.field(default=None, repr=False)
     _inc: Optional[IncrementalGEE] = dataclasses.field(default=None,
                                                        repr=False)
     _index: Optional[object] = dataclasses.field(default=None, repr=False)
@@ -97,6 +98,7 @@ class GEEEmbedder:
         self._chunked = None
         self._labels = jnp.asarray(labels, jnp.int32)
         self._z = None
+        self._plan = None
         self._inc = None
         self._reset_index()
         return self
@@ -125,6 +127,7 @@ class GEEEmbedder:
         self._prepared = None
         self._labels = jnp.asarray(labels, jnp.int32)
         self._z = None
+        self._plan = None
         self._inc = None
         self._reset_index()
         return self
@@ -166,6 +169,13 @@ class GEEEmbedder:
     def incremental(self) -> Optional[IncrementalGEE]:
         """The live streaming state (None until ``partial_fit`` is called)."""
         return self._inc
+
+    @property
+    def plan(self) -> Optional[GEEPlan]:
+        """The plan the last in-memory ``transform`` ran (None before it,
+        and for file-backed, 'distributed' and 'streamed_sharded' fits):
+        ``plan.backend`` / ``plan.fused`` say what ``backend`` resolved to."""
+        return self._plan
 
     @property
     def prepared(self) -> Optional[PreparedGraph]:
@@ -339,10 +349,11 @@ class GEEEmbedder:
         # Everything else is one plan over the shared PreparedGraph, so a
         # refit / option change / backend switch reuses all prep artifacts
         # (the chunked route reuses its cached chunk manifest too).
-        return GEEPlan.build(
+        self._plan = GEEPlan.build(
             self._prepared, self.num_classes, self.options,
             backend=self.backend, chunk_edges=self.chunk_edges,
-            prefetch_windows=self.prefetch_windows).execute(labels)
+            prefetch_windows=self.prefetch_windows)
+        return self._plan.execute(labels)
 
 
 def node_features(edges: EdgeList, labels, num_classes: int,

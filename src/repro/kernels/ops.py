@@ -2,11 +2,11 @@
 
 ``gee_pallas`` mirrors the semantics of ``repro.core.gee.gee_sparse_jax``
 exactly (same options, same -1-label convention) but routes the contraction
-through the ``gee_spmm`` kernel and the correlation step through the shared
-epilogue's Pallas path (``repro.core.epilogue.row_l2_normalize`` with
-``impl="pallas"``, i.e. the ``row_norm`` kernel).  Both drivers pack the
-*base* graph: diagonal augmentation folds in as degrees + 1 and the shared
-``diag_aug_epilogue`` term, as in the fused and streaming backends.
+through the ``gee_spmm`` kernel and the tail through the shared epilogue
+(``repro.core.epilogue.apply_epilogue`` with ``impl="pallas"``, i.e. the
+``row_norm`` kernel).  Both drivers pack the *base* graph: diagonal
+augmentation folds in as degrees + 1 and the epilogue's diag-aug term, as
+in the fused and streaming backends.
 On CPU the kernels run in interpret mode (Python evaluation of the kernel
 body); on TPU they compile to Mosaic (see :mod:`repro.kernels.platform`).
 
@@ -25,23 +25,13 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.epilogue import (diag_aug_epilogue, inv_sqrt_degrees,
-                                 row_l2_normalize)
+from repro.core.epilogue import apply_epilogue, inv_sqrt_degrees
 from repro.core.gee import GEEOptions, class_weight_inv
 from repro.graph.containers import ELL, EdgeList
 from repro.graph.ell import (BucketedELL, edges_to_bucketed_ell, edges_to_ell,
                              ell_planes)
 from repro.kernels.gee_spmm import choose_block_sizes, gee_spmm
 from repro.kernels.platform import interpret_mode
-
-
-def _epilogue(z, labels, winv, dinv, opts: GEEOptions, interpret: bool):
-    """Diag-aug term, then the Pallas row norm (the staged epilogue)."""
-    if opts.diag_aug:
-        z = diag_aug_epilogue(z, labels, winv, dinv)
-    if opts.correlation:
-        z = row_l2_normalize(z, impl="pallas", interpret=interpret)
-    return z
 
 
 def gee_pallas_from_ell(ell: ELL, labels: jax.Array, num_classes: int,
@@ -69,7 +59,7 @@ def gee_pallas_from_ell(ell: ELL, labels: jax.Array, num_classes: int,
     ylab, contrib = ell_planes(cols, vals, labels, winv)
     z = gee_spmm(ylab, contrib, num_classes, block_rows=block_rows,
                  block_deg=block_deg, deg_sub=None, interpret=interpret)[:n]
-    return _epilogue(z, labels, winv, dinv[:n], opts, interpret)
+    return apply_epilogue(z, labels, winv, dinv[:n], opts=opts, impl="pallas")
 
 
 def gee_pallas_from_bucketed(bell: BucketedELL, labels: jax.Array,
@@ -115,7 +105,7 @@ def gee_pallas_from_bucketed(bell: BucketedELL, labels: jax.Array,
                        block_deg=block_deg if block_deg is not None else bd,
                        deg_sub=None, interpret=interpret)
         z = z.at[b.row_ids].add(out)
-    return _epilogue(z[:n], labels, winv, dinv, opts, interpret)
+    return apply_epilogue(z[:n], labels, winv, dinv, opts=opts, impl="pallas")
 
 
 def gee_pallas(edges: EdgeList, labels, num_classes: int,

@@ -30,7 +30,7 @@ formula fallback, all behind an ``lru_cache`` so a sweep over many batch
 shapes stays within a handful of entries.
 
 On CPU the kernels run in interpret mode; ``impl="auto"`` therefore routes
-to the pure-JAX fallback (identical formulas, tested equivalent) on CPU and
+to the pure-JAX fallback (the same dot helpers, tested equivalent) on CPU and
 to the kernels on TPU (:func:`repro.kernels.platform.interpret_mode`).
 """
 
@@ -243,7 +243,7 @@ def pairwise_scores(queries: jax.Array, database: jax.Array,
 def _pairwise_jax(queries, database, valid, metric):
     q = queries.astype(jnp.float32)
     x = database.astype(jnp.float32)
-    dot = q @ x.T
+    dot = _pairwise_dot(q, x)
     qn2 = jnp.sum(q * q, axis=1, keepdims=True)
     xn2 = jnp.sum(x * x, axis=1)[None, :]
     s = _scores_from_parts(dot, qn2, xn2, metric)
@@ -306,7 +306,7 @@ def gathered_scores(queries: jax.Array, cand: jax.Array, mask: jax.Array, *,
 def _gathered_jax(queries, cand, mask, metric):
     q = queries.astype(jnp.float32)
     c = cand.astype(jnp.float32)
-    dot = jnp.einsum("qmk,qk->qm", c, q)
+    dot = _gathered_dot(c, q)
     qn2 = jnp.sum(q * q, axis=1, keepdims=True)
     cn2 = jnp.sum(c * c, axis=2)
     s = _scores_from_parts(dot, qn2, cn2, metric)

@@ -130,7 +130,8 @@ def main(argv=None):
     rng, su, du, wu = st["rng"], st["su"], st["du"], st["wu"]
     n_stream, n_base = st["n_stream"], st["n_base"]
     print(f"{name}: N={edges.num_nodes} K={k} [{opts.tag()}]  "
-          f"base E={n_base} streaming E={n_stream} in batches of {args.batch}")
+          f"base E={n_base} streaming E={n_stream} in batches of {args.batch}"
+          f"  platform={jax.default_backend()}")
 
     n_labels = max(1, int(round(args.batch * args.label_frac))) \
         if args.label_frac > 0 else 0

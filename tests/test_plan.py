@@ -362,6 +362,24 @@ def test_embedder_consumes_prepared():
     assert z1.shape == z2.shape
 
 
+def test_embedder_exposes_resolved_plan():
+    from repro.core.api import GEEEmbedder
+
+    edges = _random_edges()
+    labels = _random_labels()
+    emb = GEEEmbedder(num_classes=4, options=OPTS_ALL, backend="auto")
+    assert emb.fit(edges, labels).plan is None        # nothing ran yet
+    z = np.asarray(emb.transform())
+    plan = emb.plan
+    assert plan.backend != "auto"
+    assert plan.backend == GEEPlan.build(emb.prepared, 4, OPTS_ALL,
+                                         backend="auto").backend
+    ref = np.asarray(gee(edges, labels, 4, OPTS_ALL))
+    np.testing.assert_allclose(z, ref, atol=1e-5)
+    emb.fit(edges, labels)                            # a refit forgets it
+    assert emb.plan is None
+
+
 # ---------------------------------------------------------------------------
 # shared epilogue numerics
 # ---------------------------------------------------------------------------
