@@ -19,6 +19,7 @@ from repro.core.gee import GEEOptions, class_counts
 from repro.core.incremental import Delta, DirtyRowTracker, IncrementalGEE
 from repro.core.plan import GEEPlan, PreparedGraph
 from repro.graph.containers import EdgeList
+from repro.obs import trace as obs_trace
 
 
 @dataclasses.dataclass
@@ -117,15 +118,17 @@ class GEEEmbedder:
                                     open_edge_list)
 
         chunk = self.chunk_edges or DEFAULT_CHUNK_EDGES
-        self._chunked = open_edge_list(path, chunk_edges=chunk, **open_kw)
-        if labels is None:
-            labels = load_labels(path)
+        with obs_trace.span("fold.open", chunk_edges=int(chunk)):
+            self._chunked = open_edge_list(path, chunk_edges=chunk,
+                                           **open_kw)
             if labels is None:
-                raise ValueError(
-                    f"no labels given and no sidecar {path}.labels.npy")
+                labels = load_labels(path)
+                if labels is None:
+                    raise ValueError(
+                        f"no labels given and no sidecar {path}.labels.npy")
+            self._labels = jnp.asarray(labels, jnp.int32)
         self._edges = None
         self._prepared = None
-        self._labels = jnp.asarray(labels, jnp.int32)
         self._z = None
         self._plan = None
         self._inc = None
@@ -349,10 +352,12 @@ class GEEEmbedder:
         # Everything else is one plan over the shared PreparedGraph, so a
         # refit / option change / backend switch reuses all prep artifacts
         # (the chunked route reuses its cached chunk manifest too).
-        self._plan = GEEPlan.build(
-            self._prepared, self.num_classes, self.options,
-            backend=self.backend, chunk_edges=self.chunk_edges,
-            prefetch_windows=self.prefetch_windows)
+        with obs_trace.span("plan.resolve", backend=self.backend) as sp:
+            self._plan = GEEPlan.build(
+                self._prepared, self.num_classes, self.options,
+                backend=self.backend, chunk_edges=self.chunk_edges,
+                prefetch_windows=self.prefetch_windows)
+            sp.tag(resolved=self._plan.backend, fused=self._plan.fused)
         return self._plan.execute(labels)
 
 

@@ -55,6 +55,7 @@ from repro.core.fold import (both_directions, fold_degrees, fold_z,
 from repro.core.gee import GEEOptions
 from repro.graph.io import (ChunkedEdgeList, DEFAULT_CHUNK_EDGES,
                             load_labels, open_edge_list)
+from repro.obs import trace as obs_trace
 
 # Deprecated aliases: the fold primitives moved to repro.core.fold.
 _both_directions = both_directions
@@ -84,8 +85,9 @@ def gee_chunked(chunked: ChunkedEdgeList, labels, num_classes: int,
     k = int(num_classes)
     z, winv, dinv = stream_fold(chunked, labels, k, opts,
                                 prefetch_windows=prefetch_windows)
-    return finalize(z, jnp.asarray(labels, jnp.int32), winv, dinv,
-                    num_classes=k, opts=opts, impl=impl)
+    with obs_trace.span("fold.epilogue", n=chunked.num_nodes, k=k):
+        return finalize(z, jnp.asarray(labels, jnp.int32), winv, dinv,
+                        num_classes=k, opts=opts, impl=impl)
 
 
 def gee_chunked_from_file(path: str, labels=None, num_classes: int | None = None,

@@ -39,7 +39,6 @@ True
 
 from __future__ import annotations
 
-import time
 from functools import partial
 
 import jax
@@ -178,40 +177,39 @@ def stream_fold(source, labels, num_classes: int, opts: GEEOptions, *,
     und = source.undirected
     source = _prefetch(source, prefetch_windows)
     tr = obs_trace.get_tracer()
-    traced = tr.enabled
     degree_windows = 0
 
     if opts.laplacian:
         deg = jnp.zeros((n,), jnp.float32)
-        for i, w in enumerate(source.windows()):             # pass 1
-            with tr.span("fold.window", phase="degrees", idx=i,
-                         edges=int(w.num_edges)):
-                deg = fold_degrees(deg, w.src, w.dst, w.weight,
-                                   undirected=und)
-                if traced:       # async dispatch: sync for honest spans
-                    deg.block_until_ready()
-            degree_windows += 1
+        with tr.span("fold.pass", phase="degrees") as sp:
+            edges_seen = 0
+            for i, w in enumerate(source.windows()):         # pass 1
+                with tr.span("fold.window", phase="degrees", idx=i,
+                             edges=int(w.num_edges)):
+                    deg = fold_degrees(deg, w.src, w.dst, w.weight,
+                                       undirected=und)
+                degree_windows += 1
+                edges_seen += int(w.num_edges)
+            sp.tag(windows=degree_windows, edges=edges_seen)
         if opts.diag_aug:
             deg = deg + 1.0
         dinv = inv_sqrt_degrees(deg)
     else:
         dinv = jnp.ones((n,), jnp.float32)
 
-    t_scatter = time.perf_counter()
     scatter_windows = edges_folded = 0
     z = jnp.zeros((n * k,), jnp.float32)
-    for i, w in enumerate(source.windows()):                 # pass 2
-        with tr.span("fold.window", phase="scatter", idx=i,
-                     edges=int(w.num_edges)):
-            z = fold_z(z, w.src, w.dst, w.weight, labels, winv, dinv,
-                       num_classes=k, undirected=und)
-            if traced:
-                z.block_until_ready()
-        scatter_windows += 1
-        edges_folded += int(w.num_edges)
+    with tr.span("fold.pass", phase="scatter") as sp:
+        for i, w in enumerate(source.windows()):             # pass 2
+            with tr.span("fold.window", phase="scatter", idx=i,
+                         edges=int(w.num_edges)):
+                z = fold_z(z, w.src, w.dst, w.weight, labels, winv, dinv,
+                           num_classes=k, undirected=und)
+            scatter_windows += 1
+            edges_folded += int(w.num_edges)
+        sp.tag(windows=scatter_windows, edges=edges_folded)
 
-    _record_fold(degree_windows, scatter_windows, edges_folded,
-                 time.perf_counter() - t_scatter)
+    _record_fold(degree_windows, scatter_windows, edges_folded)
     return z, winv, dinv
 
 
@@ -221,8 +219,8 @@ def _prefetch(source, depth: int | None, stage=None, sharding=None):
     return prefetch_windows(source, depth, stage=stage, sharding=sharding)
 
 
-def _record_fold(degree_windows: int, scatter_windows: int, edges: int,
-                 scatter_s: float) -> None:
+def _record_fold(degree_windows: int, scatter_windows: int,
+                 edges: int) -> None:
     """Registry bookkeeping shared by the streaming folds.  Runs once per
     fold (never per window), so the always-on cost is a few lock
     acquisitions.
@@ -231,18 +229,13 @@ def _record_fold(degree_windows: int, scatter_windows: int, edges: int,
     pass walks every window exactly once in every configuration); the
     laplacian degree pre-pass is tracked separately as
     ``fold.windows.degrees`` so two-pass folds no longer double-count
-    windows or edges.  ``fold.edges`` and the ``fold.edges_per_sec``
-    gauge come from the scatter pass only: edges folded over scatter-pass
-    wall time (honest under tracing, where stage syncs are forced;
-    untraced it includes async dispatch overlap).
+    windows or edges.  ``fold.edges`` counts the scatter pass's edges.
     """
     reg = obs_metrics.get_registry()
     reg.counter("fold.windows").inc(scatter_windows)
     reg.counter("fold.windows.scatter").inc(scatter_windows)
     reg.counter("fold.windows.degrees").inc(degree_windows)
     reg.counter("fold.edges").inc(edges)
-    if scatter_s > 0 and edges:
-        reg.gauge("fold.edges_per_sec").set(edges / scatter_s)
 
 
 # ---------------------------------------------------------------------------
@@ -434,20 +427,21 @@ def gee_streamed_sharded(source, labels, num_classes: int,
     pf = _prefetch(source, prefetch_windows,
                    sharding=NamedSharding(mesh, P(axes)))
     tr = obs_trace.get_tracer()
-    traced = tr.enabled
     degree_windows = 0
 
     if opts.laplacian:
         deg_parts = jnp.zeros((p, n_pad), jnp.float32, device=parts)
-        for i, w in enumerate(pf.windows(pad_to=g)):         # pass 1
-            with tr.span("fold.window", phase="degrees", idx=i, shards=p,
-                         edges=int(w.num_edges)):
-                deg_parts = _fold_degrees_sharded(
-                    deg_parts, w.src, w.dst, w.weight,
-                    mesh=mesh, axes=axes, undirected=und)
-                if traced:
-                    deg_parts.block_until_ready()
-            degree_windows += 1
+        with tr.span("fold.pass", phase="degrees", shards=p) as sp:
+            edges_seen = 0
+            for i, w in enumerate(pf.windows(pad_to=g)):     # pass 1
+                with tr.span("fold.window", phase="degrees", idx=i,
+                             shards=p, edges=int(w.num_edges)):
+                    deg_parts = _fold_degrees_sharded(
+                        deg_parts, w.src, w.dst, w.weight,
+                        mesh=mesh, axes=axes, undirected=und)
+                degree_windows += 1
+                edges_seen += int(w.num_edges)
+            sp.tag(windows=degree_windows, edges=edges_seen)
         deg = deg_parts.sum(axis=0)
         if opts.diag_aug:
             deg = deg + 1.0
@@ -455,7 +449,6 @@ def gee_streamed_sharded(source, labels, num_classes: int,
     else:
         dinv = jnp.ones((n_pad,), jnp.float32)
 
-    t_scatter = time.perf_counter()
     scatter_windows = edges_folded = 0
     z_parts = jnp.zeros((p, n_pad * k), jnp.float32, device=parts)
     if local_backend == "pallas":
@@ -473,41 +466,35 @@ def gee_streamed_sharded(source, labels, num_classes: int,
             jax.block_until_ready((cols, vals))
             return PlaneWindow(int(w.num_edges), cols, vals)
 
-        pf_planes = _prefetch(source, prefetch_windows, stage=plane_stage)
-        for i, w in enumerate(pf_planes.windows(pad_to=g)):  # pass 2
-            with tr.span("fold.window", phase="scatter", idx=i, shards=p,
-                         edges=int(w.num_edges)):
-                if isinstance(w, PlaneWindow):               # pre-packed
-                    cols, vals = w.cols, w.vals
-                else:                                        # synchronous
-                    cols, vals = _window_plane(w, p, n_pad, und)
-                z_parts = _fold_plane_sharded(
-                    z_parts, cols, vals, labels, winv, dinv,
-                    mesh=mesh, axes=axes, num_classes=k,
-                    interpret=interpret)
-                if traced:
-                    z_parts.block_until_ready()
-            scatter_windows += 1
-            edges_folded += int(w.num_edges)
+        windows = _prefetch(source, prefetch_windows,
+                            stage=plane_stage).windows(pad_to=g)
     else:
-        for i, w in enumerate(pf.windows(pad_to=g)):         # pass 2
+        windows = pf.windows(pad_to=g)
+    with tr.span("fold.pass", phase="scatter", shards=p) as sp:
+        for i, w in enumerate(windows):                      # pass 2
             with tr.span("fold.window", phase="scatter", idx=i, shards=p,
                          edges=int(w.num_edges)):
-                z_parts = _fold_z_sharded(
-                    z_parts, w.src, w.dst, w.weight, labels, winv, dinv,
-                    mesh=mesh, axes=axes, num_classes=k, undirected=und)
-                if traced:
-                    z_parts.block_until_ready()
+                if local_backend == "segment_sum":
+                    z_parts = _fold_z_sharded(
+                        z_parts, w.src, w.dst, w.weight, labels, winv, dinv,
+                        mesh=mesh, axes=axes, num_classes=k, undirected=und)
+                else:
+                    if isinstance(w, PlaneWindow):           # pre-packed
+                        cols, vals = w.cols, w.vals
+                    else:                                    # synchronous
+                        cols, vals = _window_plane(w, p, n_pad, und)
+                    z_parts = _fold_plane_sharded(
+                        z_parts, cols, vals, labels, winv, dinv,
+                        mesh=mesh, axes=axes, num_classes=k,
+                        interpret=interpret)
             scatter_windows += 1
             edges_folded += int(w.num_edges)
+        sp.tag(windows=scatter_windows, edges=edges_folded)
 
     with tr.span("fold.combine", shards=p, n=n, k=k):
         z = _combine_sharded(z_parts, labels, winv, dinv, mesh=mesh,
                              axes=axes, num_classes=k, opts=opts)
-        if traced:
-            z.block_until_ready()
-    _record_fold(degree_windows, scatter_windows, edges_folded,
-                 time.perf_counter() - t_scatter)
+    _record_fold(degree_windows, scatter_windows, edges_folded)
     return z[:n]
 
 

@@ -51,7 +51,6 @@ from __future__ import annotations
 
 import dataclasses
 import os
-import time
 from typing import Dict, Iterable, Mapping, Optional, Tuple, Union
 
 import jax
@@ -86,18 +85,6 @@ def _laplacian_fold(edges: EdgeList) -> EdgeList:
 
 
 _add_self_loops_jit = jax.jit(add_self_loops)
-
-
-def _block_tree(x):
-    """``jax.block_until_ready`` on the array leaves of a stage result.
-
-    Host-only results (chunk manifests, numpy triples) have nothing to
-    wait for and are skipped by leaf type, so a device fault raised while
-    waiting on a real array still surfaces."""
-    for leaf in jax.tree_util.tree_leaves(x):
-        if isinstance(leaf, jax.Array):
-            leaf.block_until_ready()
-    return x
 
 
 def _chunk_key(chunk_edges: int | None) -> int:
@@ -254,12 +241,20 @@ class PreparedGraph:
                           lambda: edges_to_ell(self.augmented(diag_aug)))
 
     def bucketed_ell(self, diag_aug: bool = False):
-        """Degree-bucketed ELL packing of the (augmented) graph."""
+        """Degree-bucketed ELL packing of the (augmented) graph; the
+        host packing runs under a ``plan.pack.bucketed_ell`` span tagged
+        with its packed ``rows``, ``slots`` and real ``edges``."""
         from repro.graph.ell import edges_to_bucketed_ell
 
-        return self._memo(
-            ("bucketed_ell", bool(diag_aug)),
-            lambda: edges_to_bucketed_ell(self.augmented(diag_aug)))
+        def build():
+            with obs_trace.span("plan.pack.bucketed_ell",
+                                diag_aug=bool(diag_aug)) as sp:
+                bell = edges_to_bucketed_ell(self.augmented(diag_aug))
+                sp.tag(rows=sum(int(b.cols.shape[0]) for b in bell.buckets),
+                       slots=bell.total_slots, edges=bell.total_edges,
+                       buckets=len(bell.buckets))
+            return bell
+        return self._memo(("bucketed_ell", bool(diag_aug)), build)
 
     def chunked(self, chunk_edges: int | None = None):
         """The streaming backend's chunk manifest over the valid prefix
@@ -430,10 +425,6 @@ class GEEPlan:
     # streaming backends only: windows staged ahead by background threads
     # (resolved by build(); None defers to the env default at execute time)
     prefetch_windows: Optional[int] = None
-    # per-stage wall times (ms) of the last *traced* execution; a mutable
-    # cell on a frozen plan -- excluded from eq/repr, never reassigned
-    _timings: dict = dataclasses.field(default_factory=dict, compare=False,
-                                       repr=False)
 
     @staticmethod
     def build(graph: PreparedGraph | EdgeList, num_classes: int,
@@ -538,89 +529,52 @@ class GEEPlan:
                                  detail=f"impl={self.impl}"))
         return tuple(out)
 
-    def describe(self, timings: bool = False) -> str:
-        """One line per stage, e.g. for ``--plan`` CLI output.
-
-        ``timings=True`` appends each stage's wall time from the last
-        *traced* execution (run :meth:`execute` with the tracer enabled
-        first -- untraced executions skip the stage-boundary syncs that
-        make per-stage times honest, so they record nothing).
-        """
+    def describe(self) -> str:
+        """One line per stage, e.g. for ``--plan`` CLI output."""
         head = (f"GEEPlan(backend={self.backend}"
                 + (", fused" if self.fused else "")
                 + f", opts={self.opts.tag()}, "
                 f"N={self.prepared.num_nodes}, "
                 f"E={self.prepared.num_edges}, K={self.num_classes})")
-        timed = self._timings if timings else {}
         lines = [head]
         for s in self.stages:
-            line = (f"  [{s.kind:8s}] {s.name}"
-                    + (" (cached)" if s.cached else "")
-                    + (f" -- {s.detail}" if s.detail else ""))
-            if s.name in timed:
-                line += f"  [{timed[s.name]:.2f} ms]"
-            lines.append(line)
-        if timings:
-            if "total_ms" in timed:
-                lines.append(f"  total {timed['total_ms']:.2f} ms "
-                             f"(stage syncs forced by tracing)")
-            else:
-                lines.append("  (no traced execution yet: enable the "
-                             "tracer, then execute())")
+            lines.append(f"  [{s.kind:8s}] {s.name}"
+                         + (" (cached)" if s.cached else "")
+                         + (f" -- {s.detail}" if s.detail else ""))
         return "\n".join(lines)
-
-    @property
-    def last_timings(self) -> dict:
-        """``{stage_name: ms, "total_ms": ms}`` from the last traced
-        execution (empty until one happens)."""
-        return dict(self._timings)
 
     # -- execution -----------------------------------------------------------
     def _stage(self, kind: str, name: str, cached: bool, fn):
         """Run one pipeline stage under a ``plan.stage.<name>`` span.
 
-        With the tracer disabled this is a plain call.  With it enabled,
-        the stage result is blocked-on before the span closes -- jax
-        dispatch is async, so without the sync every stage but the last
-        would bill its device time to whoever touches the value next.
+        The span never waits for the device: jax dispatch is async, so it
+        times the stage's host work (dispatch, packing, cache lookups);
+        the device's time for the stage is in the profiler's device
+        trace, on the same clock.
         """
-        tr = obs_trace.get_tracer()
-        if not tr.enabled:
+        with obs_trace.span("plan.stage." + name, kind=kind, cached=cached):
             return fn()
-        t0 = time.perf_counter()
-        with tr.span("plan.stage." + name, kind=kind, cached=cached):
-            out = _block_tree(fn())
-        self._timings[name] = (time.perf_counter() - t0) * 1e3
-        return out
 
     def execute(self, labels) -> jax.Array:
         """Run the staged pipeline for one labels vector.
 
-        With the global tracer enabled, every stage runs under a
-        ``plan.stage.*`` span (tagged with its prep-cache status) inside
-        one ``plan.execute`` root span, and per-stage wall times are kept
-        for :meth:`describe(timings=True) <describe>`.
+        Every stage runs under a ``plan.stage.*`` span (tagged with its
+        prep-cache status) inside one ``plan.execute`` root span; the
+        ``plan.*`` counters move on every execution.
         """
-        tr = obs_trace.get_tracer()
-        if not tr.enabled:
-            return self._execute_stages(labels)
-        self._timings.clear()
         p = self.prepared
         hits0, misses0 = p._hits, p._misses
-        t0 = time.perf_counter()
-        with tr.span("plan.execute", backend=self.backend,
-                     n=p.num_nodes, e=p.num_edges, k=self.num_classes,
-                     opts=self.opts.tag(), fused=self.fused) as root:
-            z = _block_tree(self._execute_stages(labels))
+        with obs_trace.span("plan.execute", backend=self.backend,
+                            n=p.num_nodes, e=p.num_edges,
+                            k=self.num_classes, opts=self.opts.tag(),
+                            fused=self.fused) as root:
+            z = self._execute_stages(labels)
             root.tag(cache_hits=p._hits - hits0,
                      cache_misses=p._misses - misses0)
-        total_ms = (time.perf_counter() - t0) * 1e3
-        self._timings["total_ms"] = total_ms
         reg = obs_metrics.get_registry()
         reg.counter("plan.executions").inc()
         reg.counter("plan.cache_hits").inc(p._hits - hits0)
         reg.counter("plan.cache_misses").inc(p._misses - misses0)
-        reg.histogram("plan.execute_ms").observe(total_ms)
         return z
 
     def _execute_stages(self, labels) -> jax.Array:

@@ -54,6 +54,7 @@ class ELLBucket:
              point at the dump row ``num_nodes`` (see BucketedELL.num_nodes).
     num_rows: static number of *real* rows (<= R_pad).
     width:    static tile width of this bucket.
+    num_edges: static number of real (nonzero-weight) entries packed.
     """
 
     cols: jax.Array
@@ -61,6 +62,12 @@ class ELLBucket:
     row_ids: jax.Array
     num_rows: int = dataclasses.field(metadata=dict(static=True))
     width: int = dataclasses.field(metadata=dict(static=True))
+    num_edges: int = dataclasses.field(metadata=dict(static=True))
+
+    @property
+    def slots(self) -> int:
+        """Stored slots, padding rows included: R_pad * width."""
+        return int(self.cols.shape[0]) * self.width
 
 
 @jax.tree_util.register_dataclass
@@ -78,7 +85,11 @@ class BucketedELL:
 
     @property
     def total_slots(self) -> int:
-        return sum(int(b.cols.shape[0]) * b.width for b in self.buckets)
+        return sum(b.slots for b in self.buckets)
+
+    @property
+    def total_edges(self) -> int:
+        return sum(b.num_edges for b in self.buckets)
 
 
 # ---------------------------------------------------------------------------
@@ -187,7 +198,7 @@ def edges_to_bucketed_ell(edges: EdgeList, row_pad: int = SUBLANE,
         buckets.append(ELLBucket(
             cols=jnp.asarray(cols), vals=jnp.asarray(vals),
             row_ids=jnp.asarray(row_ids), num_rows=int(rows.size),
-            width=int(width)))
+            width=int(width), num_edges=int(emask.sum())))
     return BucketedELL(buckets=tuple(buckets), num_nodes=n)
 
 

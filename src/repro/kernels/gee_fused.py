@@ -47,12 +47,13 @@ from jax.experimental import pallas as pl
 from repro.core.epilogue import EPS_NORM, apply_epilogue, inv_sqrt_degrees
 from repro.core.gee import GEEOptions, class_weight_inv
 from repro.graph.containers import ELL
-from repro.graph.ell import BucketedELL, ell_planes
+from repro.graph.ell import BucketedELL, ELLBucket, ell_planes
 from repro.kernels.autotune import REGISTRY, ceil_to, pow2_bucket
 from repro.kernels.gee_spmm import (LANE, _block_sizes_formula, clamp_blocks,
                                     contract_tile, measured_block_search,
                                     measure_enabled)
 from repro.kernels.platform import interpret_mode
+from repro.obs import trace as obs_trace
 
 ENV_FUSED = "REPRO_GEE_FUSED"
 
@@ -201,7 +202,7 @@ def _gee_fused_jit(ylab, contrib, rowlab, dadd, num_classes: int,
     dadd_p = dadd_p.at[:n, 0].set(dadd.astype(jnp.float32))
 
     grid = (n_pad // block_rows, d_pad // block_deg)
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_gee_fused_kernel, num_classes_pad=k_pad,
                           deg_sub=deg_sub, diag_aug=diag_aug,
                           correlation=correlation, eps=EPS_NORM),
@@ -215,13 +216,24 @@ def _gee_fused_jit(ylab, contrib, rowlab, dadd, num_classes: int,
         out_specs=pl.BlockSpec((block_rows, k_pad), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, k_pad), jnp.float32),
         interpret=interpret,
-    )(ylab_p, contrib_p, rowlab_p, dadd_p)
+        name=KERNEL_NAME,
+    )
+    with jax.named_scope(KERNEL_NAME):
+        out = call(ylab_p, contrib_p, rowlab_p, dadd_p)
     return out[:n, :num_classes]
 
 
 # ---------------------------------------------------------------------------
 # full-pipeline drivers (what the plan layer executes)
 # ---------------------------------------------------------------------------
+
+def bucket_span(idx: int, b: ELLBucket):
+    """The ``plan.bucket`` span of one bucket's work, tagged with the
+    packing's counts: packed ``rows``, ``width``, ``slots`` (rows x
+    width) and real ``edges``."""
+    return obs_trace.span("plan.bucket", idx=idx, rows=int(b.cols.shape[0]),
+                          width=b.width, slots=b.slots, edges=b.num_edges)
+
 
 def _diag_addend(labels, winv, dinv, diag_aug: bool):
     """Per-row (rowlab, dadd) epilogue operands; disabled -> empty/zero."""
@@ -281,7 +293,10 @@ def gee_fused_from_bucketed(bell: BucketedELL, labels: jax.Array,
     -- completes inside a single launch, and results scatter back with
     ``.set`` (never ``.add``).  Degree-0 rows live in no bucket; the
     residual fixup below applies the shared epilogue arithmetic to them
-    host-free in O(#isolated * K).
+    host-free in O(#isolated * K).  Each bucket's eager ops run under its
+    ``plan.bucket`` span (:func:`bucket_span`), split into the gathers,
+    plane building, launch and write-back, so a profiler trace names the
+    device's idle gaps down to the bucket.
     """
     if interpret is None:
         interpret = interpret_mode()
@@ -291,52 +306,60 @@ def gee_fused_from_bucketed(bell: BucketedELL, labels: jax.Array,
     labels_ext = jnp.concatenate(        # dump row n -> label -1 (no-op)
         [labels, jnp.full((1,), -1, jnp.int32)])
 
-    if opts.laplacian or opts.diag_aug:
-        deg = jnp.zeros((n + 1,), jnp.float32)
-        for b in bell.buckets:
-            deg = deg.at[b.row_ids].add(jnp.sum(b.vals, axis=1))
-        deg = deg[:n]
-        if opts.diag_aug:
-            deg = deg + 1.0
-    if opts.laplacian:
-        dinv = inv_sqrt_degrees(deg)
-    else:
-        dinv = jnp.ones((n,), jnp.float32)
-    dinv_ext = jnp.concatenate([dinv, jnp.zeros((1,), jnp.float32)])
+    with obs_trace.span("plan.bucket.degrees", buckets=len(bell.buckets)):
+        if opts.laplacian or opts.diag_aug:
+            deg = jnp.zeros((n + 1,), jnp.float32)
+            for b in bell.buckets:
+                deg = deg.at[b.row_ids].add(jnp.sum(b.vals, axis=1))
+            deg = deg[:n]
+            if opts.diag_aug:
+                deg = deg + 1.0
+        if opts.laplacian:
+            dinv = inv_sqrt_degrees(deg)
+        else:
+            dinv = jnp.ones((n,), jnp.float32)
+        dinv_ext = jnp.concatenate([dinv, jnp.zeros((1,), jnp.float32)])
 
     z = jnp.zeros((n + 1, num_classes), jnp.float32)
-    for b in bell.buckets:
-        vals = b.vals
-        if opts.laplacian:
-            safe_rows = jnp.minimum(b.row_ids, n - 1)
-            vals = vals * dinv[safe_rows][:, None] \
-                        * dinv[jnp.clip(b.cols, 0, n - 1)]
-        ylab, contrib = ell_planes(b.cols, vals, labels, winv)
-        rowlab, dadd = _diag_addend(labels_ext[b.row_ids], winv,
-                                    dinv_ext[b.row_ids], opts.diag_aug)
-        br, bd, ds = choose_fused_block_sizes(int(b.cols.shape[0]), b.width,
-                                              num_classes)
-        out = gee_spmm_fused(
-            ylab, contrib, rowlab, dadd, num_classes,
-            correlation=opts.correlation,
-            block_rows=block_rows if block_rows is not None else br,
-            block_deg=block_deg if block_deg is not None else bd,
-            deg_sub=ds, interpret=interpret)
-        # disjoint real rows; bucket-padding rows all target the dump row
-        # with all-zero planes and a -1 rowlab, so they write exact zeros
-        z = z.at[b.row_ids].set(out)
+    for i, b in enumerate(bell.buckets):
+        with bucket_span(i, b):
+            with obs_trace.span("plan.bucket.scale"):
+                vals = b.vals
+                if opts.laplacian:
+                    safe_rows = jnp.minimum(b.row_ids, n - 1)
+                    vals = vals * dinv[safe_rows][:, None] \
+                                * dinv[jnp.clip(b.cols, 0, n - 1)]
+            with obs_trace.span("plan.bucket.planes"):
+                ylab, contrib = ell_planes(b.cols, vals, labels, winv)
+                rowlab, dadd = _diag_addend(labels_ext[b.row_ids], winv,
+                                            dinv_ext[b.row_ids],
+                                            opts.diag_aug)
+            with obs_trace.span("plan.bucket.launch"):
+                br, bd, ds = choose_fused_block_sizes(
+                    int(b.cols.shape[0]), b.width, num_classes)
+                out = gee_spmm_fused(
+                    ylab, contrib, rowlab, dadd, num_classes,
+                    correlation=opts.correlation,
+                    block_rows=block_rows if block_rows is not None else br,
+                    block_deg=block_deg if block_deg is not None else bd,
+                    deg_sub=ds, interpret=interpret)
+            # disjoint real rows; bucket-padding rows all target the dump
+            # row with all-zero planes and a -1 rowlab, so they write zeros
+            with obs_trace.span("plan.bucket.scatter"):
+                z = z.at[b.row_ids].set(out)
     z = z[:n]
 
     # Residual fixup: degree-0 rows (no bucket) still owe the diag-aug
     # term and the row norm -- the identical shared-epilogue arithmetic.
-    covered = jnp.zeros((n + 1,), bool)
-    for b in bell.buckets:
-        covered = covered.at[b.row_ids].set(True)
-    uncovered = ~covered[:n]
-    if opts.diag_aug or opts.correlation:
-        z_res = apply_epilogue(jnp.zeros((n, num_classes), jnp.float32),
-                               labels, winv, dinv, opts=opts, impl="jnp")
-        z = jnp.where(uncovered[:, None], z_res, z)
+    with obs_trace.span("plan.bucket.residual"):
+        covered = jnp.zeros((n + 1,), bool)
+        for b in bell.buckets:
+            covered = covered.at[b.row_ids].set(True)
+        uncovered = ~covered[:n]
+        if opts.diag_aug or opts.correlation:
+            z_res = apply_epilogue(jnp.zeros((n, num_classes), jnp.float32),
+                                   labels, winv, dinv, opts=opts, impl="jnp")
+            z = jnp.where(uncovered[:, None], z_res, z)
     return z
 
 

@@ -297,7 +297,7 @@ def _gee_spmm_jit(ylab: jax.Array, contrib: jax.Array, num_classes: int,
     contrib_p = contrib_p.at[:n, :d].set(contrib.astype(jnp.float32))
 
     grid = (n_pad // block_rows, d_pad // block_deg)
-    out = pl.pallas_call(
+    call = pl.pallas_call(
         functools.partial(_gee_spmm_kernel, num_classes_pad=k_pad,
                           deg_sub=deg_sub),
         grid=grid,
@@ -308,5 +308,8 @@ def _gee_spmm_jit(ylab: jax.Array, contrib: jax.Array, num_classes: int,
         out_specs=pl.BlockSpec((block_rows, k_pad), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, k_pad), jnp.float32),
         interpret=interpret,
-    )(ylab_p, contrib_p)
+        name=KERNEL_NAME,
+    )
+    with jax.named_scope(KERNEL_NAME):
+        out = call(ylab_p, contrib_p)
     return out[:n, :num_classes]

@@ -30,8 +30,10 @@ from repro.core.gee import GEEOptions, class_weight_inv
 from repro.graph.containers import ELL, EdgeList
 from repro.graph.ell import (BucketedELL, edges_to_bucketed_ell, edges_to_ell,
                              ell_planes)
+from repro.kernels.gee_fused import bucket_span
 from repro.kernels.gee_spmm import choose_block_sizes, gee_spmm
 from repro.kernels.platform import interpret_mode
+from repro.obs import trace as obs_trace
 
 
 def gee_pallas_from_ell(ell: ELL, labels: jax.Array, num_classes: int,
@@ -79,32 +81,39 @@ def gee_pallas_from_bucketed(bell: BucketedELL, labels: jax.Array,
     n = bell.num_nodes
     winv = class_weight_inv(labels, num_classes)
 
-    dinv = jnp.ones((n,), jnp.float32)
-    if opts.laplacian:
-        # degree = total out-weight per node, assembled across buckets
-        deg = jnp.zeros((n + 1,), jnp.float32)
-        for b in bell.buckets:
-            deg = deg.at[b.row_ids].add(jnp.sum(b.vals, axis=1))
-        deg = deg[:n]
-        if opts.diag_aug:
-            deg = deg + 1.0                               # the unpacked loop
-        dinv = inv_sqrt_degrees(deg)
+    with obs_trace.span("plan.bucket.degrees", buckets=len(bell.buckets)):
+        dinv = jnp.ones((n,), jnp.float32)
+        if opts.laplacian:
+            # degree = total out-weight per node, assembled across buckets
+            deg = jnp.zeros((n + 1,), jnp.float32)
+            for b in bell.buckets:
+                deg = deg.at[b.row_ids].add(jnp.sum(b.vals, axis=1))
+            deg = deg[:n]
+            if opts.diag_aug:
+                deg = deg + 1.0                           # the unpacked loop
+            dinv = inv_sqrt_degrees(deg)
 
     z = jnp.zeros((n + 1, num_classes), jnp.float32)
-    for b in bell.buckets:
-        vals = b.vals
-        if opts.laplacian:
-            safe_rows = jnp.minimum(b.row_ids, n - 1)
-            vals = vals * dinv[safe_rows][:, None] \
-                        * dinv[jnp.clip(b.cols, 0, n - 1)]
-        ylab, contrib = ell_planes(b.cols, vals, labels, winv)
-        br, bd, _ = choose_block_sizes(int(b.cols.shape[0]), b.width,
-                                       num_classes)
-        out = gee_spmm(ylab, contrib, num_classes,
-                       block_rows=block_rows if block_rows is not None else br,
-                       block_deg=block_deg if block_deg is not None else bd,
-                       deg_sub=None, interpret=interpret)
-        z = z.at[b.row_ids].add(out)
+    for i, b in enumerate(bell.buckets):
+        with bucket_span(i, b):
+            with obs_trace.span("plan.bucket.scale"):
+                vals = b.vals
+                if opts.laplacian:
+                    safe_rows = jnp.minimum(b.row_ids, n - 1)
+                    vals = vals * dinv[safe_rows][:, None] \
+                                * dinv[jnp.clip(b.cols, 0, n - 1)]
+            with obs_trace.span("plan.bucket.planes"):
+                ylab, contrib = ell_planes(b.cols, vals, labels, winv)
+            with obs_trace.span("plan.bucket.launch"):
+                br, bd, _ = choose_block_sizes(int(b.cols.shape[0]),
+                                               b.width, num_classes)
+                out = gee_spmm(
+                    ylab, contrib, num_classes,
+                    block_rows=block_rows if block_rows is not None else br,
+                    block_deg=block_deg if block_deg is not None else bd,
+                    deg_sub=None, interpret=interpret)
+            with obs_trace.span("plan.bucket.scatter"):
+                z = z.at[b.row_ids].add(out)
     return apply_epilogue(z[:n], labels, winv, dinv, opts=opts, impl="pallas")
 
 

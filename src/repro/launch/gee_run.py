@@ -168,27 +168,21 @@ def main(argv=None):
             plan = GEEPlan.build(prep, k, opts, backend=b,
                                  chunk_edges=args.chunk_edges,
                                  prefetch_windows=args.prefetch_windows)
-            if not args.trace:
-                print("\n".join("  " + ln for ln in
-                                plan.describe().splitlines()))
+            print("\n".join("  " + ln for ln in
+                            plan.describe().splitlines()))
         if b == "chunked" and args.chunk_edges:
             from repro.core.chunked import gee_chunked
             fn = lambda: gee_chunked(prep.chunked(args.chunk_edges),
                                      labels, k, opts,
                                      prefetch_windows=args.prefetch_windows)
         elif plan is not None:
-            # Execute through the printed plan so --trace populates its
-            # per-stage timings (describe(timings=True) below).
-            fn = lambda: plan.execute(labels)
+            fn = lambda: plan.execute(labels)     # the printed plan
         else:
             fn = lambda: gee(prep, labels, k, opts, backend=b)
         dt = _time(fn)
         z = np.asarray(fn())
         print(f"  {b:12s}: {dt*1e3:9.1f} ms   Z[{z.shape[0]}x{z.shape[1]}] "
               f"norm {np.linalg.norm(z):.4f}")
-        if plan is not None and args.trace:
-            print("\n".join("  " + ln for ln in
-                            plan.describe(timings=True).splitlines()))
     obs_cli.finish(args)
 
 

@@ -7,10 +7,11 @@ two observability flags through these three hooks:
 * :func:`setup` enables the global tracer when ``--trace`` was given
   (before any instrumented work runs);
 * :func:`finish` writes the Chrome/Perfetto trace JSON and the
-  metrics-registry snapshot, printing where they went plus the
-  plan-stage span coverage (the trace-completeness figure the
-  acceptance gate checks: stage spans should sum to >= 90% of the
-  ``plan.execute`` total).
+  metrics-registry snapshot, printing where they went.
+
+The trace holds host spans on the host's clock; the device's time for
+the same work is in a ``jax.profiler`` capture, which carries the same
+spans as annotations.
 """
 
 from __future__ import annotations
@@ -36,39 +37,15 @@ def setup(args) -> None:
         obs_trace.enable()
 
 
-def plan_span_coverage(tracer: obs_trace.Tracer | None = None):
-    """Fraction of the last ``plan.execute`` span covered by its direct
-    ``plan.stage.*`` children, or ``None`` when no plan ran under the
-    tracer.  This is the acceptance figure ``gee_run --trace`` prints:
-    stage spans summing to ~1.0x the total means the trace accounts for
-    the fit time instead of hiding it between spans."""
-    tr = tracer if tracer is not None else obs_trace.get_tracer()
-    events = tr.events()
-    roots = [e for e in events if e.name == "plan.execute"]
-    if not roots:
-        return None
-    root = roots[-1]
-    lo, hi = root.ts_us, root.ts_us + root.dur_us
-    stage_us = sum(
-        e.dur_us for e in events
-        if e.name.startswith("plan.stage.") and e.tid == root.tid
-        and e.depth == root.depth + 1
-        and lo <= e.ts_us and e.ts_us + e.dur_us <= hi + 1.0)
-    return stage_us / root.dur_us if root.dur_us > 0 else None
-
-
 def finish(args) -> None:
     """Write the artifacts ``--trace`` / ``--metrics-out`` asked for."""
     tr = obs_trace.get_tracer()
     if getattr(args, "trace", None) and tr.enabled:
-        cov = plan_span_coverage(tr)
         n_events = len(tr.events())
         tr.write(args.trace)
         line = f"  trace: {n_events} spans -> {args.trace}"
         if tr.dropped:
             line += f"  ({tr.dropped} dropped past max_events)"
-        if cov is not None:
-            line += f"  [plan stages cover {cov * 100:.1f}% of fit time]"
         print(line)
     if getattr(args, "metrics_out", None):
         obs_metrics.get_registry().write_json(args.metrics_out)

@@ -9,7 +9,7 @@ the same three primitives behind one process-global registry:
 
 * :class:`Counter` -- monotone event count (``wal.appends``).
 * :class:`Gauge`   -- last-written value, for derived rates
-  (``fold.edges_per_sec``).
+  (``serve.queries_per_sec``).
 * :class:`Histogram` -- bounded latency/occupancy distribution: exact
   ``count``/``sum``/``min``/``max`` over *all* observations, plus a
   fixed-size reservoir (Vitter's Algorithm R, seeded per histogram so

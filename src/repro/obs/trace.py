@@ -14,18 +14,24 @@ Design constraints, in order:
 
   1. **Near-zero cost when disabled.**  The instrumentation lives on hot
      paths (every plan stage, every stream window).  ``span()`` on a
-     disabled tracer returns one preallocated no-op context manager --
-     no allocation, no lock, no clock read.  The measured overhead gate
-     lives in :func:`tracer_overhead_pct` (CI asserts <= 2% on a full
-     ``gee()`` fit).
+     disabled tracer with no profiler collecting returns one
+     preallocated no-op context manager -- no allocation, no lock, no
+     clock read.  The measured overhead gate lives in
+     :func:`tracer_overhead_pct` (CI asserts <= 2% on a full ``gee()``
+     fit).
   2. **Correct nesting, even under exceptions.**  Spans per thread form
      a stack; ``__exit__`` always pops and always records, so a span
      that dies by exception still closes and its parents still nest
      around it.
-  3. **Device alignment.**  When tracing is enabled and jax is present,
-     every span also enters a ``jax.profiler.TraceAnnotation``, so a
-     simultaneous ``jax.profiler.trace()`` capture shows these host
-     spans on the same timeline as the device kernels they launched.
+  3. **Device alignment.**  Every span enters a
+     ``jax.profiler.TraceAnnotation`` carrying its tags as metadata, so a
+     ``jax.profiler.trace()`` capture shows these host spans on the same
+     clock as the device ops they launched.  This holds while the
+     profiler collects *whether or not the tracer is enabled*: a
+     disabled tracer then returns an annotation-only span that records
+     nothing in memory.  Spans never synchronize with the device; a
+     span times what the host did, and device time comes from the
+     profiler's device trace.
 
 The process-global default tracer (:func:`get_tracer` /
 :func:`set_tracer` / :func:`enable` / :func:`span`) is what the library
@@ -46,6 +52,7 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
+import sys
 import threading
 import time
 from typing import Callable, Optional
@@ -91,6 +98,29 @@ class _NullSpan:
 _NULL = _NullSpan()
 
 
+class _AnnotationSpan:
+    """A disabled tracer's span while the profiler collects: the
+    ``TraceAnnotation`` alone (tags as its metadata), recorded nowhere
+    in memory."""
+
+    __slots__ = ("_annot",)
+
+    def __init__(self, annotation):
+        self._annot = annotation
+
+    def __enter__(self):
+        self._annot.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._annot.__exit__(*exc)
+        return False
+
+    def tag(self, **kw):
+        """Forward mid-span tags to the annotation's metadata."""
+        self._annot.set_metadata(**_metadata(kw))
+
+
 class _LiveSpan:
     """An open span: records itself on exit (exception or not)."""
 
@@ -107,18 +137,20 @@ class _LiveSpan:
         stack = tr._stack()
         self._depth = len(stack)
         stack.append(self)
-        if tr.annotate_device:
-            annot = _trace_annotation(self.name)
-            if annot is not None:
-                annot.__enter__()
-                self._annot = annot
+        annotation = tr.annotate_device and _collecting_annotation()
+        if annotation:
+            self._annot = annotation(self.name, **_metadata(self.args))
+            self._annot.__enter__()
         self._t0 = time.perf_counter_ns()
         return self
 
     def tag(self, **kw) -> None:
         """Attach tags discovered mid-span (e.g. a cache-hit flag that is
-        only known after the lookup ran)."""
+        only known after the lookup ran); they reach the annotation's
+        metadata too."""
         self.args.update(kw)
+        if self._annot is not None:
+            self._annot.set_metadata(**_metadata(kw))
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.perf_counter_ns()
@@ -140,27 +172,50 @@ class _LiveSpan:
         return False
 
 
-def _trace_annotation(name: str):
-    """A ``jax.profiler.TraceAnnotation`` when jax is importable (obs
-    itself stays dependency-free -- the import is deferred and failure
-    tolerated)."""
-    try:
-        from jax.profiler import TraceAnnotation
-    except Exception:                                 # pragma: no cover
-        return None
-    return TraceAnnotation(name)
+# the annotation encodes metadata as "name#k=v,k=v#": keep values clear of it
+_METADATA_SAFE = str.maketrans({",": ";", "=": ":", "#": "_"})
+
+
+def _metadata(tags: dict) -> dict:
+    """Span tags as annotation metadata (string values made safe for the
+    annotation's encoding)."""
+    return {k: v.translate(_METADATA_SAFE) if isinstance(v, str) else v
+            for k, v in tags.items()}
+
+
+def _collecting_annotation():
+    """``jax.profiler.TraceAnnotation`` while a profiler trace collects,
+    else None.
+
+    Never imports jax: before ``jax.profiler`` is loaded no trace can be
+    running, so the answer is None without the import's cost."""
+    prof = sys.modules.get("jax.profiler")
+    if prof is not None and prof.TraceAnnotation.is_enabled():
+        return prof.TraceAnnotation
+    return None
+
+
+def _disabled_span(tracer: "Tracer", name: str, tags: dict):
+    """What ``span()`` returns on a disabled tracer."""
+    annotation = tracer.annotate_device and _collecting_annotation()
+    if annotation:
+        return _AnnotationSpan(annotation(name, **_metadata(tags)))
+    return _NULL
 
 
 class Tracer:
     """Thread-safe span recorder with Chrome/Perfetto JSON export.
 
     ``enabled=False`` (the default) makes :meth:`span` return a shared
-    no-op context manager; flipping :meth:`enable` starts recording.
+    no-op context manager, or an annotation-only span while a
+    ``jax.profiler`` trace collects; flipping :meth:`enable` starts
+    recording.
     ``max_events`` bounds memory on long streams -- events past the
     bound are dropped and counted (``dropped``), never silently.
-    ``annotate_device=True`` additionally wraps every span in
-    ``jax.profiler.TraceAnnotation`` so host spans line up with device
-    kernels inside a ``jax.profiler.trace()`` capture.
+    ``annotate_device=True`` (the default) wraps every span, enabled or
+    not, in a ``jax.profiler.TraceAnnotation`` while a
+    ``jax.profiler.trace()`` capture collects, so host spans line up
+    with the device ops they launched; ``False`` keeps them out of it.
     """
 
     def __init__(self, enabled: bool = False, max_events: int = 1_000_000,
@@ -191,9 +246,10 @@ class Tracer:
     # -- recording -----------------------------------------------------------
     def span(self, name: str, **tags):
         """Open a span (context manager).  On a disabled tracer this is
-        the no-op singleton -- the near-zero hot-path cost."""
+        the no-op singleton -- the near-zero hot-path cost -- unless the
+        profiler is collecting, when it is an annotation-only span."""
         if not self.enabled:
-            return _NULL
+            return _disabled_span(self, name, tags)
         return _LiveSpan(self, name, tags)
 
     def _stack(self) -> list:
@@ -268,12 +324,13 @@ def span(name: str, **tags):
     """Open a span on the global default tracer.
 
     The disabled path is one attribute load + one branch + the kwargs
-    dict -- cheap enough for per-window instrumentation
-    (:func:`tracer_overhead_pct` is the measured guarantee).
+    dict + the profiler's ``is_enabled()`` check -- cheap enough for
+    per-window instrumentation (:func:`tracer_overhead_pct` is the
+    measured guarantee).
     """
     t = _default
     if not t.enabled:
-        return _NULL
+        return _disabled_span(t, name, tags)
     return _LiveSpan(t, name, tags)
 
 

@@ -13,13 +13,16 @@ that runs this file loads the TPU compiler.
 """
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.gee_fused import KERNEL_NAME as FUSED_NAME
 from repro.kernels.gee_fused import choose_fused_block_sizes, gee_spmm_fused
+from repro.kernels.gee_spmm import KERNEL_NAME as STAGED_NAME
 from repro.kernels.gee_spmm import choose_block_sizes, gee_spmm
 from repro.kernels.row_norm import row_norm
 from repro.kernels.topk_score import (choose_gathered_blocks,
@@ -74,6 +77,40 @@ def test_gee_spmm_fused_compiles(one_chip, width, k):
     _compile(fn, one_chip, ((N_ROWS, width), jnp.int32),
              ((N_ROWS, width), jnp.float32), ((N_ROWS,), jnp.int32),
              ((N_ROWS,), jnp.float32))
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_kernel_named_in_compiled_hlo(one_chip, fused):
+    """The kernel's custom call carries the kernel's own name inside any
+    enclosing jit, so a device trace's op line names it."""
+    width, k = 128, 5
+    if fused:
+        name = FUSED_NAME
+        br, bd, ds = choose_fused_block_sizes(N_ROWS, width, k)
+
+        def outer(ylab, contrib, rowlab, dadd):
+            return 2.0 * gee_spmm_fused(
+                ylab, contrib, rowlab, dadd, k, correlation=True,
+                block_rows=br, block_deg=bd, deg_sub=ds, interpret=False)
+        shapes = (((N_ROWS, width), jnp.int32),
+                  ((N_ROWS, width), jnp.float32),
+                  ((N_ROWS,), jnp.int32), ((N_ROWS,), jnp.float32))
+    else:
+        name = STAGED_NAME
+        br, bd, ds = choose_block_sizes(N_ROWS, width, k)
+
+        def outer(ylab, contrib):
+            return 2.0 * gee_spmm(ylab, contrib, k, block_rows=br,
+                                  block_deg=bd, deg_sub=ds, interpret=False)
+        shapes = (((N_ROWS, width), jnp.int32),
+                  ((N_ROWS, width), jnp.float32))
+    text = _compile(outer, one_chip, *shapes)
+    calls = [ln for ln in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert len(calls) == 1
+    instr = re.match(r"\s*(?:ROOT )?%?([\w.-]+) = ", calls[0]).group(1)
+    assert instr.split(".")[0] == name, instr
+    assert f"/{name}/" in re.search(r'op_name="([^"]*)"', calls[0]).group(1)
 
 
 @pytest.mark.parametrize("k", [5, 172])
