@@ -113,6 +113,9 @@ class PreparedGraph:
                                        correlation flag never affects prep)
       * ``ell(diag_aug)`` /
         ``bucketed_ell(diag_aug)``     the Pallas kernel's packing planes
+      * ``bucket_scaling(laplacian,
+        diag_aug)``                    the bucketed drivers' label-free
+                                       part: d^{-1/2} and scaled planes
       * ``chunked(chunk_edges)``       the chunk manifest of the streaming
                                        backend
       * ``host_arrays()``              the valid-prefix numpy triple the
@@ -255,6 +258,19 @@ class PreparedGraph:
                        buckets=len(bell.buckets))
             return bell
         return self._memo(("bucketed_ell", bool(diag_aug)), build)
+
+    def bucket_scaling(self, laplacian: bool, diag_aug: bool):
+        """The base packing's label-independent ``BucketScaling``:
+        ``d^{-1/2}``, the Laplacian-scaled bucket planes, each bucket's row
+        scales and the degree-0 mask, keyed on ``(laplacian, diag_aug)``
+        (correlation never enters, as for ``effective_edges``)."""
+        from repro.kernels.gee_fused import scale_buckets
+
+        return self._memo(
+            ("bucket_scaling", bool(laplacian), bool(diag_aug)),
+            lambda: scale_buckets(self.bucketed_ell(False),
+                                  laplacian=bool(laplacian),
+                                  diag_aug=bool(diag_aug)))
 
     def chunked(self, chunk_edges: int | None = None):
         """The streaming backend's chunk manifest over the valid prefix
@@ -479,6 +495,11 @@ class GEEPlan:
                 "prep", "bucketed_ell",
                 cached=p.is_cached(("bucketed_ell", False)),
                 detail="degree-bucketed ELL packing (host, O(E))"))
+            out.append(PlanStage(
+                "prep", "bucket_scaling",
+                cached=p.is_cached(("bucket_scaling", o.laplacian,
+                                    o.diag_aug)),
+                detail="degrees + laplacian-scaled bucket planes (device)"))
             if self.fused:
                 out.append(PlanStage(
                     "compute", "gee_spmm_fused",
@@ -601,20 +622,25 @@ class GEEPlan:
                 "prep", "bucketed_ell",
                 p.is_cached(("bucketed_ell", False)),
                 lambda: p.bucketed_ell(False))
+            scaling = self._stage(
+                "prep", "bucket_scaling",
+                p.is_cached(("bucket_scaling", o.laplacian, o.diag_aug)),
+                lambda: p.bucket_scaling(o.laplacian, o.diag_aug))
             if self.fused:
                 from repro.kernels.gee_fused import gee_fused_from_bucketed
 
                 return self._stage(
                     "compute", "gee_spmm_fused", False,
                     lambda: gee_fused_from_bucketed(
-                        bell, jnp.asarray(labels), k, o))
+                        bell, jnp.asarray(labels), k, o, scaling=scaling))
             from repro.kernels.ops import gee_pallas_from_bucketed
 
             z = self._stage(
                 "compute", "gee_spmm", False,
                 lambda: gee_pallas_from_bucketed(
                     bell, jnp.asarray(labels), k,
-                    GEEOptions(laplacian=o.laplacian, diag_aug=o.diag_aug)))
+                    GEEOptions(laplacian=o.laplacian, diag_aug=o.diag_aug),
+                    scaling=scaling))
             if o.correlation:      # epilogue honors this plan's impl choice
                 z = self._stage(
                     "epilogue", "row_l2_normalize", False,

@@ -36,8 +36,10 @@ cost model only selects the fused stage on a TPU.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import os
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
@@ -280,9 +282,76 @@ def gee_fused_from_ell(ell: ELL, labels: jax.Array, num_classes: int,
     return z[:n]
 
 
+@dataclasses.dataclass(frozen=True)
+class BucketScaling:
+    """The label-independent part of a bucketed fit, for one packing and
+    one ``(laplacian, diag_aug)`` pair: built once per prepared graph
+    (``PreparedGraph.bucket_scaling``), reused by every fit.
+
+    dinv:     [N] f32 ``d^{-1/2}`` of the (loop-augmented) degrees; ones
+              when ``laplacian`` is off.
+    vals:     per bucket, the Laplacian-scaled ``vals`` plane (the
+              bucket's own ``vals`` when ``laplacian`` is off).
+    row_dinv: per bucket, ``dinv`` at its ``row_ids`` (0 at the dump row),
+              the diag-aug addend's row scale.
+    uncovered: [N] bool, the degree-0 rows no bucket holds.
+    """
+
+    dinv: jax.Array
+    vals: Tuple[jax.Array, ...]
+    row_dinv: Tuple[jax.Array, ...]
+    uncovered: jax.Array
+    laplacian: bool
+    diag_aug: bool
+
+    def check(self, opts: GEEOptions) -> None:
+        if (self.laplacian, self.diag_aug) != (bool(opts.laplacian),
+                                               bool(opts.diag_aug)):
+            raise ValueError(
+                f"scaling built for laplacian={self.laplacian}, "
+                f"diag_aug={self.diag_aug}; options ask {opts.tag()}")
+
+
+def scale_buckets(bell: BucketedELL, *, laplacian: bool,
+                  diag_aug: bool) -> BucketScaling:
+    """Build the :class:`BucketScaling` of a packing: the degree fold
+    under ``plan.bucket.degrees`` and each bucket's scaled plane under a
+    ``plan.bucket.scale`` span tagged with its ``idx``."""
+    n = bell.num_nodes
+    with obs_trace.span("plan.bucket.degrees", buckets=len(bell.buckets)):
+        dinv = jnp.ones((n,), jnp.float32)
+        if laplacian:
+            # degree = total out-weight per node, assembled across buckets
+            deg = jnp.zeros((n + 1,), jnp.float32)
+            for b in bell.buckets:
+                deg = deg.at[b.row_ids].add(jnp.sum(b.vals, axis=1))
+            deg = deg[:n]
+            if diag_aug:
+                deg = deg + 1.0            # the un-packed self loop
+            dinv = inv_sqrt_degrees(deg)
+        dinv_ext = jnp.concatenate([dinv, jnp.zeros((1,), jnp.float32)])
+        covered = jnp.zeros((n + 1,), bool)
+        for b in bell.buckets:
+            covered = covered.at[b.row_ids].set(True)
+    vals, row_dinv = [], []
+    for i, b in enumerate(bell.buckets):
+        with obs_trace.span("plan.bucket.scale", idx=i):
+            v = b.vals
+            if laplacian:
+                safe_rows = jnp.minimum(b.row_ids, n - 1)
+                v = v * dinv[safe_rows][:, None] \
+                      * dinv[jnp.clip(b.cols, 0, n - 1)]
+            vals.append(v)
+            row_dinv.append(dinv_ext[b.row_ids])
+    return BucketScaling(dinv=dinv, vals=tuple(vals),
+                         row_dinv=tuple(row_dinv), uncovered=~covered[:n],
+                         laplacian=bool(laplacian), diag_aug=bool(diag_aug))
+
+
 def gee_fused_from_bucketed(bell: BucketedELL, labels: jax.Array,
                             num_classes: int,
                             opts: GEEOptions = GEEOptions(), *,
+                            scaling: BucketScaling | None = None,
                             block_rows: int | None = None,
                             block_deg: int | None = None,
                             interpret: bool | None = None) -> jax.Array:
@@ -293,46 +362,33 @@ def gee_fused_from_bucketed(bell: BucketedELL, labels: jax.Array,
     -- completes inside a single launch, and results scatter back with
     ``.set`` (never ``.add``).  Degree-0 rows live in no bucket; the
     residual fixup below applies the shared epilogue arithmetic to them
-    host-free in O(#isolated * K).  Each bucket's eager ops run under its
-    ``plan.bucket`` span (:func:`bucket_span`), split into the gathers,
-    plane building, launch and write-back, so a profiler trace names the
+    host-free in O(#isolated * K).  ``scaling`` is the packing's
+    label-independent :class:`BucketScaling` (built here when absent), so
+    a fit does only label-dependent work.  Each bucket's eager ops run
+    under its ``plan.bucket`` span (:func:`bucket_span`), split into plane
+    building, launch and write-back, so a profiler trace names the
     device's idle gaps down to the bucket.
     """
     if interpret is None:
         interpret = interpret_mode()
+    if scaling is None:
+        scaling = scale_buckets(bell, laplacian=opts.laplacian,
+                                diag_aug=opts.diag_aug)
+    scaling.check(opts)
     labels = jnp.asarray(labels, jnp.int32)
     n = bell.num_nodes
     winv = class_weight_inv(labels, num_classes)
     labels_ext = jnp.concatenate(        # dump row n -> label -1 (no-op)
         [labels, jnp.full((1,), -1, jnp.int32)])
 
-    with obs_trace.span("plan.bucket.degrees", buckets=len(bell.buckets)):
-        if opts.laplacian or opts.diag_aug:
-            deg = jnp.zeros((n + 1,), jnp.float32)
-            for b in bell.buckets:
-                deg = deg.at[b.row_ids].add(jnp.sum(b.vals, axis=1))
-            deg = deg[:n]
-            if opts.diag_aug:
-                deg = deg + 1.0
-        if opts.laplacian:
-            dinv = inv_sqrt_degrees(deg)
-        else:
-            dinv = jnp.ones((n,), jnp.float32)
-        dinv_ext = jnp.concatenate([dinv, jnp.zeros((1,), jnp.float32)])
-
     z = jnp.zeros((n + 1, num_classes), jnp.float32)
     for i, b in enumerate(bell.buckets):
         with bucket_span(i, b):
-            with obs_trace.span("plan.bucket.scale"):
-                vals = b.vals
-                if opts.laplacian:
-                    safe_rows = jnp.minimum(b.row_ids, n - 1)
-                    vals = vals * dinv[safe_rows][:, None] \
-                                * dinv[jnp.clip(b.cols, 0, n - 1)]
             with obs_trace.span("plan.bucket.planes"):
-                ylab, contrib = ell_planes(b.cols, vals, labels, winv)
+                ylab, contrib = ell_planes(b.cols, scaling.vals[i], labels,
+                                           winv)
                 rowlab, dadd = _diag_addend(labels_ext[b.row_ids], winv,
-                                            dinv_ext[b.row_ids],
+                                            scaling.row_dinv[i],
                                             opts.diag_aug)
             with obs_trace.span("plan.bucket.launch"):
                 br, bd, ds = choose_fused_block_sizes(
@@ -352,17 +408,14 @@ def gee_fused_from_bucketed(bell: BucketedELL, labels: jax.Array,
     # Residual fixup: degree-0 rows (no bucket) still owe the diag-aug
     # term and the row norm -- the identical shared-epilogue arithmetic.
     with obs_trace.span("plan.bucket.residual"):
-        covered = jnp.zeros((n + 1,), bool)
-        for b in bell.buckets:
-            covered = covered.at[b.row_ids].set(True)
-        uncovered = ~covered[:n]
         if opts.diag_aug or opts.correlation:
             z_res = apply_epilogue(jnp.zeros((n, num_classes), jnp.float32),
-                                   labels, winv, dinv, opts=opts, impl="jnp")
-            z = jnp.where(uncovered[:, None], z_res, z)
+                                   labels, winv, scaling.dinv, opts=opts,
+                                   impl="jnp")
+            z = jnp.where(scaling.uncovered[:, None], z_res, z)
     return z
 
 
 __all__ = ["ENV_FUSED", "KERNEL_NAME", "fused_override",
            "choose_fused_block_sizes", "gee_spmm_fused", "gee_fused_from_ell",
-           "gee_fused_from_bucketed"]
+           "gee_fused_from_bucketed", "BucketScaling", "scale_buckets"]

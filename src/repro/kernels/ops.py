@@ -30,7 +30,7 @@ from repro.core.gee import GEEOptions, class_weight_inv
 from repro.graph.containers import ELL, EdgeList
 from repro.graph.ell import (BucketedELL, edges_to_bucketed_ell, edges_to_ell,
                              ell_planes)
-from repro.kernels.gee_fused import bucket_span
+from repro.kernels.gee_fused import BucketScaling, bucket_span, scale_buckets
 from repro.kernels.gee_spmm import choose_block_sizes, gee_spmm
 from repro.kernels.platform import interpret_mode
 from repro.obs import trace as obs_trace
@@ -67,43 +67,32 @@ def gee_pallas_from_ell(ell: ELL, labels: jax.Array, num_classes: int,
 def gee_pallas_from_bucketed(bell: BucketedELL, labels: jax.Array,
                              num_classes: int,
                              opts: GEEOptions = GEEOptions(), *,
+                             scaling: BucketScaling | None = None,
                              block_rows: int | None = None,
                              block_deg: int | None = None,
                              interpret: bool | None = None) -> jax.Array:
     """GEE from a degree-bucketed ELL tiling of the *base* graph: one kernel
     launch per bucket, partial outputs scattered into the [N+1]-row
-    accumulator (row N is the dump row for bucket padding).  Explicit block
-    sizes override the autotuner for every bucket; by default each bucket
-    is tuned on its own (rows, width, K)."""
+    accumulator (row N is the dump row for bucket padding).  ``scaling``
+    is the packing's label-independent ``BucketScaling`` (built here when
+    absent).  Explicit block sizes override the autotuner for every
+    bucket; by default each bucket is tuned on its own (rows, width, K)."""
     if interpret is None:
         interpret = interpret_mode()
+    if scaling is None:
+        scaling = scale_buckets(bell, laplacian=opts.laplacian,
+                                diag_aug=opts.diag_aug)
+    scaling.check(opts)
     labels = jnp.asarray(labels, jnp.int32)
     n = bell.num_nodes
     winv = class_weight_inv(labels, num_classes)
 
-    with obs_trace.span("plan.bucket.degrees", buckets=len(bell.buckets)):
-        dinv = jnp.ones((n,), jnp.float32)
-        if opts.laplacian:
-            # degree = total out-weight per node, assembled across buckets
-            deg = jnp.zeros((n + 1,), jnp.float32)
-            for b in bell.buckets:
-                deg = deg.at[b.row_ids].add(jnp.sum(b.vals, axis=1))
-            deg = deg[:n]
-            if opts.diag_aug:
-                deg = deg + 1.0                           # the unpacked loop
-            dinv = inv_sqrt_degrees(deg)
-
     z = jnp.zeros((n + 1, num_classes), jnp.float32)
     for i, b in enumerate(bell.buckets):
         with bucket_span(i, b):
-            with obs_trace.span("plan.bucket.scale"):
-                vals = b.vals
-                if opts.laplacian:
-                    safe_rows = jnp.minimum(b.row_ids, n - 1)
-                    vals = vals * dinv[safe_rows][:, None] \
-                                * dinv[jnp.clip(b.cols, 0, n - 1)]
             with obs_trace.span("plan.bucket.planes"):
-                ylab, contrib = ell_planes(b.cols, vals, labels, winv)
+                ylab, contrib = ell_planes(b.cols, scaling.vals[i], labels,
+                                           winv)
             with obs_trace.span("plan.bucket.launch"):
                 br, bd, _ = choose_block_sizes(int(b.cols.shape[0]),
                                                b.width, num_classes)
@@ -114,7 +103,8 @@ def gee_pallas_from_bucketed(bell: BucketedELL, labels: jax.Array,
                     deg_sub=None, interpret=interpret)
             with obs_trace.span("plan.bucket.scatter"):
                 z = z.at[b.row_ids].add(out)
-    return apply_epilogue(z[:n], labels, winv, dinv, opts=opts, impl="pallas")
+    return apply_epilogue(z[:n], labels, winv, scaling.dinv, opts=opts,
+                          impl="pallas")
 
 
 def gee_pallas(edges: EdgeList, labels, num_classes: int,

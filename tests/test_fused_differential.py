@@ -32,14 +32,16 @@ try:                                       # only the fuzz test needs it
 except ImportError:                        # pragma: no cover
     HAVE_HYPOTHESIS = False
 
-from repro.core.epilogue import EPS_NORM
+from repro.core.epilogue import EPS_NORM, row_l2_normalize
 from repro.core.gee import ALL_OPTION_SETTINGS, GEEOptions, gee, gee_scipy
-from repro.core.plan import KNOWN_BACKENDS, GEEPlan, select_fused
+from repro.core.plan import (KNOWN_BACKENDS, GEEPlan, PreparedGraph,
+                             select_fused)
 from repro.graph.containers import edge_list_from_numpy, edges_to_ell, symmetrize
 from repro.graph.ell import edges_to_bucketed_ell
 from repro.kernels.autotune import AutotuneRegistry
 from repro.kernels.gee_fused import (gee_fused_from_bucketed,
-                                     gee_fused_from_ell, gee_spmm_fused)
+                                     gee_fused_from_ell, gee_spmm_fused,
+                                     scale_buckets)
 from repro.kernels.ops import gee_pallas_from_bucketed
 from repro.kernels.topk_score import (gathered_scores, masked_topk,
                                       pairwise_scores, scored_topk,
@@ -168,6 +170,42 @@ def test_every_backend_matches_fused(backend, opts):
     out = np.asarray(gee(edges, labels, k, opts, backend=backend))
     np.testing.assert_allclose(out, fused, atol=1e-5,
                                err_msg=f"{backend} vs fused, {opts.tag()}")
+
+
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "staged"])
+@pytest.mark.parametrize("opts", ALL_OPTION_SETTINGS, ids=OPT_IDS)
+def test_plan_scaling_matches_inline_build(fused, opts):
+    """The plan hands the bucketed drivers the prepared graph's memoized
+    scaling; Z on the building fit and on the reusing one is bit for bit
+    the same driver's with the scaling built inline."""
+    edges, labels, k = _fixed_adversarial()
+    prep = PreparedGraph.wrap(edges)
+    plan = GEEPlan.build(prep, k, opts, backend="pallas", fused=fused)
+    zs = [np.asarray(plan.execute(labels)) for _ in range(2)]
+    bell, y = prep.bucketed_ell(False), jnp.asarray(labels)
+    if fused:
+        inline = gee_fused_from_bucketed(bell, y, k, opts, interpret=True)
+    else:                      # the plan's staged route: scatter, then norm
+        inline = gee_pallas_from_bucketed(
+            bell, y, k, GEEOptions(laplacian=opts.laplacian,
+                                   diag_aug=opts.diag_aug), interpret=True)
+        if opts.correlation:
+            inline = row_l2_normalize(inline, impl=plan.impl)
+    for z in zs:
+        assert np.array_equal(z, np.asarray(inline)), opts.tag()
+
+
+@pytest.mark.parametrize("driver", [gee_fused_from_bucketed,
+                                    gee_pallas_from_bucketed],
+                         ids=["fused", "staged"])
+def test_scaling_for_other_options_is_refused(driver):
+    edges, labels, k = _fixed_adversarial()
+    bell = edges_to_bucketed_ell(edges)
+    sc = scale_buckets(bell, laplacian=True, diag_aug=False)
+    with pytest.raises(ValueError, match="scaling built for"):
+        driver(bell, jnp.asarray(labels), k,
+               GEEOptions(laplacian=True, diag_aug=True), scaling=sc,
+               interpret=True)
 
 
 # ---------------------------------------------------------------------------

@@ -494,7 +494,9 @@ def test_stream_traced_fit_adds_no_sync(fresh_obs, sbm_small, sync_counter,
 def test_bucket_spans_carry_packing_counts(fresh_obs, fused):
     """Each ``plan.bucket`` span is tagged with its bucket's packed slots
     and real entries; over the fit they sum to the packing's totals, and
-    the host packing's own span says the same."""
+    the host packing's own span says the same.  The label-independent
+    degree fold and scaling run under the ``bucket_scaling`` prep stage of
+    the first execute only; every fit keeps its per-bucket phases."""
     tracer, _ = fresh_obs
     tracer.enable()
     s = sample_sbm(120, seed=4)
@@ -523,13 +525,39 @@ def test_bucket_spans_carry_packing_counts(fresh_obs, fused):
     assert (pack.args["slots"], pack.args["edges"]) == (bell.total_slots,
                                                         real)
     assert pack.args["rows"] == sum(e.args["rows"] for e in buckets)
-    # every bucket's work runs in its four named phases, nested inside it
-    for phase in ("scale", "planes", "launch", "scatter"):
+    # every bucket's work runs in its three named phases, nested inside it
+    for phase in ("planes", "launch", "scatter"):
         inner = [e for e in events if e.name == "plan.bucket." + phase]
         assert len(inner) == len(buckets)
         assert all(e.depth == buckets[0].depth + 1 for e in inner)
     names = {e.name for e in events}
-    assert "plan.bucket.degrees" in names
+    assert ("plan.bucket.residual" in names) == fused
+    # the one-time build: degrees once, one scale span per bucket, both
+    # inside the bucket_scaling prep stage
+    (stage,) = [e for e in events if e.name == "plan.stage.bucket_scaling"]
+    assert stage.args == {"kind": "prep", "cached": False}
+    built = [e for e in events if e.name in ("plan.bucket.degrees",
+                                             "plan.bucket.scale")]
+    assert [e.name for e in built].count("plan.bucket.degrees") == 1
+    assert sorted(e.args["idx"] for e in built
+                  if e.name == "plan.bucket.scale") \
+        == list(range(len(bell.buckets)))
+    for e in built:
+        assert e.depth > stage.depth
+        assert stage.ts_us <= e.ts_us <= stage.ts_us + stage.dur_us
+
+    # a second fit reuses it: no build spans, the same per-fit spans
+    tracer.clear()
+    z2 = plan.execute(s.labels)
+    np.testing.assert_array_equal(np.asarray(z2), np.asarray(z))
+    events = tracer.events()
+    names = [e.name for e in events]
+    assert "plan.bucket.degrees" not in names
+    assert "plan.bucket.scale" not in names
+    (stage,) = [e for e in events if e.name == "plan.stage.bucket_scaling"]
+    assert stage.args["cached"] is True
+    for phase in ("", ".planes", ".launch", ".scatter"):
+        assert names.count("plan.bucket" + phase) == len(bell.buckets)
     assert ("plan.bucket.residual" in names) == fused
 
 

@@ -15,6 +15,7 @@ from repro.graph.containers import (add_self_loops, edge_list_from_numpy,
                                     symmetrize)
 from repro.kernels.autotune import (AutotuneRegistry, REGISTRY, ceil_to,
                                     pow2_at_least, pow2_bucket)
+from repro.obs.metrics import MetricsRegistry, set_registry
 
 OPTS_ALL = GEEOptions(laplacian=True, diag_aug=True, correlation=True)
 
@@ -200,6 +201,60 @@ def test_plan_stages_and_describe():
     # same plan after execution: the prep artifact is now resident
     assert GEEPlan.build(prep, 4, OPTS_ALL).stages[0].cached
     assert "segment_scatter" in plan.describe()
+
+
+@pytest.mark.pallas_interpret
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "staged"])
+def test_pallas_refit_reuses_bucket_scaling(fused):
+    """The Laplacian scaling is a prep stage built once per prepared
+    graph: a second execute is a memo hit (no new miss, ``plan.cache_hits``
+    up) and ``describe()`` lists the stage as cached; correlation never
+    enters the key."""
+    reg = MetricsRegistry()
+    prev = set_registry(reg)
+    try:
+        prep = PreparedGraph.wrap(_random_edges())
+        labels = _random_labels()
+        plan = GEEPlan.build(prep, 4, OPTS_ALL, backend="pallas",
+                             fused=fused)
+        assert [(s.kind, s.name) for s in plan.stages][:2] == [
+            ("prep", "bucketed_ell"), ("prep", "bucket_scaling")]
+        assert "bucket_scaling (cached)" not in plan.describe()
+        plan.execute(labels)
+        cold = prep.cache_info()
+        assert str(("bucket_scaling", True, True)) in cold["keys"]
+        hits = reg.counter("plan.cache_hits").value
+        plan.execute(labels)
+        assert prep.cache_info()["misses"] == cold["misses"]
+        assert reg.counter("plan.cache_hits").value >= hits + 2
+        assert reg.counter("plan.cache_misses").value == cold["misses"]
+        assert "bucket_scaling (cached)" in plan.describe()
+        GEEPlan.build(prep, 4, GEEOptions(laplacian=True, diag_aug=True),
+                      backend="pallas", fused=fused).execute(labels)
+        assert prep.cache_info()["misses"] == cold["misses"]
+    finally:
+        set_registry(prev)
+
+
+@pytest.mark.parametrize("lap,diag", [(False, False), (False, True),
+                                      (True, False), (True, True)])
+def test_bucket_scaling_shares_the_packing(lap, diag):
+    """Without the Laplacian the scaled planes are the packing's own
+    arrays, not copies; with it they are new planes of the same shape.
+    The degree-0 mask names exactly the rows no bucket holds."""
+    edges = _random_edges(n=70, e=120, seed=3)   # leaves isolated rows
+    prep = PreparedGraph.wrap(edges)
+    bell = prep.bucketed_ell(False)
+    sc = prep.bucket_scaling(lap, diag)
+    assert len(sc.vals) == len(sc.row_dinv) == len(bell.buckets)
+    for v, r, b in zip(sc.vals, sc.row_dinv, bell.buckets):
+        assert (v is b.vals) == (not lap)
+        assert v.shape == b.vals.shape and r.shape == b.row_ids.shape
+    held = np.concatenate([np.asarray(b.row_ids) for b in bell.buckets])
+    expect = ~np.isin(np.arange(edges.num_nodes), held)
+    assert expect.any()
+    np.testing.assert_array_equal(np.asarray(sc.uncovered), expect)
+    assert prep.bucket_scaling(lap, diag) is sc
 
 
 def test_plan_rejects_unknown_backend():
