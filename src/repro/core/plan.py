@@ -632,13 +632,13 @@ class GEEPlan:
                 return self._stage(
                     "compute", "gee_spmm_fused", False,
                     lambda: gee_fused_from_bucketed(
-                        bell, jnp.asarray(labels), k, o, scaling=scaling))
+                        bell, labels, k, o, scaling=scaling))
             from repro.kernels.ops import gee_pallas_from_bucketed
 
             z = self._stage(
                 "compute", "gee_spmm", False,
                 lambda: gee_pallas_from_bucketed(
-                    bell, jnp.asarray(labels), k,
+                    bell, labels, k,
                     GEEOptions(laplacian=o.laplacian, diag_aug=o.diag_aug),
                     scaling=scaling))
             if o.correlation:      # epilogue honors this plan's impl choice

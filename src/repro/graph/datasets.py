@@ -1,4 +1,5 @@
-"""Registry of the paper's benchmark graphs (Table 2).
+"""Registry of the benchmark graphs: the paper's Table 2 and the OGB
+node-property datasets the repository is deployed on.
 
 The container has no network access, so the six Network-Repository datasets
 are regenerated as *synthetic stand-ins with matching statistics*: the same
@@ -6,6 +7,11 @@ node count, edge count, class count and (hence) edge density as Table 2.  A
 degree-skewed configuration-model-like sampler makes the degree profile
 heavy-tailed, as in the real citation/protein graphs, so the sparse-vs-dense
 runtime comparison (the paper's actual claim) exercises the same regime.
+
+``OGB`` holds OGB node-property datasets (Hu et al., NeurIPS 2020,
+arXiv:2005.00687) by the same recipe, plus the size of the train split:
+only ``labelled`` vertices carry a class, the rest are ``-1`` (unknown),
+as in semi-supervised vertex classification.
 
 This substitution is recorded in DESIGN.md; the paper's evaluation is about
 *runtime vs. sparsity*, which depends on (N, E, K) and not on ground-truth
@@ -29,6 +35,11 @@ class DatasetSpec:
     num_nodes: int
     num_edges: int     # undirected edge count, as in paper Table 2
     num_classes: int
+    labelled: int | None = None   # vertices with a known label; None: all
+
+    @property
+    def num_labelled(self) -> int:
+        return self.num_nodes if self.labelled is None else self.labelled
 
     @property
     def density(self) -> float:
@@ -46,6 +57,15 @@ TABLE2: Dict[str, DatasetSpec] = {
     "cl-100k-1d8-l9": DatasetSpec("cl-100k-1d8-l9", 92_482, 373_986, 9),
     "cl-100k-1d8-l5": DatasetSpec("cl-100k-1d8-l5", 92_482, 10_000_000, 5),
 }
+
+# OGB node-property datasets (arXiv:2005.00687): vertices, undirected edges,
+# classes, and the train split's size as the labelled count.
+OGB: Dict[str, DatasetSpec] = {
+    "ogbn-products": DatasetSpec("ogbn-products", 2_449_029, 61_859_140, 47,
+                                 labelled=196_615),
+}
+
+REGISTRY: Dict[str, DatasetSpec] = {**TABLE2, **OGB}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -79,14 +99,29 @@ def _sample_loop_free_pairs(rng: np.random.Generator, n: int, count: int,
     return src, dst
 
 
+def _unlabel(labels: np.ndarray, spec: DatasetSpec, seed: int) -> np.ndarray:
+    """Keep the classes of ``spec.labelled`` vertices drawn from a stream
+    of their own, and mark the rest -1.  Drawn after the edges and apart
+    from the graph's stream, so a fully labelled spec's draw is untouched."""
+    if spec.num_labelled >= spec.num_nodes:
+        return labels
+    rng = np.random.default_rng([seed, 1])
+    keep = rng.choice(spec.num_nodes, size=spec.num_labelled, replace=False)
+    out = np.full_like(labels, -1)
+    out[keep] = labels[keep]
+    return out
+
+
 def synth_like(spec: DatasetSpec, seed: int = 0,
                pad_to: int | None = None) -> GraphDataset:
-    """Sample a graph matching (N, E, K) with a heavy-tailed degree profile."""
+    """Sample a graph matching (N, E, K) with a heavy-tailed degree
+    profile; only ``spec.labelled`` vertices keep a class."""
     rng = np.random.default_rng(seed)
     n, e, k = spec.num_nodes, spec.num_edges, spec.num_classes
     labels = rng.integers(0, k, size=n).astype(np.int32)
     src, dst = _sample_loop_free_pairs(rng, n, e,
                                        _skewed_endpoint_probs(rng, n))
+    labels = _unlabel(labels, spec, seed)
     s = np.concatenate([src, dst])
     d = np.concatenate([dst, src])
     edges = edge_list_from_numpy(s, d, None, n, pad_to=pad_to)
@@ -124,25 +159,26 @@ def load_file(path: str, pad_to: int | None = None, **open_kw) -> GraphDataset:
                  else chunked.num_edges // 2)
     spec = DatasetSpec(
         name=os.path.splitext(os.path.basename(path))[0],
-        num_nodes=chunked.num_nodes, num_edges=und_edges, num_classes=k)
+        num_nodes=chunked.num_nodes, num_edges=und_edges, num_classes=k,
+        labelled=int(np.count_nonzero(labels >= 0)))
     return GraphDataset(spec=spec, edges=edges, labels=labels)
 
 
 def load(name: str, seed: int = 0, pad_to: int | None = None) -> GraphDataset:
-    """Resolve a Table 2 spec name *or* an edge-file path.
+    """Resolve a registry name (Table 2 or OGB) *or* an edge-file path.
 
-    Spec names sample a synthetic stand-in (see module docstring) and
+    Registry names sample a synthetic stand-in (see module docstring) and
     always win -- a stray file that happens to be called ``cora`` cannot
     shadow the registry.  Anything else that looks like a path routes
     through the ``repro.graph.io`` layer (``load_file``).
     """
     key = name.lower()
-    if key in TABLE2:
-        return synth_like(TABLE2[key], seed=seed, pad_to=pad_to)
+    if key in REGISTRY:
+        return synth_like(REGISTRY[key], seed=seed, pad_to=pad_to)
     if _looks_like_path(name):
         return load_file(name, pad_to=pad_to)
-    raise KeyError(f"unknown dataset {name!r} (not a Table 2 name, and "
-                   f"not an edge-file path); available: {sorted(TABLE2)}")
+    raise KeyError(f"unknown dataset {name!r} (not a registry name, and "
+                   f"not an edge-file path); available: {sorted(REGISTRY)}")
 
 
 def synth_to_disk(spec: DatasetSpec, path: str, seed: int = 0,
@@ -185,5 +221,5 @@ def synth_to_disk(spec: DatasetSpec, path: str, seed: int = 0,
             f.write(f"# nodes {n} edges {e} undirected 1\n")
             for src, dst in chunks():
                 f.writelines(f"{s} {d}\n" for s, d in zip(src, dst))
-    save_labels(path, labels)
+    save_labels(path, _unlabel(labels, spec, seed))
     return path

@@ -55,6 +55,7 @@ from repro.kernels.gee_spmm import (LANE, _block_sizes_formula, clamp_blocks,
                                     contract_tile, measured_block_search,
                                     measure_enabled)
 from repro.kernels.platform import interpret_mode
+from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 
 ENV_FUSED = "REPRO_GEE_FUSED"
@@ -237,6 +238,20 @@ def bucket_span(idx: int, b: ELLBucket):
                           width=b.width, slots=b.slots, edges=b.num_edges)
 
 
+def labels_span(labels, n: int, num_classes: int):
+    """The ``plan.labels`` span (tags ``n``, ``k``) of a bucketed fit's
+    label step: the upload, the class weights, the dump-row extension and
+    Z's allocation.  Moves ``plan.labels.vertices`` by ``n`` and, when
+    ``labels`` is a host array, ``plan.labels.known`` by its known (>= 0)
+    labels; a device array is never read back."""
+    reg = obs_metrics.get_registry()
+    reg.counter("plan.labels.vertices").inc(n)
+    if isinstance(labels, np.ndarray):
+        reg.counter("plan.labels.known").inc(
+            int(np.count_nonzero(labels >= 0)))
+    return obs_trace.span("plan.labels", n=n, k=num_classes)
+
+
 def _diag_addend(labels, winv, dinv, diag_aug: bool):
     """Per-row (rowlab, dadd) epilogue operands; disabled -> empty/zero."""
     if not diag_aug:
@@ -364,7 +379,8 @@ def gee_fused_from_bucketed(bell: BucketedELL, labels: jax.Array,
     residual fixup below applies the shared epilogue arithmetic to them
     host-free in O(#isolated * K).  ``scaling`` is the packing's
     label-independent :class:`BucketScaling` (built here when absent), so
-    a fit does only label-dependent work.  Each bucket's eager ops run
+    a fit does only label-dependent work.  The label step runs under
+    :func:`labels_span`; each bucket's eager ops run
     under its ``plan.bucket`` span (:func:`bucket_span`), split into plane
     building, launch and write-back, so a profiler trace names the
     device's idle gaps down to the bucket.
@@ -375,13 +391,13 @@ def gee_fused_from_bucketed(bell: BucketedELL, labels: jax.Array,
         scaling = scale_buckets(bell, laplacian=opts.laplacian,
                                 diag_aug=opts.diag_aug)
     scaling.check(opts)
-    labels = jnp.asarray(labels, jnp.int32)
     n = bell.num_nodes
-    winv = class_weight_inv(labels, num_classes)
-    labels_ext = jnp.concatenate(        # dump row n -> label -1 (no-op)
-        [labels, jnp.full((1,), -1, jnp.int32)])
-
-    z = jnp.zeros((n + 1, num_classes), jnp.float32)
+    with labels_span(labels, n, num_classes):
+        labels = jnp.asarray(labels, jnp.int32)
+        winv = class_weight_inv(labels, num_classes)
+        labels_ext = jnp.concatenate(    # dump row n -> label -1 (no-op)
+            [labels, jnp.full((1,), -1, jnp.int32)])
+        z = jnp.zeros((n + 1, num_classes), jnp.float32)
     for i, b in enumerate(bell.buckets):
         with bucket_span(i, b):
             with obs_trace.span("plan.bucket.planes"):
@@ -418,4 +434,5 @@ def gee_fused_from_bucketed(bell: BucketedELL, labels: jax.Array,
 
 __all__ = ["ENV_FUSED", "KERNEL_NAME", "fused_override",
            "choose_fused_block_sizes", "gee_spmm_fused", "gee_fused_from_ell",
-           "gee_fused_from_bucketed", "BucketScaling", "scale_buckets"]
+           "gee_fused_from_bucketed", "BucketScaling", "scale_buckets",
+           "labels_span"]

@@ -30,7 +30,8 @@ from repro.core.gee import GEEOptions, class_weight_inv
 from repro.graph.containers import ELL, EdgeList
 from repro.graph.ell import (BucketedELL, edges_to_bucketed_ell, edges_to_ell,
                              ell_planes)
-from repro.kernels.gee_fused import BucketScaling, bucket_span, scale_buckets
+from repro.kernels.gee_fused import (BucketScaling, bucket_span, labels_span,
+                                     scale_buckets)
 from repro.kernels.gee_spmm import choose_block_sizes, gee_spmm
 from repro.kernels.platform import interpret_mode
 from repro.obs import trace as obs_trace
@@ -75,19 +76,20 @@ def gee_pallas_from_bucketed(bell: BucketedELL, labels: jax.Array,
     launch per bucket, partial outputs scattered into the [N+1]-row
     accumulator (row N is the dump row for bucket padding).  ``scaling``
     is the packing's label-independent ``BucketScaling`` (built here when
-    absent).  Explicit block sizes override the autotuner for every
-    bucket; by default each bucket is tuned on its own (rows, width, K)."""
+    absent); the label step runs under ``gee_fused.labels_span``.
+    Explicit block sizes override the autotuner for every bucket; by
+    default each bucket is tuned on its own (rows, width, K)."""
     if interpret is None:
         interpret = interpret_mode()
     if scaling is None:
         scaling = scale_buckets(bell, laplacian=opts.laplacian,
                                 diag_aug=opts.diag_aug)
     scaling.check(opts)
-    labels = jnp.asarray(labels, jnp.int32)
     n = bell.num_nodes
-    winv = class_weight_inv(labels, num_classes)
-
-    z = jnp.zeros((n + 1, num_classes), jnp.float32)
+    with labels_span(labels, n, num_classes):
+        labels = jnp.asarray(labels, jnp.int32)
+        winv = class_weight_inv(labels, num_classes)
+        z = jnp.zeros((n + 1, num_classes), jnp.float32)
     for i, b in enumerate(bell.buckets):
         with bucket_span(i, b):
             with obs_trace.span("plan.bucket.planes"):

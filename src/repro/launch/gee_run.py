@@ -17,7 +17,7 @@ import numpy as np
 
 from repro.core.gee import GEEOptions, gee
 from repro.core.plan import GEEPlan, PreparedGraph
-from repro.graph.datasets import TABLE2, load
+from repro.graph.datasets import REGISTRY, load
 from repro.graph.sbm import sample_sbm
 from repro.kernels.platform import interpret_mode
 from repro.launch.compile_cache import enable_compile_cache
@@ -41,7 +41,7 @@ def main(argv=None):
     ap.add_argument("--sbm", type=int, default=None,
                     help="SBM node count (paper's simulation)")
     ap.add_argument("--dataset", default=None,
-                    help=f"one of {sorted(TABLE2)}, or a path to an edge "
+                    help=f"one of {sorted(REGISTRY)}, or a path to an edge "
                          f"file (.geeb/.npz/.txt)")
     ap.add_argument("--edge-file", default=None,
                     help="embed an on-disk edge list out-of-core (any "
@@ -112,7 +112,8 @@ def main(argv=None):
         print(f"{args.edge_file}: N={chunked.num_nodes} "
               f"E={chunked.num_edges}"
               f"{' (undirected storage)' if chunked.undirected else ''} "
-              f"K={k} windows={chunked.num_windows}"
+              f"K={k} known={int(np.count_nonzero(labels >= 0))} "
+              f"windows={chunked.num_windows}"
               f"x{chunked.window_edges} "
               f"[{opts.tag()}]")
         pf = args.prefetch_windows
@@ -145,8 +146,9 @@ def main(argv=None):
         ds = load(args.dataset or "citeseer", seed=args.seed)
         edges, labels, k = ds.edges, ds.labels, ds.spec.num_classes
         name = ds.spec.name
+    known = int(np.count_nonzero(np.asarray(labels) >= 0))
     print(f"{name}: N={edges.num_nodes} E={edges.num_edges//2} K={k} "
-          f"[{opts.tag()}]")
+          f"known={known} [{opts.tag()}]")
 
     backends = (("sparse_jax", "chunked", "streamed_sharded", "pallas",
                  "auto", "dense_jax", "scipy", "python_loop")

@@ -23,7 +23,7 @@ import numpy as np
 
 from repro.core.api import GEEEmbedder
 from repro.core.gee import GEEOptions
-from repro.graph.datasets import TABLE2, load
+from repro.graph.datasets import REGISTRY, load
 from repro.graph.sbm import sample_sbm
 from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import cli as obs_cli
@@ -57,7 +57,7 @@ def main(argv=None):
     ap.add_argument("--sbm", type=int, default=None,
                     help="SBM node count (paper's simulation)")
     ap.add_argument("--dataset", default=None,
-                    help=f"one of {sorted(TABLE2)}")
+                    help=f"one of {sorted(REGISTRY)}")
     ap.add_argument("--edge-file", default=None,
                     help="embed an on-disk edge list out-of-core first "
                          "(any repro.graph.io format; labels from the "
@@ -120,7 +120,8 @@ def main(argv=None):
     t0 = time.perf_counter()
     index = emb.build_index(metric=args.metric, nprobe=args.nprobe)
     t_build = time.perf_counter() - t0
-    print(f"{name}: N={n} K={emb.num_classes} [{opts.tag()}]  "
+    known = int(np.count_nonzero(np.asarray(labels) >= 0))
+    print(f"{name}: N={n} K={emb.num_classes} known={known} [{opts.tag()}]  "
           f"embed {t_embed*1e3:.1f} ms, index build {t_build*1e3:.1f} ms  "
           f"(C={index.num_cells} cells, bucket cap "
           f"{index.bucket_capacity}, padding "

@@ -34,7 +34,7 @@ import numpy as np
 from repro.core.gee import GEEOptions, gee_sparse_jax
 from repro.core.incremental import IncrementalGEE
 from repro.graph.containers import edge_list_from_numpy, symmetrize
-from repro.graph.datasets import TABLE2, load
+from repro.graph.datasets import REGISTRY, load
 from repro.graph.delta import (edge_delta_from_numpy, label_delta_from_numpy,
                                symmetrize_delta)
 from repro.graph.sbm import sample_sbm
@@ -86,7 +86,7 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--sbm", type=int, default=None)
     ap.add_argument("--dataset", default=None,
-                    help=f"one of {sorted(TABLE2)}")
+                    help=f"one of {sorted(REGISTRY)}")
     ap.add_argument("--stream-frac", type=float, default=0.2,
                     help="fraction of undirected edges replayed as a stream")
     ap.add_argument("--batch", type=int, default=64,
@@ -129,7 +129,8 @@ def main(argv=None):
                                     st["k"], st["opts"])
     rng, su, du, wu = st["rng"], st["su"], st["du"], st["wu"]
     n_stream, n_base = st["n_stream"], st["n_base"]
-    print(f"{name}: N={edges.num_nodes} K={k} [{opts.tag()}]  "
+    known = int(np.count_nonzero(np.asarray(labels) >= 0))
+    print(f"{name}: N={edges.num_nodes} K={k} known={known} [{opts.tag()}]  "
           f"base E={n_base} streaming E={n_stream} in batches of {args.batch}"
           f"  platform={jax.default_backend()}")
 

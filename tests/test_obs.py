@@ -561,6 +561,53 @@ def test_bucket_spans_carry_packing_counts(fresh_obs, fused):
     assert ("plan.bucket.residual" in names) == fused
 
 
+@pytest.mark.pallas_interpret
+@pytest.mark.parametrize("fused", [True, False])
+def test_labels_span_once_per_fit_in_both_drivers(fresh_obs, sync_counter,
+                                                  fused):
+    """A bucketed fit's label step (upload, class weights, dump-row
+    extension, Z's allocation) runs under one ``plan.labels`` span inside
+    the plan's compute stage and before the first bucket, in both drivers;
+    ``plan.labels.vertices`` moves every fit, ``plan.labels.known`` only
+    for host labels, and the span adds no wait on the device."""
+    import jax.numpy as jnp
+
+    tracer, reg = fresh_obs
+    s = sample_sbm(120, seed=4)
+    labels = np.asarray(s.labels).copy()
+    labels[::3] = -1
+    known = int(np.count_nonzero(labels >= 0))
+    prep = PreparedGraph.wrap(s.edges)
+    plan = GEEPlan.build(prep, s.num_classes, OPTS_ALL, backend="pallas",
+                         fused=fused)
+    syncs_off, syncs_on, z_off, z_on = _traced_against_untraced(
+        tracer, sync_counter, lambda: plan.execute(labels))
+    np.testing.assert_array_equal(z_on, z_off)
+    assert syncs_on == syncs_off == 0, (syncs_on, syncs_off)
+
+    events = tracer.events()
+    stage_name = "plan.stage." + ("gee_spmm_fused" if fused else "gee_spmm")
+    (stage,) = [e for e in events if e.name == stage_name]
+    (lab,) = [e for e in events if e.name == "plan.labels"]
+    assert lab.args == {"n": 120, "k": s.num_classes}
+    assert lab.depth == stage.depth + 1 and lab.tid == stage.tid
+    assert stage.ts_us <= lab.ts_us
+    assert lab.ts_us + lab.dur_us <= stage.ts_us + stage.dur_us + 1.0
+    buckets = [e for e in events if e.name == "plan.bucket"]
+    assert buckets and all(b.ts_us >= lab.ts_us + lab.dur_us - 1.0
+                           for b in buckets)
+
+    counters = reg.snapshot()["counters"]        # three fits so far
+    assert counters["plan.labels.vertices"] == 3 * 120
+    assert counters["plan.labels.known"] == 3 * known
+    # labels already on the device are never read back to count them
+    plan.execute(jnp.asarray(labels))
+    counters = reg.snapshot()["counters"]
+    assert counters["plan.labels.vertices"] == 4 * 120
+    assert counters["plan.labels.known"] == 3 * known
+    assert [e.name for e in tracer.events()].count("plan.labels") == 2
+
+
 def test_embedder_spans_name_resolve_open_and_epilogue(fresh_obs,
                                                        sbm_small, tmp_path):
     from repro.core.api import GEEEmbedder

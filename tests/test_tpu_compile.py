@@ -4,7 +4,9 @@ No chip is needed: the TPU compiler compiles for a topology that is
 described, not attached, and refuses what the chip would refuse (shapes
 Mosaic cannot lower, tiles that overflow VMEM).  Shapes are those of the
 paper's largest Table-2 graph (``cl-100k-1d8-l5``: 92,482 rows, degree
-buckets up to 512 wide, K=5) plus one wide-K case (K=172).  Each test
+buckets up to 512 wide, K=5), one wide-K case (K=172), and the ogbn-products
+deployment's K=47 at its fullest bucket (1,147,712 rows, 64 wide) and its
+widest (8 rows, 65,536 wide).  Each test
 asserts the kernel reached the program as a Mosaic ``tpu_custom_call``.
 
 The topology is described only inside the fixture below, never at import,
@@ -31,6 +33,13 @@ from repro.kernels.topk_score import (choose_gathered_blocks,
 
 N_ROWS = 92_482          # cl-100k-1d8-l5 node count
 BUCKET_WIDTHS = (8, 128, 256, 512)
+# (width, rows) of ogbn-products buckets (2,449,029 vertices, K=47)
+OGBN_BUCKETS = ((64, 1_147_712), (65_536, 8))
+# (k, width, rows), ids "<k>-<width>"
+SPMM_CASES = ([pytest.param(k, w, N_ROWS, id=f"{k}-{w}")
+               for k in (5, 172) for w in BUCKET_WIDTHS]
+              + [pytest.param(47, w, rows, id=f"47-{w}")
+                 for w, rows in OGBN_BUCKETS])
 
 
 @pytest.fixture(scope="module")
@@ -57,26 +66,24 @@ def _compile(fn, sharding, *shapes):
     return text
 
 
-@pytest.mark.parametrize("width", BUCKET_WIDTHS)
-@pytest.mark.parametrize("k", [5, 172])
-def test_gee_spmm_compiles(one_chip, width, k):
-    br, bd, ds = choose_block_sizes(N_ROWS, width, k)
+@pytest.mark.parametrize("k,width,rows", SPMM_CASES)
+def test_gee_spmm_compiles(one_chip, width, k, rows):
+    br, bd, ds = choose_block_sizes(rows, width, k)
     fn = functools.partial(gee_spmm, num_classes=k, block_rows=br,
                            block_deg=bd, deg_sub=ds, interpret=False)
-    _compile(fn, one_chip, ((N_ROWS, width), jnp.int32),
-             ((N_ROWS, width), jnp.float32))
+    _compile(fn, one_chip, ((rows, width), jnp.int32),
+             ((rows, width), jnp.float32))
 
 
-@pytest.mark.parametrize("width", BUCKET_WIDTHS)
-@pytest.mark.parametrize("k", [5, 172])
-def test_gee_spmm_fused_compiles(one_chip, width, k):
-    br, bd, ds = choose_fused_block_sizes(N_ROWS, width, k)
+@pytest.mark.parametrize("k,width,rows", SPMM_CASES)
+def test_gee_spmm_fused_compiles(one_chip, width, k, rows):
+    br, bd, ds = choose_fused_block_sizes(rows, width, k)
     fn = functools.partial(gee_spmm_fused, num_classes=k, correlation=True,
                            block_rows=br, block_deg=bd, deg_sub=ds,
                            interpret=False)
-    _compile(fn, one_chip, ((N_ROWS, width), jnp.int32),
-             ((N_ROWS, width), jnp.float32), ((N_ROWS,), jnp.int32),
-             ((N_ROWS,), jnp.float32))
+    _compile(fn, one_chip, ((rows, width), jnp.int32),
+             ((rows, width), jnp.float32), ((rows,), jnp.int32),
+             ((rows,), jnp.float32))
 
 
 @pytest.mark.parametrize("fused", [True, False])
