@@ -68,7 +68,8 @@ class GEEEmbedder:
     _prepared: Optional[PreparedGraph] = dataclasses.field(default=None,
                                                           repr=False)
     _chunked: Optional[object] = dataclasses.field(default=None, repr=False)
-    _labels: Optional[jax.Array] = dataclasses.field(default=None, repr=False)
+    _labels: "jax.Array | np.ndarray | None" = dataclasses.field(
+        default=None, repr=False)
     _z: Optional[jax.Array] = dataclasses.field(default=None, repr=False)
     _plan: Optional[GEEPlan] = dataclasses.field(default=None, repr=False)
     _inc: Optional[IncrementalGEE] = dataclasses.field(default=None,
@@ -97,7 +98,11 @@ class GEEEmbedder:
         self._prepared = PreparedGraph.wrap(edges)
         self._edges = self._prepared.base
         self._chunked = None
-        self._labels = jnp.asarray(labels, jnp.int32)
+        # host labels stay on the host (a copy) until the plan's label
+        # step uploads them, under its ``plan.labels`` span
+        self._labels = (jnp.asarray(labels, jnp.int32)
+                        if isinstance(labels, jax.Array)
+                        else np.array(labels, np.int32))
         self._z = None
         self._plan = None
         self._inc = None

@@ -24,9 +24,10 @@ staged path stays untouched as the differential reference
 8 option settings).
 
 Degree-0 rows appear in *no* ELL bucket (see ``repro.graph.ell``), so a
-per-bucket fused launch can never visit them; ``gee_fused_from_bucketed``
-applies the identical shared-epilogue arithmetic to those few rows as an
-O(#isolated * K) residual fixup.
+per-bucket fused launch can never visit them; when a packing has any
+(a count known when its scaling is built), ``gee_fused_from_bucketed``
+gives those rows the identical shared-epilogue arithmetic applied to
+zero rows.
 
 ``REPRO_GEE_FUSED=0/1`` overrides the plan-layer cost model
 (``repro.core.plan.select_fused``); unset defers to it.  On CPU the
@@ -164,6 +165,23 @@ def gee_spmm_fused(ylab: jax.Array, contrib: jax.Array, rowlab: jax.Array,
     (pass all -1 / zeros to disable diagonal augmentation).  Returns
     [N, num_classes] f32, row-normalized when ``correlation``.
     """
+    return gee_spmm_fused_padded(
+        ylab, contrib, rowlab, dadd, num_classes, correlation=correlation,
+        block_rows=block_rows, block_deg=block_deg, deg_sub=deg_sub,
+        interpret=interpret)[:, :num_classes]
+
+
+def gee_spmm_fused_padded(ylab: jax.Array, contrib: jax.Array,
+                          rowlab: jax.Array, dadd: jax.Array,
+                          num_classes: int, *, correlation: bool = True,
+                          block_rows: int | None = None,
+                          block_deg: int | None = None,
+                          deg_sub: int | None = None,
+                          interpret: bool | None = None) -> jax.Array:
+    """:func:`gee_spmm_fused` with the kernel's lane padding kept:
+    [N, K_pad] f32, ``K_pad`` the 128-multiple above ``num_classes``,
+    lanes ``K..K_pad`` exactly zero.  A caller that moves whole rows
+    moves them lane-aligned and slices the K lanes once."""
     n, d = ylab.shape
     if interpret is None:
         interpret = interpret_mode()
@@ -223,7 +241,7 @@ def _gee_fused_jit(ylab, contrib, rowlab, dadd, num_classes: int,
     )
     with jax.named_scope(KERNEL_NAME):
         out = call(ylab_p, contrib_p, rowlab_p, dadd_p)
-    return out[:n, :num_classes]
+    return out[:n]
 
 
 # ---------------------------------------------------------------------------
@@ -240,8 +258,9 @@ def bucket_span(idx: int, b: ELLBucket):
 
 def labels_span(labels, n: int, num_classes: int):
     """The ``plan.labels`` span (tags ``n``, ``k``) of a bucketed fit's
-    label step: the upload, the class weights, the dump-row extension and
-    Z's allocation.  Moves ``plan.labels.vertices`` by ``n`` and, when
+    host label step: the upload of host labels, and in the staged driver
+    the class weights and Z's allocation (the fused driver's program
+    computes those).  Moves ``plan.labels.vertices`` by ``n`` and, when
     ``labels`` is a host array, ``plan.labels.known`` by its known (>= 0)
     labels; a device array is never read back."""
     reg = obs_metrics.get_registry()
@@ -310,12 +329,18 @@ class BucketScaling:
     row_dinv: per bucket, ``dinv`` at its ``row_ids`` (0 at the dump row),
               the diag-aug addend's row scale.
     uncovered: [N] bool, the degree-0 rows no bucket holds.
+    num_uncovered: their count, from the packing's row counts on the host.
+    row_order: [N] int32, each row's position among the buckets' real
+              rows taken in bucket order (0 for a degree-0 row): the
+              gather that writes the fused fit's Z.
     """
 
     dinv: jax.Array
     vals: Tuple[jax.Array, ...]
     row_dinv: Tuple[jax.Array, ...]
     uncovered: jax.Array
+    num_uncovered: int
+    row_order: jax.Array
     laplacian: bool
     diag_aug: bool
 
@@ -329,8 +354,9 @@ class BucketScaling:
 
 def scale_buckets(bell: BucketedELL, *, laplacian: bool,
                   diag_aug: bool) -> BucketScaling:
-    """Build the :class:`BucketScaling` of a packing: the degree fold
-    under ``plan.bucket.degrees`` and each bucket's scaled plane under a
+    """Build the :class:`BucketScaling` of a packing: the degree fold,
+    the degree-0 mask and the row order under ``plan.bucket.degrees``,
+    and each bucket's scaled plane under a
     ``plan.bucket.scale`` span tagged with its ``idx``."""
     n = bell.num_nodes
     with obs_trace.span("plan.bucket.degrees", buckets=len(bell.buckets)):
@@ -346,8 +372,13 @@ def scale_buckets(bell: BucketedELL, *, laplacian: bool,
             dinv = inv_sqrt_degrees(deg)
         dinv_ext = jnp.concatenate([dinv, jnp.zeros((1,), jnp.float32)])
         covered = jnp.zeros((n + 1,), bool)
+        row_order = jnp.zeros((n + 1,), jnp.int32)
+        lo = 0
         for b in bell.buckets:
             covered = covered.at[b.row_ids].set(True)
+            row_order = row_order.at[b.row_ids[:b.num_rows]].set(
+                jnp.arange(lo, lo + b.num_rows, dtype=jnp.int32))
+            lo += b.num_rows
     vals, row_dinv = [], []
     for i, b in enumerate(bell.buckets):
         with obs_trace.span("plan.bucket.scale", idx=i):
@@ -360,7 +391,18 @@ def scale_buckets(bell: BucketedELL, *, laplacian: bool,
             row_dinv.append(dinv_ext[b.row_ids])
     return BucketScaling(dinv=dinv, vals=tuple(vals),
                          row_dinv=tuple(row_dinv), uncovered=~covered[:n],
+                         num_uncovered=n - lo, row_order=row_order[:n],
                          laplacian=bool(laplacian), diag_aug=bool(diag_aug))
+
+
+def fit_span(bell: BucketedELL):
+    """The ``plan.fit`` span around the dispatch of one fused fit program,
+    tagged with the packing's totals: ``buckets``, packed ``rows``,
+    ``slots`` and real ``edges``."""
+    return obs_trace.span(
+        "plan.fit", buckets=len(bell.buckets),
+        rows=sum(int(b.cols.shape[0]) for b in bell.buckets),
+        slots=bell.total_slots, edges=bell.total_edges)
 
 
 def gee_fused_from_bucketed(bell: BucketedELL, labels: jax.Array,
@@ -372,18 +414,21 @@ def gee_fused_from_bucketed(bell: BucketedELL, labels: jax.Array,
                             interpret: bool | None = None) -> jax.Array:
     """Fused GEE from a degree-bucketed packing of the *base* graph.
 
-    One fused launch per bucket: rows are disjoint across buckets, so
-    each real row's full contraction -- and therefore its whole epilogue
-    -- completes inside a single launch, and results scatter back with
-    ``.set`` (never ``.add``).  Degree-0 rows live in no bucket; the
-    residual fixup below applies the shared epilogue arithmetic to them
-    host-free in O(#isolated * K).  ``scaling`` is the packing's
-    label-independent :class:`BucketScaling` (built here when absent), so
-    a fit does only label-dependent work.  The label step runs under
-    :func:`labels_span`; each bucket's eager ops run
-    under its ``plan.bucket`` span (:func:`bucket_span`), split into plane
-    building, launch and write-back, so a profiler trace names the
-    device's idle gaps down to the bucket.
+    The whole fit is one compiled program (:func:`_fused_fit`), dispatched
+    once: the label step, per bucket its planes and its fused launch, then
+    one write of every real row into Z.  Rows are disjoint across buckets,
+    so each real row's full contraction -- and therefore its whole
+    epilogue -- completes inside a single launch.  Degree-0 rows live in
+    no bucket: when the packing has any, they take the shared epilogue
+    applied to zero rows (their diag-aug term and row norm).  ``scaling``
+    is the packing's label-independent :class:`BucketScaling` (built here
+    when absent), so a fit does only label-dependent work.
+
+    The host's label handling runs under :func:`labels_span`, the dispatch
+    under :func:`fit_span`; each bucket's device ops carry the named scope
+    ``bucket<i>``.  ``plan.fit_program.calls`` counts dispatches and
+    ``plan.fit_program.traces`` traces of the program: one per set of
+    bucket shapes, options and K, however many embedders and plans fit.
     """
     if interpret is None:
         interpret = interpret_mode()
@@ -391,48 +436,77 @@ def gee_fused_from_bucketed(bell: BucketedELL, labels: jax.Array,
         scaling = scale_buckets(bell, laplacian=opts.laplacian,
                                 diag_aug=opts.diag_aug)
     scaling.check(opts)
-    n = bell.num_nodes
-    with labels_span(labels, n, num_classes):
+    with labels_span(labels, bell.num_nodes, num_classes):
         labels = jnp.asarray(labels, jnp.int32)
+    blocks = []
+    for b in bell.buckets:
+        br, bd, ds = choose_fused_block_sizes(int(b.cols.shape[0]), b.width,
+                                              num_classes)
+        blocks.append((block_rows if block_rows is not None else br,
+                       block_deg if block_deg is not None else bd, ds))
+    obs_metrics.get_registry().counter("plan.fit_program.calls").inc()
+    with fit_span(bell):
+        return _fused_fit(
+            labels, tuple(b.cols for b in bell.buckets),
+            tuple(b.row_ids for b in bell.buckets), scaling.vals,
+            scaling.row_dinv, scaling.dinv, scaling.uncovered,
+            scaling.row_order, num_classes=num_classes, opts=opts,
+            blocks=tuple(blocks),
+            num_rows=tuple(b.num_rows for b in bell.buckets),
+            residual=scaling.num_uncovered > 0, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "num_classes", "opts", "blocks", "num_rows", "residual", "interpret"))
+def _fused_fit(labels, cols, row_ids, vals, row_dinv, dinv, uncovered,
+               row_order, *, num_classes: int, opts: GEEOptions, blocks,
+               num_rows, residual: bool, interpret: bool) -> jax.Array:
+    """The body of one bucketed fit.  The packing's arrays are arguments,
+    never closed over, so one executable serves every prepared graph of
+    the same bucket shapes.
+
+    Z is written once: the buckets' real rows (``num_rows``, static) are
+    concatenated lane-padded in bucket order and gathered into vertex
+    order by ``row_order``, then sliced to K lanes -- a row gather, where
+    a scatter of K-wide rows into Z runs an order of magnitude slower on
+    a v5e.  ``residual`` (static) says whether any row lies in no bucket;
+    only then does the shared epilogue run for those rows."""
+    obs_metrics.get_registry().counter("plan.fit_program.traces").inc()
+    n = labels.shape[0]
+    with jax.named_scope("labels"):
         winv = class_weight_inv(labels, num_classes)
         labels_ext = jnp.concatenate(    # dump row n -> label -1 (no-op)
             [labels, jnp.full((1,), -1, jnp.int32)])
-        z = jnp.zeros((n + 1, num_classes), jnp.float32)
-    for i, b in enumerate(bell.buckets):
-        with bucket_span(i, b):
-            with obs_trace.span("plan.bucket.planes"):
-                ylab, contrib = ell_planes(b.cols, scaling.vals[i], labels,
-                                           winv)
-                rowlab, dadd = _diag_addend(labels_ext[b.row_ids], winv,
-                                            scaling.row_dinv[i],
-                                            opts.diag_aug)
-            with obs_trace.span("plan.bucket.launch"):
-                br, bd, ds = choose_fused_block_sizes(
-                    int(b.cols.shape[0]), b.width, num_classes)
-                out = gee_spmm_fused(
-                    ylab, contrib, rowlab, dadd, num_classes,
-                    correlation=opts.correlation,
-                    block_rows=block_rows if block_rows is not None else br,
-                    block_deg=block_deg if block_deg is not None else bd,
-                    deg_sub=ds, interpret=interpret)
-            # disjoint real rows; bucket-padding rows all target the dump
-            # row with all-zero planes and a -1 rowlab, so they write zeros
-            with obs_trace.span("plan.bucket.scatter"):
-                z = z.at[b.row_ids].set(out)
-    z = z[:n]
-
-    # Residual fixup: degree-0 rows (no bucket) still owe the diag-aug
-    # term and the row norm -- the identical shared-epilogue arithmetic.
-    with obs_trace.span("plan.bucket.residual"):
-        if opts.diag_aug or opts.correlation:
+    outs = []
+    for i, (c, r, v, rd, (br, bd, ds), m) in enumerate(
+            zip(cols, row_ids, vals, row_dinv, blocks, num_rows)):
+        with jax.named_scope(f"bucket{i}"):
+            ylab, contrib = ell_planes(c, v, labels, winv)
+            rowlab, dadd = _diag_addend(labels_ext[r], winv, rd,
+                                        opts.diag_aug)
+            out = gee_spmm_fused_padded(
+                ylab, contrib, rowlab, dadd, num_classes,
+                correlation=opts.correlation, block_rows=br, block_deg=bd,
+                deg_sub=ds, interpret=interpret)
+            outs.append(out[:m])
+    with jax.named_scope("write"):
+        if outs:
+            z = jnp.concatenate(outs).at[row_order].get(
+                mode="promise_in_bounds")[:, :num_classes]
+        else:
+            z = jnp.zeros((n, num_classes), jnp.float32)
+        if residual:
+            # degree-0 rows gathered row 0; they owe zeros, the diag-aug
+            # term and the row norm: the identical shared-epilogue
+            # arithmetic
             z_res = apply_epilogue(jnp.zeros((n, num_classes), jnp.float32),
-                                   labels, winv, scaling.dinv, opts=opts,
-                                   impl="jnp")
-            z = jnp.where(scaling.uncovered[:, None], z_res, z)
+                                   labels, winv, dinv, opts=opts, impl="jnp")
+            z = jnp.where(uncovered[:, None], z_res, z)
     return z
 
 
 __all__ = ["ENV_FUSED", "KERNEL_NAME", "fused_override",
-           "choose_fused_block_sizes", "gee_spmm_fused", "gee_fused_from_ell",
+           "choose_fused_block_sizes", "gee_spmm_fused",
+           "gee_spmm_fused_padded", "gee_fused_from_ell",
            "gee_fused_from_bucketed", "BucketScaling", "scale_buckets",
            "labels_span"]

@@ -39,10 +39,12 @@ from repro.core.plan import (KNOWN_BACKENDS, GEEPlan, PreparedGraph,
 from repro.graph.containers import edge_list_from_numpy, edges_to_ell, symmetrize
 from repro.graph.ell import edges_to_bucketed_ell
 from repro.kernels.autotune import AutotuneRegistry
+from repro.kernels import gee_fused
 from repro.kernels.gee_fused import (gee_fused_from_bucketed,
                                      gee_fused_from_ell, gee_spmm_fused,
                                      scale_buckets)
 from repro.kernels.ops import gee_pallas_from_bucketed
+from repro.obs.metrics import MetricsRegistry, set_registry
 from repro.kernels.topk_score import (gathered_scores, masked_topk,
                                       pairwise_scores, scored_topk,
                                       scored_topk_gathered)
@@ -206,6 +208,92 @@ def test_scaling_for_other_options_is_refused(driver):
         driver(bell, jnp.asarray(labels), k,
                GEEOptions(laplacian=True, diag_aug=True), scaling=sc,
                interpret=True)
+
+
+# ---------------------------------------------------------------------------
+# one compiled program a fit
+# ---------------------------------------------------------------------------
+
+def _no_degree0_graph(n=40, k=3, seed=5):
+    """A ring plus random chords: every vertex has an edge."""
+    rng = np.random.default_rng(seed)
+    src = np.concatenate([np.arange(n), rng.integers(0, n, 2 * n)])
+    dst = np.concatenate([(np.arange(n) + 1) % n, rng.integers(0, n, 2 * n)])
+    w = rng.uniform(0.5, 2.0, src.shape[0]).astype(np.float32)
+    labels = rng.integers(0, k, n).astype(np.int32)
+    labels[::5] = -1
+    return symmetrize(edge_list_from_numpy(src, dst, w, n)), labels, k
+
+
+def test_fresh_embedders_share_one_traced_program(monkeypatch):
+    """Three fits with fresh labels, each through a fresh embedder and
+    plan over one prepared graph, dispatch the fit program three times
+    and trace it once."""
+    from repro.core.api import GEEEmbedder
+
+    monkeypatch.setenv("REPRO_GEE_FUSED", "1")
+    edges, _, k = _fixed_adversarial()
+    prep = PreparedGraph.wrap(edges)
+    rng = np.random.default_rng(0)
+    gee_fused._fused_fit.clear_cache()
+    reg = MetricsRegistry()
+    prev = set_registry(reg)
+    try:
+        for _ in range(3):
+            y = rng.integers(-1, k, edges.num_nodes).astype(np.int32)
+            emb = GEEEmbedder(num_classes=k, backend="pallas")
+            z = emb.fit(prep, y).transform()
+            assert emb.plan.fused
+            np.testing.assert_allclose(
+                np.asarray(z), _scipy_ref(edges, y, k, emb.options),
+                atol=1e-5)
+    finally:
+        set_registry(prev)
+    counters = reg.snapshot()["counters"]
+    assert counters["plan.fit_program.calls"] == 3
+    assert counters["plan.fit_program.traces"] == 1
+
+
+@pytest.mark.parametrize("degree0", [False, True],
+                         ids=["no-degree0", "degree0"])
+def test_residual_traced_only_with_degree0_rows(monkeypatch, degree0):
+    """The degree-0 residual is static: a packing that covers every row
+    traces no epilogue outside the kernel; one with degree-0 rows traces
+    it once.  Both match the staged driver."""
+    edges, labels, k = (_fixed_adversarial() if degree0
+                        else _no_degree0_graph())
+    opts = GEEOptions(laplacian=True, diag_aug=True, correlation=True)
+    bell = edges_to_bucketed_ell(edges)
+    sc = scale_buckets(bell, laplacian=True, diag_aug=True)
+    assert sc.num_uncovered == int(np.asarray(sc.uncovered).sum())
+    assert (sc.num_uncovered > 0) == degree0
+    traced = []
+    shared = gee_fused.apply_epilogue
+    monkeypatch.setattr(gee_fused, "apply_epilogue",
+                        lambda *a, **kw: traced.append(1) or shared(*a, **kw))
+    gee_fused._fused_fit.clear_cache()
+    try:
+        z = gee_fused_from_bucketed(bell, jnp.asarray(labels), k, opts,
+                                    scaling=sc, interpret=True)
+    finally:
+        gee_fused._fused_fit.clear_cache()
+    assert len(traced) == int(degree0)
+    staged = gee_pallas_from_bucketed(bell, jnp.asarray(labels), k, opts,
+                                      scaling=sc, interpret=True)
+    np.testing.assert_allclose(np.asarray(z), np.asarray(staged), atol=1e-5)
+
+
+@pytest.mark.parametrize("opts", ALL_OPTION_SETTINGS, ids=OPT_IDS)
+def test_degree0_rows_match_staged(opts):
+    edges, labels, k = _fixed_adversarial()      # isolated tail 8..22
+    bell = edges_to_bucketed_ell(edges)
+    assert scale_buckets(bell, laplacian=opts.laplacian,
+                         diag_aug=opts.diag_aug).num_uncovered == 15
+    y = jnp.asarray(labels)
+    fused = gee_fused_from_bucketed(bell, y, k, opts, interpret=True)
+    staged = gee_pallas_from_bucketed(bell, y, k, opts, interpret=True)
+    np.testing.assert_allclose(np.asarray(fused), np.asarray(staged),
+                               atol=1e-5, err_msg=opts.tag())
 
 
 # ---------------------------------------------------------------------------

@@ -492,11 +492,13 @@ def test_stream_traced_fit_adds_no_sync(fresh_obs, sbm_small, sync_counter,
 @pytest.mark.pallas_interpret
 @pytest.mark.parametrize("fused", [True, False])
 def test_bucket_spans_carry_packing_counts(fresh_obs, fused):
-    """Each ``plan.bucket`` span is tagged with its bucket's packed slots
-    and real entries; over the fit they sum to the packing's totals, and
-    the host packing's own span says the same.  The label-independent
-    degree fold and scaling run under the ``bucket_scaling`` prep stage of
-    the first execute only; every fit keeps its per-bucket phases."""
+    """The staged driver tags each ``plan.bucket`` span with its bucket's
+    packed slots and real entries, which sum over the fit to the
+    packing's totals; the fused driver dispatches the whole fit as one
+    program under one ``plan.fit`` span tagged with those totals, and
+    opens no per-bucket host span.  The host packing's own span says the
+    same.  The label-independent degree fold and scaling run under the
+    ``bucket_scaling`` prep stage of the first execute only."""
     tracer, _ = fresh_obs
     tracer.enable()
     s = sample_sbm(120, seed=4)
@@ -509,29 +511,42 @@ def test_bucket_spans_carry_packing_counts(fresh_obs, fused):
     np.testing.assert_allclose(np.asarray(z), np.asarray(z_ref), atol=1e-5)
 
     bell = prep.bucketed_ell(False)
-    events = tracer.events()
-    buckets = sorted((e for e in events if e.name == "plan.bucket"),
-                     key=lambda e: e.args["idx"])
-    assert [e.args["idx"] for e in buckets] == list(range(len(bell.buckets)))
-    for e, b in zip(buckets, bell.buckets):
-        a = e.args
-        assert a["slots"] == a["rows"] * a["width"] >= a["edges"] > 0
-        assert (a["width"], a["edges"]) == (b.width, b.num_edges)
-    assert sum(e.args["slots"] for e in buckets) == bell.total_slots
     real = int(np.count_nonzero(np.asarray(s.edges.weight)
                                 [: s.edges.num_edges]))
-    assert sum(e.args["edges"] for e in buckets) == bell.total_edges == real
+    events = tracer.events()
     (pack,) = [e for e in events if e.name == "plan.pack.bucketed_ell"]
     assert (pack.args["slots"], pack.args["edges"]) == (bell.total_slots,
                                                         real)
-    assert pack.args["rows"] == sum(e.args["rows"] for e in buckets)
-    # every bucket's work runs in its three named phases, nested inside it
-    for phase in ("planes", "launch", "scatter"):
-        inner = [e for e in events if e.name == "plan.bucket." + phase]
-        assert len(inner) == len(buckets)
-        assert all(e.depth == buckets[0].depth + 1 for e in inner)
-    names = {e.name for e in events}
-    assert ("plan.bucket.residual" in names) == fused
+    bucket_spans = ("plan.bucket", "plan.bucket.planes",
+                    "plan.bucket.launch", "plan.bucket.scatter",
+                    "plan.bucket.residual")
+    if fused:
+        (fit,) = [e for e in events if e.name == "plan.fit"]
+        assert fit.args == {"buckets": len(bell.buckets),
+                            "rows": pack.args["rows"],
+                            "slots": bell.total_slots, "edges": real}
+        assert bell.total_edges == real
+        assert not [e for e in events if e.name in bucket_spans]
+    else:
+        buckets = sorted((e for e in events if e.name == "plan.bucket"),
+                         key=lambda e: e.args["idx"])
+        assert [e.args["idx"] for e in buckets] \
+            == list(range(len(bell.buckets)))
+        for e, b in zip(buckets, bell.buckets):
+            a = e.args
+            assert a["slots"] == a["rows"] * a["width"] >= a["edges"] > 0
+            assert (a["width"], a["edges"]) == (b.width, b.num_edges)
+        assert sum(e.args["slots"] for e in buckets) == bell.total_slots
+        assert sum(e.args["edges"] for e in buckets) \
+            == bell.total_edges == real
+        assert pack.args["rows"] == sum(e.args["rows"] for e in buckets)
+        # every bucket's work runs in its three named phases, nested in it
+        for phase in ("planes", "launch", "scatter"):
+            inner = [e for e in events if e.name == "plan.bucket." + phase]
+            assert len(inner) == len(buckets)
+            assert all(e.depth == buckets[0].depth + 1 for e in inner)
+        names = {e.name for e in events}
+        assert "plan.bucket.residual" not in names
     # the one-time build: degrees once, one scale span per bucket, both
     # inside the bucket_scaling prep stage
     (stage,) = [e for e in events if e.name == "plan.stage.bucket_scaling"]
@@ -556,18 +571,22 @@ def test_bucket_spans_carry_packing_counts(fresh_obs, fused):
     assert "plan.bucket.scale" not in names
     (stage,) = [e for e in events if e.name == "plan.stage.bucket_scaling"]
     assert stage.args["cached"] is True
-    for phase in ("", ".planes", ".launch", ".scatter"):
-        assert names.count("plan.bucket" + phase) == len(bell.buckets)
-    assert ("plan.bucket.residual" in names) == fused
+    if fused:
+        assert names.count("plan.fit") == 1
+        assert not set(names) & set(bucket_spans)
+    else:
+        for phase in ("", ".planes", ".launch", ".scatter"):
+            assert names.count("plan.bucket" + phase) == len(bell.buckets)
+        assert "plan.bucket.residual" not in names
 
 
 @pytest.mark.pallas_interpret
 @pytest.mark.parametrize("fused", [True, False])
 def test_labels_span_once_per_fit_in_both_drivers(fresh_obs, sync_counter,
                                                   fused):
-    """A bucketed fit's label step (upload, class weights, dump-row
-    extension, Z's allocation) runs under one ``plan.labels`` span inside
-    the plan's compute stage and before the first bucket, in both drivers;
+    """A bucketed fit's host label step runs under one ``plan.labels``
+    span inside the plan's compute stage, before the first bucket of the
+    staged driver and before the fused driver's one ``plan.fit`` dispatch;
     ``plan.labels.vertices`` moves every fit, ``plan.labels.known`` only
     for host labels, and the span adds no wait on the device."""
     import jax.numpy as jnp
@@ -593,9 +612,13 @@ def test_labels_span_once_per_fit_in_both_drivers(fresh_obs, sync_counter,
     assert lab.depth == stage.depth + 1 and lab.tid == stage.tid
     assert stage.ts_us <= lab.ts_us
     assert lab.ts_us + lab.dur_us <= stage.ts_us + stage.dur_us + 1.0
-    buckets = [e for e in events if e.name == "plan.bucket"]
-    assert buckets and all(b.ts_us >= lab.ts_us + lab.dur_us - 1.0
-                           for b in buckets)
+    # the fused fit's one dispatch, or the staged driver's buckets
+    after = [e for e in events
+             if e.name == ("plan.fit" if fused else "plan.bucket")]
+    assert after and all(e.ts_us >= lab.ts_us + lab.dur_us - 1.0
+                         for e in after)
+    if fused:
+        assert after[0].depth == lab.depth and len(after) == 1
 
     counters = reg.snapshot()["counters"]        # three fits so far
     assert counters["plan.labels.vertices"] == 3 * 120

@@ -241,7 +241,8 @@ def test_pallas_refit_reuses_bucket_scaling(fused):
 def test_bucket_scaling_shares_the_packing(lap, diag):
     """Without the Laplacian the scaled planes are the packing's own
     arrays, not copies; with it they are new planes of the same shape.
-    The degree-0 mask names exactly the rows no bucket holds."""
+    The degree-0 mask names exactly the rows no bucket holds, and the
+    count taken from the packing on the host agrees."""
     edges = _random_edges(n=70, e=120, seed=3)   # leaves isolated rows
     prep = PreparedGraph.wrap(edges)
     bell = prep.bucketed_ell(False)
@@ -254,6 +255,7 @@ def test_bucket_scaling_shares_the_packing(lap, diag):
     expect = ~np.isin(np.arange(edges.num_nodes), held)
     assert expect.any()
     np.testing.assert_array_equal(np.asarray(sc.uncovered), expect)
+    assert sc.num_uncovered == int(expect.sum())
     assert prep.bucket_scaling(lap, diag) is sc
 
 

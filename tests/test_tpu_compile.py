@@ -15,6 +15,7 @@ that runs this file loads the TPU compiler.
 """
 
 import functools
+import math
 import re
 
 import jax
@@ -22,7 +23,9 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.core.gee import GEEOptions
 from repro.kernels.gee_fused import KERNEL_NAME as FUSED_NAME
+from repro.kernels.gee_fused import _fused_fit
 from repro.kernels.gee_fused import choose_fused_block_sizes, gee_spmm_fused
 from repro.kernels.gee_spmm import KERNEL_NAME as STAGED_NAME
 from repro.kernels.gee_spmm import choose_block_sizes, gee_spmm
@@ -118,6 +121,46 @@ def test_kernel_named_in_compiled_hlo(one_chip, fused):
     instr = re.match(r"\s*(?:ROOT )?%?([\w.-]+) = ", calls[0]).group(1)
     assert instr.split(".")[0] == name, instr
     assert f"/{name}/" in re.search(r'op_name="([^"]*)"', calls[0]).group(1)
+
+
+@pytest.mark.parametrize("k,buckets", [
+    pytest.param(5, ((128, 2_000), (256, 1_000), (8_192, 8)), id="5"),
+    pytest.param(47, ((8, 800), (64, 4_000), (8_192, 8)), id="47"),
+])
+def test_fused_fit_program_compiles(one_chip, k, buckets):
+    """The whole bucketed fit compiles as one program: one kernel launch
+    per bucket, and each bucket's label gather ``labels[cols]`` compiled
+    exactly once, named by its bucket's scope (XLA does not duplicate it
+    into the plane consumers)."""
+    n = N_ROWS
+    args = [((n,), jnp.int32)]
+    args += [((r, w), jnp.int32) for w, r in buckets]      # cols
+    args += [((r,), jnp.int32) for _, r in buckets]        # row_ids
+    args += [((r, w), jnp.float32) for w, r in buckets]    # scaled vals
+    args += [((r,), jnp.float32) for _, r in buckets]      # row_dinv
+    args += [((n,), jnp.float32), ((n,), jnp.bool_),      # dinv, uncovered
+             ((n,), jnp.int32)]                           # row_order
+    nb = len(buckets)
+
+    def fit(labels, *flat):
+        cols, rows, vals, rdinv = (tuple(flat[i * nb:(i + 1) * nb])
+                                   for i in range(4))
+        return _fused_fit(
+            labels, cols, rows, vals, rdinv, *flat[-3:], num_classes=k,
+            opts=GEEOptions(laplacian=True, diag_aug=True, correlation=True),
+            blocks=tuple(choose_fused_block_sizes(r, w, k)
+                         for w, r in buckets),
+            num_rows=tuple(r - 3 for _, r in buckets), residual=True,
+            interpret=False)
+    text = _compile(fit, one_chip, *args)
+    assert text.count('custom_call_target="tpu_custom_call"') == nb
+    gathers = {}
+    for m in re.finditer(r"= s32\[([\d,]*)\]\S* gather\(.*?"
+                         r'op_name="[^"]*/bucket(\d+)/', text):
+        size = math.prod(int(x) for x in m.group(1).split(",") if x)
+        gathers.setdefault(int(m.group(2)), []).append(size)
+    for i, (w, r) in enumerate(buckets):
+        assert gathers[i].count(r * w) == 1, (i, gathers[i])
 
 
 @pytest.mark.parametrize("k", [5, 172])
