@@ -5,8 +5,7 @@ Reports, per graph size:
   * ELL padding overhead (stored slots / real edges) for the flat and the
     degree-bucketed packing -- the quantity the bucketing layer exists to
     bound on power-law graphs;
-  * runtime of gee(..., backend="pallas") (bucketed), the flat-plane kernel
-    path, and gee_sparse_jax.
+  * runtime of gee(..., backend="pallas") and gee_sparse_jax.
 
 On CPU the kernel runs in interpret mode, so the runtime columns measure
 pipeline overhead, not MXU throughput; on TPU the same script times the
@@ -59,9 +58,6 @@ def run(sizes=SIZES, repeats=2):
                                                 s.num_classes, OPTS), repeats)
         t_bucketed = _time(lambda: gee(s.edges, s.labels, s.num_classes,
                                        OPTS, backend="pallas"), repeats)
-        from repro.kernels.ops import gee_pallas
-        t_flat = _time(lambda: gee_pallas(s.edges, s.labels, s.num_classes,
-                                          OPTS, bucketed=False), repeats)
 
         # equivalence gate: the benchmark is invalid if the backends diverge
         zp = np.asarray(gee(s.edges, s.labels, s.num_classes, OPTS,
@@ -79,7 +75,6 @@ def run(sizes=SIZES, repeats=2):
             "num_buckets": stats["num_buckets"],
             "t_sparse_jax": t_sparse,
             "t_pallas_bucketed": t_bucketed,
-            "t_pallas_flat": t_flat,
             "max_abs_err": max_err,
         }
         rows.append(row)
@@ -87,8 +82,7 @@ def run(sizes=SIZES, repeats=2):
               f"pad flat={row['flat_overhead']:5.2f}x "
               f"bucketed={row['bucketed_overhead']:5.2f}x  "
               f"sparse_jax={t_sparse*1e3:8.1f}ms "
-              f"pallas={t_bucketed*1e3:8.1f}ms "
-              f"flat={t_flat*1e3:8.1f}ms  err={max_err:.1e}")
+              f"pallas={t_bucketed*1e3:8.1f}ms  err={max_err:.1e}")
     return rows
 
 

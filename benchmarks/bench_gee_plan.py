@@ -80,39 +80,6 @@ def _time(fn, repeats: int) -> float:
     return min(ts)
 
 
-def _fused_vs_staged_cell(n: int, repeats: int) -> dict:
-    """Fused megakernel vs staged Pallas on one graph: correctness first
-    (<= 1e-5), then min-of-N plan-execute timings.
-
-    Off-TPU both paths run the kernels in interpret mode, so the speedup
-    is *parity documentation only* -- the >= 1.2x gate is asserted by the
-    caller exclusively on TPU-capable runs (see ``--min-fused-speedup``).
-    """
-    import jax
-
-    from repro.core.gee import GEEOptions
-
-    s = sample_sbm(n, seed=0)
-    src, dst, w = _raw_half_edges(s.edges)
-    prep = PreparedGraph.from_arrays(src, dst, w, num_nodes=n)
-    labels, k = s.labels, s.num_classes
-    opts = GEEOptions(laplacian=True, diag_aug=True, correlation=True)
-
-    plan_s = GEEPlan.build(prep, k, opts, backend="pallas", fused=False)
-    plan_f = GEEPlan.build(prep, k, opts, backend="pallas", fused=True)
-    z_s = np.asarray(_block(plan_s.execute(labels)))
-    z_f = np.asarray(_block(plan_f.execute(labels)))
-    err = float(np.abs(z_s - z_f).max())
-    assert err <= 1e-5, f"fused diverged from staged: {err}"
-
-    t_staged = _time(lambda: _block(plan_s.execute(labels)), repeats)
-    t_fused = _time(lambda: _block(plan_f.execute(labels)), repeats)
-    return {"nodes": int(n), "edges": int(s.edges.num_edges),
-            "device": jax.default_backend(), "max_abs_err": err,
-            "staged_s": t_staged, "fused_s": t_fused,
-            "fused_speedup": t_staged / t_fused}
-
-
 def _tracer_overhead_cell(n: int, repeats: int) -> dict:
     """The observability gate: disabled span instrumentation must cost
     <= 2% of a full-option ``gee()`` fit.  Uses the deterministic
@@ -155,7 +122,6 @@ def _autotune_roundtrip_smoke() -> bool:
 
 def run(nodes=NODE_GRID, repeats: int = 3, backend: str = "sparse_jax",
         min_speedup: float = 1.5, json_path: str | None = None,
-        min_fused_speedup: float = 1.2,
         max_tracer_overhead: float = 2.0,
         metrics_path: str | None = None):
     cells = []
@@ -186,19 +152,6 @@ def run(nodes=NODE_GRID, repeats: int = 3, backend: str = "sparse_jax",
               f"cold={t_cold*1e3:8.1f} ms  warm={t_warm*1e3:8.1f} ms  "
               f"prep-reuse speedup {cell['speedup']:5.2f}x")
 
-    import jax
-
-    on_tpu = jax.default_backend() == "tpu"
-    # interpret mode makes large fused cells pointless off-TPU: cap the
-    # graph so the smoke stays fast and report parity instead of a gate
-    fused_n = max(nodes) if on_tpu else min(max(nodes), 2_000)
-    fused_cell = _fused_vs_staged_cell(fused_n, repeats)
-    print(f"fused vs staged (N={fused_n}, {fused_cell['device']}):  "
-          f"staged={fused_cell['staged_s']*1e3:8.1f} ms  "
-          f"fused={fused_cell['fused_s']*1e3:8.1f} ms  "
-          f"{fused_cell['fused_speedup']:5.2f}x"
-          + ("" if on_tpu else "  [interpret mode: parity only, no gate]"))
-
     overhead = _tracer_overhead_cell(min(max(nodes), 3_000), repeats)
     print(f"disabled-tracer overhead (N={overhead['nodes']}): "
           f"{overhead['span_count']} spans x "
@@ -213,10 +166,6 @@ def run(nodes=NODE_GRID, repeats: int = 3, backend: str = "sparse_jax",
     worst = min(c["speedup"] for c in cells)
     result = {"backend": backend, "repeats": repeats, "cells": cells,
               "worst_speedup": worst, "min_speedup": min_speedup,
-              "fused_cell": fused_cell,
-              "fused_speedup": fused_cell["fused_speedup"],
-              "fused_gate_on": on_tpu,
-              "min_fused_speedup": min_fused_speedup,
               "tracer_overhead": overhead,
               "tracer_overhead_pct": overhead["overhead_pct"],
               "max_tracer_overhead": max_tracer_overhead,
@@ -236,10 +185,6 @@ def run(nodes=NODE_GRID, repeats: int = 3, backend: str = "sparse_jax",
     assert overhead["overhead_pct"] <= max_tracer_overhead, (
         f"disabled tracer overhead {overhead['overhead_pct']:.3f}% above "
         f"the {max_tracer_overhead}% gate")
-    if on_tpu:
-        assert fused_cell["fused_speedup"] >= min_fused_speedup, (
-            f"fused speedup {fused_cell['fused_speedup']:.2f}x below the "
-            f"{min_fused_speedup}x TPU gate")
     return result
 
 
@@ -250,8 +195,6 @@ def main(argv=None):
     ap.add_argument("--repeats", type=int, default=3)
     ap.add_argument("--backend", default="sparse_jax")
     ap.add_argument("--min-speedup", type=float, default=1.5)
-    ap.add_argument("--min-fused-speedup", type=float, default=1.2,
-                    help="fused-vs-staged gate, asserted only on TPU runs")
     ap.add_argument("--max-tracer-overhead", type=float, default=2.0,
                     help="disabled-instrumentation overhead gate, percent")
     ap.add_argument("--json", default=None)
@@ -260,7 +203,7 @@ def main(argv=None):
     args = ap.parse_args(argv)
     return run(tuple(int(x) for x in args.nodes.split(",")),
                args.repeats, args.backend, args.min_speedup, args.json,
-               args.min_fused_speedup, args.max_tracer_overhead,
+               args.max_tracer_overhead,
                args.metrics_out)
 
 

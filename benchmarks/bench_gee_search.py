@@ -36,8 +36,7 @@ NODES = (2_000, 6_000, 20_000)
 OPTS = GEEOptions(laplacian=True, diag_aug=True, correlation=True)
 
 
-def _time_search(index, queries, k, repeats, **kw):
-    fn = lambda: index.search(queries, k, **kw)
+def _time_call(fn, repeats):
     jax.block_until_ready(fn()[1])            # compile/warm outside timing
     ts = []
     for _ in range(repeats):
@@ -47,34 +46,31 @@ def _time_search(index, queries, k, repeats, **kw):
     return min(ts)
 
 
-def _fused_query_cell(z, labels, num_classes, queries, k, repeats, seed):
-    """Fused score-and-top-k vs staged scores+masked_topk on the pallas
-    query path (``REPRO_GEE_FUSED`` flips routing per-call).  Off-TPU the
-    kernels run in interpret mode, so this is parity documentation; the
-    headline gate lives in the TPU-capable runs."""
-    import os
+def _time_search(index, queries, k, repeats, **kw):
+    return _time_call(lambda: index.search(queries, k, **kw), repeats)
 
-    from repro.search.index import ClassPartitionedIndex
+
+def _fused_query_cell(z, queries, k, repeats, seed):
+    """Fused score-and-top-k vs staged scores+masked_topk on the pallas
+    query path: the index's brute-force search (``scored_topk`` over the
+    whole Z) with ``fused=`` set each way.  Off-TPU the kernels run in
+    interpret mode, so this is parity documentation; the headline gate
+    lives in the TPU-capable runs."""
+    import jax.numpy as jnp
+
+    from repro.kernels.topk_score import scored_topk
 
     n = z.shape[0]
-    q = z[np.random.default_rng(seed).integers(0, n, queries)]
-    index = ClassPartitionedIndex.build(z, labels, num_classes,
-                                        impl="pallas")
-    prev = os.environ.get("REPRO_GEE_FUSED")
-    try:
-        os.environ["REPRO_GEE_FUSED"] = "0"
-        ids_s, sc_s = (np.asarray(a) for a in
-                       index.search(q, k, brute_force=True))
-        t_staged = _time_search(index, q, k, repeats, brute_force=True)
-        os.environ["REPRO_GEE_FUSED"] = "1"
-        ids_f, sc_f = (np.asarray(a) for a in
-                       index.search(q, k, brute_force=True))
-        t_fused = _time_search(index, q, k, repeats, brute_force=True)
-    finally:
-        if prev is None:
-            os.environ.pop("REPRO_GEE_FUSED", None)
-        else:
-            os.environ["REPRO_GEE_FUSED"] = prev
+    zj = jnp.asarray(z)
+    q = zj[np.random.default_rng(seed).integers(0, n, queries)]
+    searches = {
+        fused: jax.jit(lambda q, fused=fused: scored_topk(
+            q, zj, None, k, metric="l2", impl="pallas", fused=fused))
+        for fused in (False, True)}
+    ids_s, sc_s = (np.asarray(a) for a in searches[False](q))
+    ids_f, sc_f = (np.asarray(a) for a in searches[True](q))
+    t_staged = _time_call(lambda: searches[False](q), repeats)
+    t_fused = _time_call(lambda: searches[True](q), repeats)
     assert np.array_equal(ids_s, ids_f), \
         "fused top-k returned different neighbor ids than staged"
     np.testing.assert_allclose(sc_f, sc_s, atol=1e-5)
@@ -142,8 +138,7 @@ def run(nodes=NODES, queries=256, k=10, repeats=3, seed=0):
 
         if n == fused_n:
             fq = queries if on_tpu else min(queries, 64)
-            fused_cell = _fused_query_cell(z, s.labels, s.num_classes,
-                                           fq, k, repeats, seed)
+            fused_cell = _fused_query_cell(z, fq, k, repeats, seed)
             print(f"  fused query path (N={n}, {fused_cell['device']}): "
                   f"staged={fused_cell['staged_s']*1e3:7.1f}ms  "
                   f"fused={fused_cell['fused_s']*1e3:7.1f}ms  "

@@ -82,7 +82,6 @@ def _extract(payload: dict) -> dict:
         put("prefetch_speedup", payload.get("prefetch_speedup"), HIGHER)
     elif bench == "gee_plan":
         put("prep_reuse_speedup", payload.get("worst_speedup"), HIGHER)
-        put("fused_speedup", payload.get("fused_speedup"), HIGHER)
         put("tracer_overhead_pct", payload.get("tracer_overhead_pct"),
             LOWER)
     elif bench == "gee_search":

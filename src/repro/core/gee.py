@@ -19,7 +19,8 @@ Backends (all numerically equivalent; tested against each other):
 Two more live in their own modules and are reachable through ``gee``'s
 ``backend=`` switch: ``chunked`` (``repro.core.chunked``: the out-of-core
 two-pass stream over disk-resident edge lists) and ``pallas``
-(``repro.kernels.ops``: the ELL-tiled MXU kernel).
+(``repro.kernels.gee_fused``: the ELL-tiled MXU kernel with the epilogue
+fused in).
 
 Shared semantics
 ----------------

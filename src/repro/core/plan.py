@@ -111,11 +111,10 @@ class PreparedGraph:
                                        input of the scatter stage, keyed
                                        on ``(diag_aug, laplacian)`` (the
                                        correlation flag never affects prep)
-      * ``ell(diag_aug)`` /
-        ``bucketed_ell(diag_aug)``     the Pallas kernel's packing planes
+      * ``bucketed_ell(diag_aug)``     the Pallas kernel's packing planes
       * ``bucket_scaling(laplacian,
-        diag_aug)``                    the bucketed drivers' label-free
-                                       part: d^{-1/2} and scaled planes
+        diag_aug)``                    the Pallas fit's label-free part:
+                                       d^{-1/2} and scaled planes
       * ``chunked(chunk_edges)``       the chunk manifest of the streaming
                                        backend
       * ``host_arrays()``              the valid-prefix numpy triple the
@@ -234,14 +233,6 @@ class PreparedGraph:
             e = self.augmented(opts.diag_aug)
             return _laplacian_fold(e) if opts.laplacian else e
         return self._memo(key, build)
-
-    def ell(self, diag_aug: bool = False):
-        """Single-plane ELL packing of the (augmented) graph (host-side
-        O(E); by far the most expensive prep artifact -- cache pays)."""
-        from repro.graph.ell import edges_to_ell  # deferred: keep core light
-
-        return self._memo(("ell", bool(diag_aug)),
-                          lambda: edges_to_ell(self.augmented(diag_aug)))
 
     def bucketed_ell(self, diag_aug: bool = False):
         """Degree-bucketed ELL packing of the (augmented) graph; the
@@ -381,31 +372,6 @@ def select_backend(graph: PreparedGraph | EdgeList, num_classes: int, *,
     return "sparse_jax"
 
 
-def select_fused(backend: str, opts: GEEOptions, *,
-                 device: str | None = None) -> bool:
-    """The fused-epilogue stage's cost model (``fused="auto"``).
-
-    The fused megakernel (``repro.kernels.gee_fused``) replaces the
-    staged scatter + epilogue of the ``pallas`` backend, eliminating one
-    full [N, K] materialization -- it pays off exactly when (a) the
-    backend is ``pallas``, (b) there is an epilogue to fuse (diag-aug or
-    correlation; with neither the fused kernel degenerates to the staged
-    scatter), and (c) the device is a real TPU (off-TPU both paths run in
-    interpret mode and fusion saves nothing).  ``REPRO_GEE_FUSED=1/0``
-    overrides (b) and (c) but never (a): the fused stage only exists on
-    the Pallas path, so the override is a no-op for other backends.
-    """
-    if backend != "pallas":
-        return False
-    from repro.kernels.gee_fused import fused_override  # deferred: keep light
-
-    override = fused_override()
-    if override is not None:
-        return bool(override)
-    device = device or jax.default_backend()
-    return device == "tpu" and bool(opts.diag_aug or opts.correlation)
-
-
 # ---------------------------------------------------------------------------
 # GEEPlan: resolved stages + executor
 # ---------------------------------------------------------------------------
@@ -437,7 +403,6 @@ class GEEPlan:
     backend: str                      # resolved; never "auto"
     chunk_edges: Optional[int] = None
     impl: str = "auto"                # epilogue row-norm impl
-    fused: bool = False               # pallas-only: fused-epilogue megakernel
     # streaming backends only: windows staged ahead by background threads
     # (resolved by build(); None defers to the env default at execute time)
     prefetch_windows: Optional[int] = None
@@ -447,7 +412,6 @@ class GEEPlan:
               opts: GEEOptions = GEEOptions(), *, backend: str = "auto",
               device: str | None = None, chunk_edges: int | None = None,
               budget_bytes: int | None = None, impl: str = "auto",
-              fused: "bool | str" = "auto",
               prefetch_windows: int | None = None) -> "GEEPlan":
         prepared = PreparedGraph.wrap(graph)
         if backend == "auto":
@@ -458,8 +422,6 @@ class GEEPlan:
                 f"unknown backend {backend!r}; known: {KNOWN_BACKENDS} "
                 f"(+ 'auto'; 'distributed' needs an explicit mesh -- use "
                 f"GEEEmbedder, or 'streamed_sharded' for the default mesh)")
-        if fused == "auto":
-            fused = select_fused(backend, opts, device=device)
         if backend in ("chunked", "streamed_sharded"):
             from repro.graph.prefetch import resolve_prefetch_depth
             prefetch_windows = resolve_prefetch_depth(prefetch_windows)
@@ -467,10 +429,15 @@ class GEEPlan:
             prefetch_windows = None      # knob only exists for streaming
         return GEEPlan(prepared=prepared, num_classes=int(num_classes),
                        opts=opts, backend=backend, chunk_edges=chunk_edges,
-                       impl=impl, fused=bool(fused) and backend == "pallas",
-                       prefetch_windows=prefetch_windows)
+                       impl=impl, prefetch_windows=prefetch_windows)
 
     # -- introspection -------------------------------------------------------
+    @property
+    def fused(self) -> bool:
+        """Whether the fit runs the fused-epilogue kernel: always on the
+        ``pallas`` backend, never elsewhere."""
+        return self.backend == "pallas"
+
     @property
     def _prefetch_detail(self) -> str:
         """Human-readable prefetch depth for ``stages``/``describe()``."""
@@ -488,8 +455,11 @@ class GEEPlan:
                 detail="self-loop augment + laplacian fold"))
             out.append(PlanStage("compute", "segment_scatter",
                                  detail="flat segment-sum, O(E)"))
+            if o.correlation:
+                out.append(PlanStage("epilogue", "row_l2_normalize",
+                                     detail=f"impl={self.impl}"))
         elif self.backend == "pallas":
-            # both packings hold the *base* graph: diag-aug folds in as
+            # the packing holds the *base* graph: diag-aug folds in as
             # deg+1 plus the per-row addend
             out.append(PlanStage(
                 "prep", "bucketed_ell",
@@ -500,14 +470,9 @@ class GEEPlan:
                 cached=p.is_cached(("bucket_scaling", o.laplacian,
                                     o.diag_aug)),
                 detail="degrees + laplacian-scaled bucket planes (device)"))
-            if self.fused:
-                out.append(PlanStage(
-                    "compute", "gee_spmm_fused",
-                    detail="scatter + diag-aug + row-norm fused in VMEM"))
-            else:
-                out.append(PlanStage(
-                    "compute", "gee_spmm",
-                    detail="MXU one-hot contraction per bucket"))
+            out.append(PlanStage(
+                "compute", "gee_spmm_fused",
+                detail="scatter + diag-aug + row-norm fused in VMEM"))
         elif self.backend == "chunked":
             from repro.graph.io import DEFAULT_CHUNK_EDGES
 
@@ -542,19 +507,11 @@ class GEEPlan:
                                  cached=p.is_cached(("host",)),
                                  detail="valid-prefix numpy triple"))
             out.append(PlanStage("compute", self.backend))
-        if o.correlation and not self.fused \
-                and self.backend not in ("chunked", "streamed_sharded",
-                                         "dense_jax", "scipy",
-                                         "python_loop"):
-            out.append(PlanStage("epilogue", "row_l2_normalize",
-                                 detail=f"impl={self.impl}"))
         return tuple(out)
 
     def describe(self) -> str:
         """One line per stage, e.g. for ``--plan`` CLI output."""
-        head = (f"GEEPlan(backend={self.backend}"
-                + (", fused" if self.fused else "")
-                + f", opts={self.opts.tag()}, "
+        head = (f"GEEPlan(backend={self.backend}, opts={self.opts.tag()}, "
                 f"N={self.prepared.num_nodes}, "
                 f"E={self.prepared.num_edges}, K={self.num_classes})")
         lines = [head]
@@ -626,26 +583,12 @@ class GEEPlan:
                 "prep", "bucket_scaling",
                 p.is_cached(("bucket_scaling", o.laplacian, o.diag_aug)),
                 lambda: p.bucket_scaling(o.laplacian, o.diag_aug))
-            if self.fused:
-                from repro.kernels.gee_fused import gee_fused_from_bucketed
+            from repro.kernels.gee_fused import gee_fused_from_bucketed
 
-                return self._stage(
-                    "compute", "gee_spmm_fused", False,
-                    lambda: gee_fused_from_bucketed(
-                        bell, labels, k, o, scaling=scaling))
-            from repro.kernels.ops import gee_pallas_from_bucketed
-
-            z = self._stage(
-                "compute", "gee_spmm", False,
-                lambda: gee_pallas_from_bucketed(
-                    bell, labels, k,
-                    GEEOptions(laplacian=o.laplacian, diag_aug=o.diag_aug),
-                    scaling=scaling))
-            if o.correlation:      # epilogue honors this plan's impl choice
-                z = self._stage(
-                    "epilogue", "row_l2_normalize", False,
-                    lambda: epilogue.row_l2_normalize(z, impl=self.impl))
-            return z
+            return self._stage(
+                "compute", "gee_spmm_fused", False,
+                lambda: gee_fused_from_bucketed(
+                    bell, labels, k, o, scaling=scaling))
         if self.backend == "chunked":
             from repro.core.chunked import gee_chunked
 
@@ -732,6 +675,6 @@ def sweep_options(graph: PreparedGraph | EdgeList, labels, num_classes: int,
 Graph = Union[PreparedGraph, EdgeList]
 
 __all__ = ["PreparedGraph", "GEEPlan", "PlanStage", "select_backend",
-           "select_fused", "sweep_options", "estimate_working_set_bytes",
+           "sweep_options", "estimate_working_set_bytes",
            "memory_budget_bytes", "KNOWN_BACKENDS", "ENV_MEMORY_BUDGET",
            "DEFAULT_MEMORY_BUDGET", "PALLAS_MAX_CLASSES"]

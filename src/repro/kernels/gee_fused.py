@@ -1,13 +1,10 @@
-"""Fused GEE epilogue megakernel: scatter + diag-aug + row-norm in VMEM.
+"""The Pallas backend's fit: ELL contraction + diag-aug + row-norm in VMEM.
 
-The staged Pallas path (``repro.kernels.ops``) materializes the full
-[N, K] embedding twice: once between the ``gee_spmm`` scatter and the
-epilogue (diag-aug fold, row L2 norm), and once more inside the epilogue
-itself.  One-Hot GEE (arXiv 2109.13098) shows the method is
-memory-bandwidth-bound at scale and Edge-Parallel GEE (arXiv 2402.04403)
-that the scatter is the only stage needing global memory -- so this
-module fuses the whole O(N*K) epilogue into the scatter's resident
-output tile:
+One-Hot GEE (arXiv 2109.13098) shows the method is memory-bandwidth-bound
+at scale and Edge-Parallel GEE (arXiv 2402.04403) that the scatter is the
+only stage needing global memory -- so this module fuses the whole O(N*K)
+epilogue into the scatter's resident output tile, and never materializes
+an un-normalized [N, K] embedding:
 
   * the contraction is ``_gee_spmm_kernel``'s own
     (:func:`repro.kernels.gee_spmm.contract_tile`);
@@ -16,30 +13,26 @@ output tile:
     ``z[i, y_i] += dinv_i^2 * winv[y_i]`` (the streaming backends' trick
     from ``repro.core.epilogue.diag_aug_epilogue``: degrees get +1, no
     self-loop edges are ever packed) and row-L2-normalizes with the
-    shared ``EPS_NORM`` clamp.
+    shared ``EPS_NORM`` clamp.  Either step is static: a fit without
+    diag-aug passes an empty ``rowlab``, one without correlation skips
+    the norm, so one driver serves all 8 option settings.
 
-The numerics are the ones in :mod:`repro.core.epilogue` verbatim; the
-staged path stays untouched as the differential reference
-(``tests/test_fused_differential.py`` holds the two to <= 1e-5 under all
-8 option settings).
+The numerics are the ones in :mod:`repro.core.epilogue` verbatim
+(``tests/test_fused_differential.py`` holds the fit to ``gee_scipy`` to
+<= 1e-5 under all 8 option settings).
 
 Degree-0 rows appear in *no* ELL bucket (see ``repro.graph.ell``), so a
 per-bucket fused launch can never visit them; when a packing has any
 (a count known when its scaling is built), ``gee_fused_from_bucketed``
 gives those rows the identical shared-epilogue arithmetic applied to
-zero rows.
-
-``REPRO_GEE_FUSED=0/1`` overrides the plan-layer cost model
-(``repro.core.plan.select_fused``); unset defers to it.  On CPU the
-kernels run in interpret mode (:mod:`repro.kernels.platform`), so the
-cost model only selects the fused stage on a TPU.
+zero rows.  On CPU the kernels run in interpret mode
+(:mod:`repro.kernels.platform`).
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-import os
 from typing import Tuple
 
 import jax
@@ -49,8 +42,7 @@ from jax.experimental import pallas as pl
 
 from repro.core.epilogue import EPS_NORM, apply_epilogue, inv_sqrt_degrees
 from repro.core.gee import GEEOptions, class_weight_inv
-from repro.graph.containers import ELL
-from repro.graph.ell import BucketedELL, ELLBucket, ell_planes
+from repro.graph.ell import BucketedELL, ell_planes
 from repro.kernels.autotune import REGISTRY, ceil_to, pow2_bucket
 from repro.kernels.gee_spmm import (LANE, _block_sizes_formula, clamp_blocks,
                                     contract_tile, measured_block_search,
@@ -59,23 +51,12 @@ from repro.kernels.platform import interpret_mode
 from repro.obs import metrics as obs_metrics
 from repro.obs import trace as obs_trace
 
-ENV_FUSED = "REPRO_GEE_FUSED"
-
 KERNEL_NAME = "gee_spmm_fused"
 # The fused kernel's tile geometry matches gee_spmm (the epilogue adds no
 # VMEM-resident operand bigger than the output block itself), so it shares
 # the formula; measured entries are recorded under its own name so
 # on-device search can diverge where the epilogue tail matters.
 REGISTRY.register(KERNEL_NAME, fallback=_block_sizes_formula)
-
-
-def fused_override() -> bool | None:
-    """The ``REPRO_GEE_FUSED`` env override: True/False when set, None
-    when unset (defer to the cost model)."""
-    raw = os.environ.get(ENV_FUSED)
-    if raw is None or raw == "":
-        return None
-    return raw not in ("0", "false", "False", "no")
 
 
 # ---------------------------------------------------------------------------
@@ -245,22 +226,13 @@ def _gee_fused_jit(ylab, contrib, rowlab, dadd, num_classes: int,
 
 
 # ---------------------------------------------------------------------------
-# full-pipeline drivers (what the plan layer executes)
+# the fit driver (what the plan layer executes)
 # ---------------------------------------------------------------------------
-
-def bucket_span(idx: int, b: ELLBucket):
-    """The ``plan.bucket`` span of one bucket's work, tagged with the
-    packing's counts: packed ``rows``, ``width``, ``slots`` (rows x
-    width) and real ``edges``."""
-    return obs_trace.span("plan.bucket", idx=idx, rows=int(b.cols.shape[0]),
-                          width=b.width, slots=b.slots, edges=b.num_edges)
-
 
 def labels_span(labels, n: int, num_classes: int):
     """The ``plan.labels`` span (tags ``n``, ``k``) of a bucketed fit's
-    host label step: the upload of host labels, and in the staged driver
-    the class weights and Z's allocation (the fused driver's program
-    computes those).  Moves ``plan.labels.vertices`` by ``n`` and, when
+    host label step: the upload of host labels (the fit program computes
+    the class weights).  Moves ``plan.labels.vertices`` by ``n`` and, when
     ``labels`` is a host array, ``plan.labels.known`` by its known (>= 0)
     labels; a device array is never read back."""
     reg = obs_metrics.get_registry()
@@ -279,41 +251,6 @@ def _diag_addend(labels, winv, dinv, diag_aug: bool):
     ys = jnp.where(valid, labels, 0)
     dadd = jnp.where(valid, dinv * dinv * winv[ys], 0.0)
     return labels.astype(jnp.int32), dadd.astype(jnp.float32)
-
-
-def gee_fused_from_ell(ell: ELL, labels: jax.Array, num_classes: int,
-                       opts: GEEOptions = GEEOptions(), *,
-                       block_rows: int | None = None,
-                       block_deg: int | None = None,
-                       interpret: bool | None = None) -> jax.Array:
-    """Fused GEE from a flat ELL packing of the *base* graph (no appended
-    self loops: diagonal augmentation folds in as degrees+1 and the
-    in-kernel ``dinv^2 * winv[y]`` addend, exactly like the streaming
-    backends)."""
-    if interpret is None:
-        interpret = interpret_mode()
-    labels = jnp.asarray(labels, jnp.int32)
-    n = ell.num_nodes
-    vals, cols = ell.vals, ell.cols
-    n_rows = vals.shape[0]                 # row-padded plane height
-    winv = class_weight_inv(labels, num_classes)
-    labels_rows = jnp.full((n_rows,), -1, jnp.int32).at[:n].set(labels)
-
-    if opts.laplacian:
-        deg = jnp.sum(vals, axis=1)        # padding rows -> 0
-        if opts.diag_aug:
-            deg = deg + 1.0                # the un-packed self loop
-        dinv = inv_sqrt_degrees(deg)
-        vals = vals * dinv[:, None] * dinv[jnp.clip(cols, 0, n_rows - 1)]
-    else:
-        dinv = jnp.ones((n_rows,), jnp.float32)
-
-    ylab, contrib = ell_planes(cols, vals, labels, winv)
-    rowlab, dadd = _diag_addend(labels_rows, winv, dinv, opts.diag_aug)
-    z = gee_spmm_fused(ylab, contrib, rowlab, dadd, num_classes,
-                       correlation=opts.correlation, block_rows=block_rows,
-                       block_deg=block_deg, interpret=interpret)
-    return z[:n]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -505,8 +442,6 @@ def _fused_fit(labels, cols, row_ids, vals, row_dinv, dinv, uncovered,
     return z
 
 
-__all__ = ["ENV_FUSED", "KERNEL_NAME", "fused_override",
-           "choose_fused_block_sizes", "gee_spmm_fused",
-           "gee_spmm_fused_padded", "gee_fused_from_ell",
-           "gee_fused_from_bucketed", "BucketScaling", "scale_buckets",
-           "labels_span"]
+__all__ = ["KERNEL_NAME", "choose_fused_block_sizes", "gee_spmm_fused",
+           "gee_spmm_fused_padded", "gee_fused_from_bucketed",
+           "BucketScaling", "scale_buckets", "labels_span"]

@@ -364,13 +364,7 @@ def _gathered_pallas(queries, cand, mask, metric, block_q, block_m,
 
 def fused_topk_enabled(impl: str = "auto") -> bool:
     """Whether the serving path should route through the fused kernels:
-    ``REPRO_GEE_FUSED`` wins when set; otherwise fused iff the resolved
-    impl is ``pallas`` (i.e. a real TPU under ``auto``)."""
-    from repro.kernels.gee_fused import fused_override  # deferred: no cycle
-
-    override = fused_override()
-    if override is not None:
-        return bool(override)
+    iff the resolved impl is ``pallas`` (i.e. a real TPU under ``auto``)."""
     return _resolve_impl(impl) == "pallas"
 
 

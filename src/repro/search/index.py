@@ -217,8 +217,6 @@ class ClassPartitionedIndex:
         self.stats["queries"] += int(queries.shape[0])
         p = self.nprobe if nprobe is None else int(nprobe)
         p = max(1, min(p, int(self._active.shape[0])))
-        # resolved per call (not inside the jitted body) so flipping
-        # REPRO_GEE_FUSED between calls re-routes without a stale trace
         fused = fused_topk_enabled(self.impl)
         if brute_force:
             self.stats["brute_force_queries"] += int(queries.shape[0])

@@ -1,13 +1,11 @@
-"""Differential fuzz harness for the fused GEE epilogue megakernel.
+"""Differential fuzz harness for the Pallas backend's fused fit.
 
 The fused path (``repro.kernels.gee_fused``) re-derives the whole
 O(N*K) epilogue inside the scatter kernel, so every numerics bug it
 could introduce is a *divergence* from an existing reference.  This
-module holds it to three of them at once:
+module holds it to two of them:
 
-  * ``gee_scipy`` -- the paper-faithful ground truth;
-  * the staged Pallas path (``gee_pallas_from_bucketed``) -- identical
-    packing, epilogue applied as separate stages;
+  * ``gee_scipy`` -- the paper-faithful ground truth, for whole fits;
   * a pure-numpy oracle for the raw kernel contract (tile boundaries,
     padding lanes, ragged tails).
 
@@ -32,18 +30,15 @@ try:                                       # only the fuzz test needs it
 except ImportError:                        # pragma: no cover
     HAVE_HYPOTHESIS = False
 
-from repro.core.epilogue import EPS_NORM, row_l2_normalize
+from repro.core.epilogue import EPS_NORM
 from repro.core.gee import ALL_OPTION_SETTINGS, GEEOptions, gee, gee_scipy
-from repro.core.plan import (KNOWN_BACKENDS, GEEPlan, PreparedGraph,
-                             select_fused)
-from repro.graph.containers import edge_list_from_numpy, edges_to_ell, symmetrize
+from repro.core.plan import KNOWN_BACKENDS, GEEPlan, PreparedGraph
+from repro.graph.containers import edge_list_from_numpy, symmetrize
 from repro.graph.ell import edges_to_bucketed_ell
 from repro.kernels.autotune import AutotuneRegistry
 from repro.kernels import gee_fused
-from repro.kernels.gee_fused import (gee_fused_from_bucketed,
-                                     gee_fused_from_ell, gee_spmm_fused,
+from repro.kernels.gee_fused import (gee_fused_from_bucketed, gee_spmm_fused,
                                      scale_buckets)
-from repro.kernels.ops import gee_pallas_from_bucketed
 from repro.obs.metrics import MetricsRegistry, set_registry
 from repro.kernels.topk_score import (gathered_scores, masked_topk,
                                       pairwise_scores, scored_topk,
@@ -105,14 +100,14 @@ def _scipy_ref(edges, labels, k, opts):
 
 
 # ---------------------------------------------------------------------------
-# tentpole: fused vs staged vs scipy, all 8 settings
+# the fused fit vs scipy, all 8 settings
 # ---------------------------------------------------------------------------
 
 if HAVE_HYPOTHESIS:
     _fuzz = lambda f: settings(max_examples=12, deadline=None)(  # noqa: E731
         given(adversarial_graphs())(f))
-    # the edgeless graph Hypothesis recorded as a failure (N=1, E=0, K=1):
-    # the staged driver once skipped diag-aug on base-graph packings
+    # the edgeless graph Hypothesis once recorded as a failure (N=1, E=0,
+    # K=1): a base-graph packing must still apply diag-aug
     _edgeless = example(graph=(
         edge_list_from_numpy(np.zeros(0, np.int32), np.zeros(0, np.int32),
                              None, 1),
@@ -125,27 +120,17 @@ else:                                      # pragma: no cover
 
 @_fuzz
 @_edgeless
-def test_fused_matches_staged_and_scipy(graph):
+def test_fused_matches_scipy(graph):
     edges, labels, k = graph
     labels_j = jnp.asarray(labels)
     bell = edges_to_bucketed_ell(edges)
-    ell = edges_to_ell(edges)
     for opts in ALL_OPTION_SETTINGS:
-        ref = _scipy_ref(edges, labels, k, opts)
-        staged = np.asarray(gee_pallas_from_bucketed(
+        fused = np.asarray(gee_fused_from_bucketed(
             bell, labels_j, k, opts, interpret=True))
-        fused_b = np.asarray(gee_fused_from_bucketed(
-            bell, labels_j, k, opts, interpret=True))
-        fused_f = np.asarray(gee_fused_from_ell(
-            ell, labels_j, k, opts, interpret=True))
-        for name, out in [("staged", staged), ("fused-bucketed", fused_b),
-                          ("fused-flat", fused_f)]:
-            np.testing.assert_allclose(
-                out, ref, atol=1e-5,
-                err_msg=f"{name} vs scipy, {opts.tag()}, "
-                        f"n={edges.num_nodes} k={k}")
-        np.testing.assert_allclose(fused_b, staged, atol=1e-5,
-                                   err_msg=f"fused vs staged, {opts.tag()}")
+        np.testing.assert_allclose(
+            fused, _scipy_ref(edges, labels, k, opts), atol=1e-5,
+            err_msg=f"fused vs scipy, {opts.tag()}, "
+                    f"n={edges.num_nodes} k={k}")
 
 
 def _fixed_adversarial():
@@ -174,40 +159,30 @@ def test_every_backend_matches_fused(backend, opts):
                                err_msg=f"{backend} vs fused, {opts.tag()}")
 
 
-@pytest.mark.parametrize("fused", [True, False], ids=["fused", "staged"])
 @pytest.mark.parametrize("opts", ALL_OPTION_SETTINGS, ids=OPT_IDS)
-def test_plan_scaling_matches_inline_build(fused, opts):
-    """The plan hands the bucketed drivers the prepared graph's memoized
+def test_plan_scaling_matches_inline_build(opts):
+    """The plan hands the fused driver the prepared graph's memoized
     scaling; Z on the building fit and on the reusing one is bit for bit
-    the same driver's with the scaling built inline."""
+    the driver's with the scaling built inline."""
     edges, labels, k = _fixed_adversarial()
     prep = PreparedGraph.wrap(edges)
-    plan = GEEPlan.build(prep, k, opts, backend="pallas", fused=fused)
+    plan = GEEPlan.build(prep, k, opts, backend="pallas")
     zs = [np.asarray(plan.execute(labels)) for _ in range(2)]
-    bell, y = prep.bucketed_ell(False), jnp.asarray(labels)
-    if fused:
-        inline = gee_fused_from_bucketed(bell, y, k, opts, interpret=True)
-    else:                      # the plan's staged route: scatter, then norm
-        inline = gee_pallas_from_bucketed(
-            bell, y, k, GEEOptions(laplacian=opts.laplacian,
-                                   diag_aug=opts.diag_aug), interpret=True)
-        if opts.correlation:
-            inline = row_l2_normalize(inline, impl=plan.impl)
+    inline = gee_fused_from_bucketed(prep.bucketed_ell(False),
+                                     jnp.asarray(labels), k, opts,
+                                     interpret=True)
     for z in zs:
         assert np.array_equal(z, np.asarray(inline)), opts.tag()
 
 
-@pytest.mark.parametrize("driver", [gee_fused_from_bucketed,
-                                    gee_pallas_from_bucketed],
-                         ids=["fused", "staged"])
-def test_scaling_for_other_options_is_refused(driver):
+def test_scaling_for_other_options_is_refused():
     edges, labels, k = _fixed_adversarial()
     bell = edges_to_bucketed_ell(edges)
     sc = scale_buckets(bell, laplacian=True, diag_aug=False)
     with pytest.raises(ValueError, match="scaling built for"):
-        driver(bell, jnp.asarray(labels), k,
-               GEEOptions(laplacian=True, diag_aug=True), scaling=sc,
-               interpret=True)
+        gee_fused_from_bucketed(bell, jnp.asarray(labels), k,
+                                GEEOptions(laplacian=True, diag_aug=True),
+                                scaling=sc, interpret=True)
 
 
 # ---------------------------------------------------------------------------
@@ -225,13 +200,12 @@ def _no_degree0_graph(n=40, k=3, seed=5):
     return symmetrize(edge_list_from_numpy(src, dst, w, n)), labels, k
 
 
-def test_fresh_embedders_share_one_traced_program(monkeypatch):
+def test_fresh_embedders_share_one_traced_program():
     """Three fits with fresh labels, each through a fresh embedder and
     plan over one prepared graph, dispatch the fit program three times
     and trace it once."""
     from repro.core.api import GEEEmbedder
 
-    monkeypatch.setenv("REPRO_GEE_FUSED", "1")
     edges, _, k = _fixed_adversarial()
     prep = PreparedGraph.wrap(edges)
     rng = np.random.default_rng(0)
@@ -259,7 +233,7 @@ def test_fresh_embedders_share_one_traced_program(monkeypatch):
 def test_residual_traced_only_with_degree0_rows(monkeypatch, degree0):
     """The degree-0 residual is static: a packing that covers every row
     traces no epilogue outside the kernel; one with degree-0 rows traces
-    it once.  Both match the staged driver."""
+    it once.  Both match the SciPy reference."""
     edges, labels, k = (_fixed_adversarial() if degree0
                         else _no_degree0_graph())
     opts = GEEOptions(laplacian=True, diag_aug=True, correlation=True)
@@ -278,22 +252,82 @@ def test_residual_traced_only_with_degree0_rows(monkeypatch, degree0):
     finally:
         gee_fused._fused_fit.clear_cache()
     assert len(traced) == int(degree0)
-    staged = gee_pallas_from_bucketed(bell, jnp.asarray(labels), k, opts,
-                                      scaling=sc, interpret=True)
-    np.testing.assert_allclose(np.asarray(z), np.asarray(staged), atol=1e-5)
+    np.testing.assert_allclose(np.asarray(z),
+                               _scipy_ref(edges, labels, k, opts), atol=1e-5)
 
 
 @pytest.mark.parametrize("opts", ALL_OPTION_SETTINGS, ids=OPT_IDS)
-def test_degree0_rows_match_staged(opts):
+def test_degree0_rows_match_scipy(opts):
     edges, labels, k = _fixed_adversarial()      # isolated tail 8..22
     bell = edges_to_bucketed_ell(edges)
     assert scale_buckets(bell, laplacian=opts.laplacian,
                          diag_aug=opts.diag_aug).num_uncovered == 15
-    y = jnp.asarray(labels)
-    fused = gee_fused_from_bucketed(bell, y, k, opts, interpret=True)
-    staged = gee_pallas_from_bucketed(bell, y, k, opts, interpret=True)
-    np.testing.assert_allclose(np.asarray(fused), np.asarray(staged),
+    fused = gee_fused_from_bucketed(bell, jnp.asarray(labels), k, opts,
+                                    interpret=True)
+    np.testing.assert_allclose(np.asarray(fused),
+                               _scipy_ref(edges, labels, k, opts),
                                atol=1e-5, err_msg=opts.tag())
+
+
+def _relabelled(edges, labels, seed=3):
+    """The graph with its vertex ids permuted, and its labels moved with
+    them: the same degree sequence, so the same bucket shapes."""
+    n = edges.num_nodes
+    perm = np.random.default_rng(seed).permutation(n)
+    src, dst, w = edges.valid_arrays()
+    moved = np.empty_like(labels)
+    moved[perm] = labels
+    return (edge_list_from_numpy(perm[src], perm[dst], w, n).with_padding(64),
+            moved, perm)
+
+
+@pytest.mark.parametrize("opts", ALL_OPTION_SETTINGS, ids=OPT_IDS)
+def test_same_shaped_graphs_share_one_trace(opts):
+    """Two prepared graphs of the same bucket shapes -- one graph and a
+    vertex-id permutation of it, as the benchmark's seeds are -- run one
+    traced fit program: the packing's arrays are its arguments, never
+    closed over.  Each Z is its own graph's."""
+    edges, labels, k = _fixed_adversarial()
+    edges2, labels2, perm = _relabelled(edges, labels)
+    shapes = [[b.cols.shape for b in edges_to_bucketed_ell(e).buckets]
+              for e in (edges, edges2)]
+    assert shapes[0] == shapes[1]
+    gee_fused._fused_fit.clear_cache()
+    reg = MetricsRegistry()
+    prev = set_registry(reg)
+    try:
+        zs = [np.asarray(GEEPlan.build(PreparedGraph.wrap(e), k, opts,
+                                       backend="pallas").execute(y))
+              for e, y in ((edges, labels), (edges2, labels2))]
+    finally:
+        set_registry(prev)
+    counters = reg.snapshot()["counters"]
+    assert counters["plan.fit_program.calls"] == 2
+    assert counters["plan.fit_program.traces"] == 1
+    for z, e, y in zip(zs, (edges, edges2), (labels, labels2)):
+        np.testing.assert_allclose(z, _scipy_ref(e, y, k, opts), atol=1e-5,
+                                   err_msg=opts.tag())
+    np.testing.assert_allclose(zs[1][perm], zs[0], atol=1e-6)
+
+
+@pytest.mark.parametrize("opts", ALL_OPTION_SETTINGS, ids=OPT_IDS)
+def test_pallas_plan_dispatches_one_program_a_fit(opts):
+    """Every option setting -- diag-aug and correlation on or off -- runs
+    the ``pallas`` plan as one fit program: one dispatch an execute, and
+    Z matches the SciPy reference."""
+    edges, labels, k = _no_degree0_graph()
+    plan = GEEPlan.build(edges, k, opts, backend="pallas")
+    assert plan.fused
+    reg = MetricsRegistry()
+    prev = set_registry(reg)
+    try:
+        for fit in (1, 2):
+            z = np.asarray(plan.execute(labels))
+            assert reg.counter("plan.fit_program.calls").value == fit
+            np.testing.assert_allclose(z, _scipy_ref(edges, labels, k, opts),
+                                       atol=1e-5, err_msg=opts.tag())
+    finally:
+        set_registry(prev)
 
 
 # ---------------------------------------------------------------------------
@@ -383,52 +417,19 @@ def test_fused_kernel_no_diag_when_rowlab_empty():
 # plan-layer surface
 # ---------------------------------------------------------------------------
 
-def test_plan_fused_matches_staged_and_describes():
+def test_plan_fused_matches_scipy_and_describes():
     edges, labels, k = _fixed_adversarial()
     opts = GEEOptions(laplacian=True, diag_aug=True, correlation=True)
-    plan_f = GEEPlan.build(edges, k, opts, backend="pallas", fused=True)
-    plan_s = GEEPlan.build(edges, k, opts, backend="pallas", fused=False)
-    z_f = np.asarray(plan_f.execute(labels))
-    z_s = np.asarray(plan_s.execute(labels))
-    np.testing.assert_allclose(z_f, z_s, atol=1e-5)
-    assert "fused" in plan_f.describe()
-    assert any(s.name == "gee_spmm_fused" for s in plan_f.stages)
-    assert all(s.name != "gee_spmm_fused" for s in plan_s.stages)
-    # fused folds the epilogue into compute: no separate row-norm stage
-    assert all(s.kind != "epilogue" for s in plan_f.stages)
-    assert any(s.kind == "epilogue" for s in plan_s.stages)
-
-
-def test_select_fused_cost_model(monkeypatch):
-    monkeypatch.delenv("REPRO_GEE_FUSED", raising=False)
-    opts = GEEOptions(diag_aug=True, correlation=True)
-    assert select_fused("pallas", opts, device="tpu")
-    assert not select_fused("pallas", opts, device="cpu")
-    assert not select_fused("pallas", GEEOptions(), device="tpu")
-    assert not select_fused("sparse_jax", opts, device="tpu")
-
-
-def test_select_fused_env_override(monkeypatch):
-    opts = GEEOptions(diag_aug=True, correlation=True)
-    monkeypatch.setenv("REPRO_GEE_FUSED", "1")
-    assert select_fused("pallas", opts, device="cpu")
-    assert select_fused("pallas", GEEOptions(), device="cpu")
-    # the override never drags a non-pallas backend onto the kernel path
-    assert not select_fused("sparse_jax", opts, device="tpu")
-    monkeypatch.setenv("REPRO_GEE_FUSED", "0")
-    assert not select_fused("pallas", opts, device="tpu")
-
-
-def test_plan_build_honors_env_override(monkeypatch):
-    edges, labels, k = _fixed_adversarial()
-    opts = GEEOptions(diag_aug=True, correlation=True)
-    monkeypatch.setenv("REPRO_GEE_FUSED", "1")
     plan = GEEPlan.build(edges, k, opts, backend="pallas")
-    assert plan.fused
     np.testing.assert_allclose(np.asarray(plan.execute(labels)),
                                _scipy_ref(edges, labels, k, opts), atol=1e-5)
-    monkeypatch.setenv("REPRO_GEE_FUSED", "0")
-    assert not GEEPlan.build(edges, k, opts, backend="pallas").fused
+    assert plan.fused
+    assert [(s.kind, s.name) for s in plan.stages if s.kind != "prep"] \
+        == [("compute", "gee_spmm_fused")]
+    # the epilogue lives in the kernel: no separate row-norm stage
+    assert "gee_spmm_fused" in plan.describe()
+    assert "epilogue" not in plan.describe()
+    assert not GEEPlan.build(edges, k, opts, backend="sparse_jax").fused
 
 
 # ---------------------------------------------------------------------------

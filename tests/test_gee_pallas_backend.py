@@ -8,10 +8,10 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core.api import GEEEmbedder
-from repro.core.gee import (ALL_OPTION_SETTINGS, GEEOptions, gee,
+from repro.core.gee import (ALL_OPTION_SETTINGS, gee,
                             gee_sparse_jax, select_backend)
 from repro.graph.containers import edge_list_from_numpy, symmetrize
-from repro.kernels import choose_block_sizes, gee_pallas, gee_spmm
+from repro.kernels import choose_block_sizes, gee_spmm
 from repro.kernels.ref import gee_spmm_ref
 
 pytestmark = pytest.mark.pallas_interpret
@@ -30,17 +30,6 @@ def test_pallas_backend_matches_sparse_jax(sbm_small, opts):
     zr = np.asarray(gee_sparse_jax(s.edges, jnp.asarray(s.labels),
                                    s.num_classes, opts))
     np.testing.assert_allclose(zp, zr, atol=1e-5, err_msg=opts.tag())
-
-
-@pytest.mark.parametrize("bucketed", [True, False])
-def test_both_packings_agree(sbm_small, bucketed):
-    s = sbm_small
-    opts = GEEOptions(laplacian=True, diag_aug=True, correlation=True)
-    zp = np.asarray(gee_pallas(s.edges, s.labels, s.num_classes, opts,
-                               bucketed=bucketed))
-    zr = np.asarray(gee_sparse_jax(s.edges, jnp.asarray(s.labels),
-                                   s.num_classes, opts))
-    np.testing.assert_allclose(zp, zr, atol=1e-5)
 
 
 def test_auto_backend_dispatches(sbm_small):

@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.kernels import gee_pallas, gee_spmm, row_norm
+from repro.kernels import gee_spmm, row_norm
 from repro.kernels.ref import gee_spmm_ref, row_norm_ref
 
 pytestmark = pytest.mark.pallas_interpret
@@ -105,14 +105,3 @@ def test_row_norm_bf16_input():
     ref = row_norm_ref(z)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-2)
 
-
-def test_gee_pallas_end_to_end_vs_core(sbm_small):
-    """Full pipeline (edge list -> ELL -> kernels) vs the core sparse path."""
-    from repro.core.gee import ALL_OPTION_SETTINGS, gee_sparse_jax
-
-    s = sbm_small
-    for opts in ALL_OPTION_SETTINGS:
-        zp = np.asarray(gee_pallas(s.edges, s.labels, s.num_classes, opts))
-        zr = np.asarray(gee_sparse_jax(s.edges, jnp.asarray(s.labels),
-                                       s.num_classes, opts))
-        np.testing.assert_allclose(zp, zr, atol=1e-5, err_msg=opts.tag())

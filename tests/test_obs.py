@@ -490,21 +490,18 @@ def test_stream_traced_fit_adds_no_sync(fresh_obs, sbm_small, sync_counter,
 
 
 @pytest.mark.pallas_interpret
-@pytest.mark.parametrize("fused", [True, False])
-def test_bucket_spans_carry_packing_counts(fresh_obs, fused):
-    """The staged driver tags each ``plan.bucket`` span with its bucket's
-    packed slots and real entries, which sum over the fit to the
-    packing's totals; the fused driver dispatches the whole fit as one
-    program under one ``plan.fit`` span tagged with those totals, and
-    opens no per-bucket host span.  The host packing's own span says the
-    same.  The label-independent degree fold and scaling run under the
-    ``bucket_scaling`` prep stage of the first execute only."""
+def test_bucket_spans_carry_packing_counts(fresh_obs):
+    """The fit dispatches as one program under one ``plan.fit`` span
+    tagged with the packing's totals -- buckets, packed rows, slots and
+    real entries -- and opens no per-bucket host span.  The host
+    packing's own span says the same.  The label-independent degree fold
+    and scaling run under the ``bucket_scaling`` prep stage of the first
+    execute only."""
     tracer, _ = fresh_obs
     tracer.enable()
     s = sample_sbm(120, seed=4)
     prep = PreparedGraph.wrap(s.edges)
-    plan = GEEPlan.build(prep, s.num_classes, OPTS_ALL, backend="pallas",
-                         fused=fused)
+    plan = GEEPlan.build(prep, s.num_classes, OPTS_ALL, backend="pallas")
     z = plan.execute(s.labels)
     z_ref = gee(prep, s.labels, s.num_classes, OPTS_ALL,
                 backend="sparse_jax")
@@ -520,33 +517,12 @@ def test_bucket_spans_carry_packing_counts(fresh_obs, fused):
     bucket_spans = ("plan.bucket", "plan.bucket.planes",
                     "plan.bucket.launch", "plan.bucket.scatter",
                     "plan.bucket.residual")
-    if fused:
-        (fit,) = [e for e in events if e.name == "plan.fit"]
-        assert fit.args == {"buckets": len(bell.buckets),
-                            "rows": pack.args["rows"],
-                            "slots": bell.total_slots, "edges": real}
-        assert bell.total_edges == real
-        assert not [e for e in events if e.name in bucket_spans]
-    else:
-        buckets = sorted((e for e in events if e.name == "plan.bucket"),
-                         key=lambda e: e.args["idx"])
-        assert [e.args["idx"] for e in buckets] \
-            == list(range(len(bell.buckets)))
-        for e, b in zip(buckets, bell.buckets):
-            a = e.args
-            assert a["slots"] == a["rows"] * a["width"] >= a["edges"] > 0
-            assert (a["width"], a["edges"]) == (b.width, b.num_edges)
-        assert sum(e.args["slots"] for e in buckets) == bell.total_slots
-        assert sum(e.args["edges"] for e in buckets) \
-            == bell.total_edges == real
-        assert pack.args["rows"] == sum(e.args["rows"] for e in buckets)
-        # every bucket's work runs in its three named phases, nested in it
-        for phase in ("planes", "launch", "scatter"):
-            inner = [e for e in events if e.name == "plan.bucket." + phase]
-            assert len(inner) == len(buckets)
-            assert all(e.depth == buckets[0].depth + 1 for e in inner)
-        names = {e.name for e in events}
-        assert "plan.bucket.residual" not in names
+    (fit,) = [e for e in events if e.name == "plan.fit"]
+    assert fit.args == {"buckets": len(bell.buckets),
+                        "rows": pack.args["rows"],
+                        "slots": bell.total_slots, "edges": real}
+    assert bell.total_edges == real
+    assert not [e for e in events if e.name in bucket_spans]
     # the one-time build: degrees once, one scale span per bucket, both
     # inside the bucket_scaling prep stage
     (stage,) = [e for e in events if e.name == "plan.stage.bucket_scaling"]
@@ -571,24 +547,17 @@ def test_bucket_spans_carry_packing_counts(fresh_obs, fused):
     assert "plan.bucket.scale" not in names
     (stage,) = [e for e in events if e.name == "plan.stage.bucket_scaling"]
     assert stage.args["cached"] is True
-    if fused:
-        assert names.count("plan.fit") == 1
-        assert not set(names) & set(bucket_spans)
-    else:
-        for phase in ("", ".planes", ".launch", ".scatter"):
-            assert names.count("plan.bucket" + phase) == len(bell.buckets)
-        assert "plan.bucket.residual" not in names
+    assert names.count("plan.fit") == 1
+    assert not set(names) & set(bucket_spans)
 
 
 @pytest.mark.pallas_interpret
-@pytest.mark.parametrize("fused", [True, False])
-def test_labels_span_once_per_fit_in_both_drivers(fresh_obs, sync_counter,
-                                                  fused):
+def test_labels_span_once_per_fit_in_both_drivers(fresh_obs, sync_counter):
     """A bucketed fit's host label step runs under one ``plan.labels``
-    span inside the plan's compute stage, before the first bucket of the
-    staged driver and before the fused driver's one ``plan.fit`` dispatch;
-    ``plan.labels.vertices`` moves every fit, ``plan.labels.known`` only
-    for host labels, and the span adds no wait on the device."""
+    span inside the plan's compute stage, before the fit's one
+    ``plan.fit`` dispatch; ``plan.labels.vertices`` moves every fit,
+    ``plan.labels.known`` only for host labels, and the span adds no wait
+    on the device."""
     import jax.numpy as jnp
 
     tracer, reg = fresh_obs
@@ -597,28 +566,22 @@ def test_labels_span_once_per_fit_in_both_drivers(fresh_obs, sync_counter,
     labels[::3] = -1
     known = int(np.count_nonzero(labels >= 0))
     prep = PreparedGraph.wrap(s.edges)
-    plan = GEEPlan.build(prep, s.num_classes, OPTS_ALL, backend="pallas",
-                         fused=fused)
+    plan = GEEPlan.build(prep, s.num_classes, OPTS_ALL, backend="pallas")
     syncs_off, syncs_on, z_off, z_on = _traced_against_untraced(
         tracer, sync_counter, lambda: plan.execute(labels))
     np.testing.assert_array_equal(z_on, z_off)
     assert syncs_on == syncs_off == 0, (syncs_on, syncs_off)
 
     events = tracer.events()
-    stage_name = "plan.stage." + ("gee_spmm_fused" if fused else "gee_spmm")
-    (stage,) = [e for e in events if e.name == stage_name]
+    (stage,) = [e for e in events if e.name == "plan.stage.gee_spmm_fused"]
     (lab,) = [e for e in events if e.name == "plan.labels"]
     assert lab.args == {"n": 120, "k": s.num_classes}
     assert lab.depth == stage.depth + 1 and lab.tid == stage.tid
     assert stage.ts_us <= lab.ts_us
     assert lab.ts_us + lab.dur_us <= stage.ts_us + stage.dur_us + 1.0
-    # the fused fit's one dispatch, or the staged driver's buckets
-    after = [e for e in events
-             if e.name == ("plan.fit" if fused else "plan.bucket")]
-    assert after and all(e.ts_us >= lab.ts_us + lab.dur_us - 1.0
-                         for e in after)
-    if fused:
-        assert after[0].depth == lab.depth and len(after) == 1
+    (fit,) = [e for e in events if e.name == "plan.fit"]
+    assert fit.ts_us >= lab.ts_us + lab.dur_us - 1.0
+    assert fit.depth == lab.depth
 
     counters = reg.snapshot()["counters"]        # three fits so far
     assert counters["plan.labels.vertices"] == 3 * 120

@@ -5,6 +5,7 @@ held against the float64 SciPy reference under all 8 option settings."""
 
 import hashlib
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -137,30 +138,36 @@ def mini():
     return ds, PreparedGraph.wrap(ds.edges), (src, dst, w)
 
 
-PATHS = [("pallas", "1"), ("pallas", "0"), ("sparse_jax", None)]
+PATHS = [("pallas", "host"), ("pallas", "device"), ("sparse_jax", "host")]
 
 
 # Tolerance 1e-5 max-abs, the repository's cross-backend gate: every path
 # accumulates in float32 (the Pallas contraction at HIGHEST), the
 # reference in float64; with correlation on, Z is row-normalised to <= 1,
 # and without it the entries are sums of at most a few hundred terms of
-# size <= 1/n_k, so float32 rounding stays near 1e-7 (all three paths read
+# size <= 1/n_k, so float32 rounding stays near 1e-7 (every path read
 # 1.2e-7 at most over the 8 settings on this graph).
 @pytest.mark.pallas_interpret
 @pytest.mark.parametrize("opts", ALL_OPTION_SETTINGS, ids=OPT_IDS)
-@pytest.mark.parametrize("backend,fused", PATHS,
-                         ids=["pallas-fused", "pallas-staged", "sparse_jax"])
-def test_paths_match_reference_k47_partly_labelled(mini, monkeypatch,
-                                                   backend, fused, opts):
+@pytest.mark.parametrize("backend,labels_on", PATHS,
+                         ids=["pallas-fused", "pallas-device-labels",
+                              "sparse_jax"])
+def test_paths_match_reference_k47_partly_labelled(mini, backend, labels_on,
+                                                   opts):
+    """Labels already on the device give the Pallas fit the same Z, bit
+    for bit, as the host labels they were uploaded from."""
     ds, prep, (src, dst, w) = mini
-    if fused is not None:
-        monkeypatch.setenv("REPRO_GEE_FUSED", fused)
+    labels = np.asarray(ds.labels)
     emb = GEEEmbedder(num_classes=47, options=opts, backend=backend)
-    z = np.asarray(emb.fit(prep, ds.labels).transform())
+    z = np.asarray(emb.fit(prep, jnp.asarray(labels) if labels_on == "device"
+                           else labels).transform())
     assert emb.plan.backend == backend
-    if fused is not None:
-        assert emb.plan.fused is (fused == "1")
-    ref = gee_scipy(src, dst, w, np.asarray(ds.labels), 47, opts,
+    assert emb.plan.fused is (backend == "pallas")
+    if labels_on == "device":
+        z_host = GEEEmbedder(num_classes=47, options=opts, backend=backend)
+        assert np.array_equal(
+            z, np.asarray(z_host.fit(prep, labels).transform()))
+    ref = gee_scipy(src, dst, w, labels, 47, opts,
                     num_nodes=MINI.num_nodes)
     assert z.shape == (MINI.num_nodes, 47)
     np.testing.assert_allclose(z, ref, rtol=0, atol=1e-5)

@@ -204,8 +204,7 @@ def test_plan_stages_and_describe():
 
 
 @pytest.mark.pallas_interpret
-@pytest.mark.parametrize("fused", [True, False], ids=["fused", "staged"])
-def test_pallas_refit_reuses_bucket_scaling(fused):
+def test_pallas_refit_reuses_bucket_scaling():
     """The Laplacian scaling is a prep stage built once per prepared
     graph: a second execute is a memo hit (no new miss, ``plan.cache_hits``
     up) and ``describe()`` lists the stage as cached; correlation never
@@ -215,8 +214,7 @@ def test_pallas_refit_reuses_bucket_scaling(fused):
     try:
         prep = PreparedGraph.wrap(_random_edges())
         labels = _random_labels()
-        plan = GEEPlan.build(prep, 4, OPTS_ALL, backend="pallas",
-                             fused=fused)
+        plan = GEEPlan.build(prep, 4, OPTS_ALL, backend="pallas")
         assert [(s.kind, s.name) for s in plan.stages][:2] == [
             ("prep", "bucketed_ell"), ("prep", "bucket_scaling")]
         assert "bucket_scaling (cached)" not in plan.describe()
@@ -230,7 +228,7 @@ def test_pallas_refit_reuses_bucket_scaling(fused):
         assert reg.counter("plan.cache_misses").value == cold["misses"]
         assert "bucket_scaling (cached)" in plan.describe()
         GEEPlan.build(prep, 4, GEEOptions(laplacian=True, diag_aug=True),
-                      backend="pallas", fused=fused).execute(labels)
+                      backend="pallas").execute(labels)
         assert prep.cache_info()["misses"] == cold["misses"]
     finally:
         set_registry(prev)

@@ -123,15 +123,15 @@ def test_kernel_named_in_compiled_hlo(one_chip, fused):
     assert f"/{name}/" in re.search(r'op_name="([^"]*)"', calls[0]).group(1)
 
 
-@pytest.mark.parametrize("k,buckets", [
+FIT_BUCKETS = [
     pytest.param(5, ((128, 2_000), (256, 1_000), (8_192, 8)), id="5"),
     pytest.param(47, ((8, 800), (64, 4_000), (8_192, 8)), id="47"),
-])
-def test_fused_fit_program_compiles(one_chip, k, buckets):
-    """The whole bucketed fit compiles as one program: one kernel launch
-    per bucket, and each bucket's label gather ``labels[cols]`` compiled
-    exactly once, named by its bucket's scope (XLA does not duplicate it
-    into the plane consumers)."""
+]
+
+
+def _compile_fit(one_chip, k, buckets, opts):
+    """Compile one bucketed fit program for a described chip; return its
+    text after checking one kernel launch per bucket."""
     n = N_ROWS
     args = [((n,), jnp.int32)]
     args += [((r, w), jnp.int32) for w, r in buckets]      # cols
@@ -147,13 +147,24 @@ def test_fused_fit_program_compiles(one_chip, k, buckets):
                                    for i in range(4))
         return _fused_fit(
             labels, cols, rows, vals, rdinv, *flat[-3:], num_classes=k,
-            opts=GEEOptions(laplacian=True, diag_aug=True, correlation=True),
+            opts=opts,
             blocks=tuple(choose_fused_block_sizes(r, w, k)
                          for w, r in buckets),
             num_rows=tuple(r - 3 for _, r in buckets), residual=True,
             interpret=False)
     text = _compile(fit, one_chip, *args)
     assert text.count('custom_call_target="tpu_custom_call"') == nb
+    return text
+
+
+@pytest.mark.parametrize("k,buckets", FIT_BUCKETS)
+def test_fused_fit_program_compiles(one_chip, k, buckets):
+    """The whole bucketed fit compiles as one program: one kernel launch
+    per bucket, and each bucket's label gather ``labels[cols]`` compiled
+    exactly once, named by its bucket's scope (XLA does not duplicate it
+    into the plane consumers)."""
+    text = _compile_fit(one_chip, k, buckets, GEEOptions(
+        laplacian=True, diag_aug=True, correlation=True))
     gathers = {}
     for m in re.finditer(r"= s32\[([\d,]*)\]\S* gather\(.*?"
                          r'op_name="[^"]*/bucket(\d+)/', text):
@@ -161,6 +172,16 @@ def test_fused_fit_program_compiles(one_chip, k, buckets):
         gathers.setdefault(int(m.group(2)), []).append(size)
     for i, (w, r) in enumerate(buckets):
         assert gathers[i].count(r * w) == 1, (i, gathers[i])
+
+
+@pytest.mark.parametrize("opts", [GEEOptions(), GEEOptions(laplacian=True)],
+                         ids=lambda o: o.tag())
+@pytest.mark.parametrize("k,buckets", FIT_BUCKETS)
+def test_fit_program_without_epilogue_compiles(one_chip, k, buckets, opts):
+    """The settings with neither diag-aug nor correlation run the same
+    fit program, with the kernel's epilogue compiled out; Mosaic takes
+    that variant too."""
+    _compile_fit(one_chip, k, buckets, opts)
 
 
 @pytest.mark.parametrize("k", [5, 172])
