@@ -26,6 +26,9 @@ The kernel does not consume neighbor ids directly; it consumes *planes*:
 ``ell_planes`` builds them with exactly the label/weight preprocessing of
 ``repro.core.gee.gee_sparse_jax`` (the -1-label convention, the 1/n_k class
 weights), so kernel and segment-sum backends agree to float tolerance.
+Where the label table is small, the fused kernel makes the same planes in
+VMEM from ``cols`` and ``vals`` instead
+(``repro.kernels.gee_fused.gee_lookup_fused_padded``).
 """
 
 from __future__ import annotations

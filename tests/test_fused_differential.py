@@ -331,6 +331,115 @@ def test_pallas_plan_dispatches_one_program_a_fit(opts):
 
 
 # ---------------------------------------------------------------------------
+# the label lookup inside the kernel against XLA's gather
+# ---------------------------------------------------------------------------
+
+def _wide_graph(n=10_333, seed=11):
+    """A ring over all but the last vertex (left at degree 0), 150 rows
+    of degree ~70-120, 36 of ~140-220 and two of ~300: narrow buckets and
+    128-, 256- and 512-wide ones, a wide bucket of more than one (ragged)
+    row block, wide buckets of several degree tiles, and N a multiple of
+    neither 8, 128 nor 1,024."""
+    rng = np.random.default_rng(seed)
+    ring = np.arange(n - 1)
+    hubs = np.repeat(np.arange(188), np.concatenate(
+        [rng.integers(70, 121, 150), rng.integers(140, 221, 36),
+         [300, 310]]))
+    src = np.concatenate([ring, hubs])
+    dst = np.concatenate([(ring + 1) % (n - 1),
+                          rng.integers(0, n - 1, hubs.size)])
+    w = rng.uniform(0.5, 2.0, src.shape[0]).astype(np.float32)
+    return symmetrize(edge_list_from_numpy(src, dst, w, n))
+
+
+@pytest.fixture(scope="module")
+def wide_graph():
+    edges = _wide_graph()
+    return edges, edges_to_bucketed_ell(edges)
+
+
+@pytest.mark.parametrize("labelled", ["some", "all", "beyond-k"])
+@pytest.mark.parametrize("k", [1, 5, 15, 16, 47])
+@pytest.mark.parametrize("opts", ALL_OPTION_SETTINGS, ids=OPT_IDS)
+def test_lookup_route_matches_gather_route(monkeypatch, wide_graph, opts, k,
+                                           labelled):
+    """Buckets whose labels are looked up in the kernel, from the packed
+    VMEM table, give Z bit for bit as when XLA gathers them into planes,
+    on both sides of the 4-bit/8-bit packing boundary (K = 15, 16), with
+    and without unlabelled vertices, and with labels of K or more, which
+    both routes take as unlabelled (no code of theirs spills into a
+    neighbour's field); and both match SciPy."""
+    edges, bell = wide_graph
+    n = edges.num_nodes
+    rng = np.random.default_rng(k)
+    labels = rng.integers(0 if labelled == "all" else -1,
+                          k + 20 if labelled == "beyond-k" else k, n)
+    known = np.where(labels < k, labels, -1)
+    labels = jnp.asarray(labels, jnp.int32)
+    widths = [b.width for b in bell.buckets]
+    assert min(widths) < 128 and {128, 256, 512} <= set(widths)
+    # the scan's loop steps and its remainder both run
+    assert gee_fused.label_table_rows(n, k) % gee_fused._LOOKUP_UNROLL
+    assert gee_fused.label_table_rows(n, k) > gee_fused._LOOKUP_UNROLL
+    sc = scale_buckets(bell, laplacian=opts.laplacian,
+                       diag_aug=opts.diag_aug)
+    assert sc.num_uncovered == 1                     # a degree-0 row
+
+    def fit():
+        reg = MetricsRegistry()
+        prev = set_registry(reg)
+        try:
+            z = gee_fused_from_bucketed(bell, labels, k, opts, scaling=sc,
+                                        interpret=True)
+        finally:
+            set_registry(prev)
+        return np.asarray(z), reg.snapshot()["counters"]
+
+    z_vmem, c_vmem = fit()
+    monkeypatch.setattr(gee_fused, "LOOKUP_MAX_TABLE_ROWS", 0)
+    z_gather, c_gather = fit()
+    wide = sum(w >= 128 for w in widths)
+    assert (c_vmem["plan.fit.lookup.vmem"],
+            c_vmem["plan.fit.lookup.gather"]) == (wide, len(widths) - wide)
+    assert (c_gather["plan.fit.lookup.vmem"],
+            c_gather["plan.fit.lookup.gather"]) == (0, len(widths))
+    assert np.array_equal(z_vmem, z_gather), opts.tag()
+    np.testing.assert_allclose(
+        z_vmem, _scipy_ref(edges, known, k, opts), atol=1e-5,
+        err_msg=opts.tag())
+
+
+@pytest.mark.parametrize("n,k,block_deg,routes", [
+    (92_482, 5, (8, 128, 128), (False, True, True)),   # cl-100k-1d8-l5
+    (2_449_029, 47, (64, 128), (False, False)),        # ogbn-products
+    (92_482, 256, (128,), (False,)),                   # K too large to pack
+])
+def test_lookup_route_chosen_from_n_and_k(n, k, block_deg, routes):
+    """``cl-100k-1d8-l5`` (T = 91 rows) looks its labels up in the kernel
+    for every bucket of whole 128-lane degree blocks; ``ogbn-products``
+    (T = 4,784) and a K beyond 8-bit codes keep XLA's gather."""
+    assert gee_fused.lookup_routes(n, k, block_deg) == routes
+    assert gee_fused.label_table_rows(92_482, 5) == 91
+    assert gee_fused.label_table_rows(2_449_029, 47) == 4_784
+
+
+@pytest.mark.parametrize("k", [1, 15, 16, 255])
+def test_label_table_packs_codes(k):
+    """Each vertex's code ``y + 1`` (0 = unlabelled) sits at the row,
+    lane and field the kernel reads."""
+    n = 3_001
+    labels = np.random.default_rng(k).integers(-1, k, n).astype(np.int32)
+    table = np.asarray(gee_fused.label_table(jnp.asarray(labels), k))
+    bits = gee_fused.label_code_bits(k)
+    per_word = 32 // bits
+    assert table.shape == (gee_fused.label_table_rows(n, k), 128)
+    v = np.arange(n)
+    words = table.view(np.uint32)[v // (128 * per_word), v % 128]
+    codes = (words >> ((v // 128) % per_word * bits)) & ((1 << bits) - 1)
+    np.testing.assert_array_equal(codes, labels + 1)
+
+
+# ---------------------------------------------------------------------------
 # raw kernel contract: tile boundaries, padding lanes, ragged tails
 # ---------------------------------------------------------------------------
 

@@ -518,9 +518,11 @@ def test_bucket_spans_carry_packing_counts(fresh_obs):
                     "plan.bucket.launch", "plan.bucket.scatter",
                     "plan.bucket.residual")
     (fit,) = [e for e in events if e.name == "plan.fit"]
+    # every bucket of this graph is narrower than a vreg: XLA gathers
     assert fit.args == {"buckets": len(bell.buckets),
                         "rows": pack.args["rows"],
-                        "slots": bell.total_slots, "edges": real}
+                        "slots": bell.total_slots, "edges": real,
+                        "lookup": "gather"}
     assert bell.total_edges == real
     assert not [e for e in events if e.name in bucket_spans]
     # the one-time build: degrees once, one scale span per bucket, both
@@ -549,6 +551,47 @@ def test_bucket_spans_carry_packing_counts(fresh_obs):
     assert stage.args["cached"] is True
     assert names.count("plan.fit") == 1
     assert not set(names) & set(bucket_spans)
+
+
+def _lookup_graph(clique: int, path: int):
+    """A clique of ``clique`` vertices (every row in one 128-lane-or-wider
+    bucket when ``clique`` > 65) and a path of ``path`` more (narrow
+    rows)."""
+    from repro.graph.containers import edge_list_from_numpy, symmetrize
+
+    a, b = np.triu_indices(clique, 1)
+    p = np.arange(clique, clique + path - 1)
+    src = np.concatenate([a, p])
+    dst = np.concatenate([b, p + 1])
+    n = clique + path
+    return symmetrize(edge_list_from_numpy(src, dst, None, n)), n
+
+
+@pytest.mark.pallas_interpret
+@pytest.mark.parametrize("clique,path,route", [
+    (130, 0, "vmem"), (130, 40, "mixed"), (0, 60, "gather")])
+def test_fit_span_tags_label_lookup_route(fresh_obs, clique, path, route):
+    """``plan.fit`` is tagged with the buckets' label ``lookup`` route,
+    and ``plan.fit.lookup.vmem`` / ``plan.fit.lookup.gather`` move by the
+    buckets that take each route, once a fit."""
+    tracer, reg = fresh_obs
+    tracer.enable()
+    edges, n = _lookup_graph(clique, path)
+    labels = (np.arange(n) % 3).astype(np.int32)
+    prep = PreparedGraph.wrap(edges)
+    plan = GEEPlan.build(prep, 3, OPTS_ALL, backend="pallas")
+    widths = [b.width for b in prep.bucketed_ell(False).buckets]
+    wide = sum(w >= 128 for w in widths)
+    for fit in (1, 2):
+        z = plan.execute(labels)
+        counters = reg.snapshot()["counters"]
+        assert counters["plan.fit.lookup.vmem"] == fit * wide
+        assert counters["plan.fit.lookup.gather"] \
+            == fit * (len(widths) - wide)
+    fits = [e for e in tracer.events() if e.name == "plan.fit"]
+    assert [e.args["lookup"] for e in fits] == [route, route]
+    z_ref = gee(prep, labels, 3, OPTS_ALL, backend="sparse_jax")
+    np.testing.assert_allclose(np.asarray(z), np.asarray(z_ref), atol=1e-5)
 
 
 @pytest.mark.pallas_interpret
