@@ -18,8 +18,12 @@ def readings(config: dict, seed: int, fits: int, precision: str) -> list:
     s, d = graphgen.relabel(s, d, n, seed)
     ref = Reference(np.concatenate([s, d]), np.concatenate([d, s]), n,
                     config["options"])
+    del s, d
+    blocks = ref.blocks(k)
     out = []
     for i in range(fits):
         y = graphgen.draw_labels(n, k, config["labelled"], seed, i)
-        out.append(check.gaps(ref.embed(y, k, precision), ref.embed(y, k)))
+        out.append(check.block_gaps(
+            lambda lo, hi: ref.embed_rows(y, k, lo, hi, precision),
+            lambda lo, hi: ref.embed_rows(y, k, lo, hi), blocks))
     return out

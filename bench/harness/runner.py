@@ -162,14 +162,17 @@ def _run(cell, mix, devices, seed, seconds, trace, t_start, jax,
     mix.release_program_state()
     t_ref = time.perf_counter()
     ref = mix.reference()
+    blocks = ref.blocks(mix.k)
     readings = []
     for i, z in answers:
-        readings.append(check.gaps(z, ref.embed(mix.labels(i), mix.k)))
+        y = mix.labels(i)
+        readings.append(check.answer_gaps(
+            z, lambda lo, hi: ref.embed_rows(y, mix.k, lo, hi), blocks))
     del ref
     correct, failed, shown = check.verdict(readings, cell.limits)
     log(checked_fits=[i for i, _ in answers], readings=readings,
         reference_s=time.perf_counter() - t_ref,
-        window_compiles=window_compiles)
+        reference_blocks=len(blocks), window_compiles=window_compiles)
 
     out = {"correct": correct, "attempted": fits, "failed": failed}
     if trace:

@@ -15,12 +15,15 @@ benchmark's own ``bench.*`` spans and the program's ``plan.*`` and
 
 ``reduce_trace`` then gives, inside the window the ``bench.fit`` spans
 cover: each device's busy time (the union of its op intervals), its idle
-gaps named by the innermost host span open at the gap's middle, its time
-in collective ops, and the ops that took the most time.
+gaps named by the innermost host span open at the gap's middle, the idle
+time under each host span (every gap cut at the host spans' edges, each
+piece given to the innermost span open over it), its time in collective
+ops, and the ops that took the most time.
 """
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import glob
 import os
@@ -93,6 +96,10 @@ class Reduced:
     collective_s: list      # per device
     gaps: list              # [(span name, seconds)], all devices
     op_seconds: dict        # op name -> seconds, mean over devices
+    # span name -> idle seconds under it as the innermost open span, summed
+    # over devices; every host span name in the window has an entry, 0.0
+    # where the devices were never idle under it
+    idle_under: dict = dataclasses.field(default_factory=dict)
 
     @property
     def mean_busy_s(self) -> float:
@@ -114,6 +121,38 @@ def _span_at(host: list, t: float) -> str:
     return best
 
 
+def _innermost_segments(host: list):
+    """The host spans' edges, sorted, and the innermost span open between
+    each edge and the next (``"none"`` where none is), as ``_span_at``
+    names it."""
+    edges = sorted({t for _, start, dur in host for t in (start, start + dur)})
+    index = {t: i for i, t in enumerate(edges)}
+    opens: list = [[] for _ in edges]
+    closes: list = [[] for _ in edges]
+    for order, (name, start, dur) in enumerate(host):
+        if dur <= 0:
+            continue
+        opens[index[start]].append((dur, order, name))
+        closes[index[start + dur]].append((dur, order, name))
+    names, open_now = [], set()
+    for i in range(len(edges) - 1):
+        open_now.difference_update(closes[i])
+        open_now.update(opens[i])
+        names.append(min(open_now)[2] if open_now else "none")
+    return edges, names
+
+
+def _idle_under(edges: list, names: list, g0: float, g1: float, out: dict):
+    """Add the idle interval [g0, g1] (ns) to ``out`` in seconds, cut at
+    the span edges inside it."""
+    cuts = [g0] + edges[bisect.bisect_right(edges, g0):
+                        bisect.bisect_left(edges, g1)] + [g1]
+    for a, b in zip(cuts, cuts[1:]):
+        i = bisect.bisect_right(edges, (a + b) / 2) - 1
+        name = names[i] if 0 <= i < len(names) else "none"
+        out[name] = out.get(name, 0.0) + (b - a) / 1e9
+
+
 def reduce_trace(trace: dict) -> Reduced:
     fits = [h for h in trace["host"] if h[0] == FIT_SPAN]
     if not fits:
@@ -122,6 +161,9 @@ def reduce_trace(trace: dict) -> Reduced:
     hi = max(f[1] + f[2] for f in fits)
     busy, coll, gaps = [], [], []
     op_ns: dict = {}
+    span_edges, span_names = _innermost_segments(trace["host"])
+    under = {name: 0.0 for name, start, dur in trace["host"]
+             if start < hi and start + dur > lo}
     for dev in trace["devices"]:
         clipped = []
         c = 0.0
@@ -141,7 +183,9 @@ def reduce_trace(trace: dict) -> Reduced:
             if g1 > g0:
                 gaps.append((_span_at(trace["host"], (g0 + g1) / 2),
                              (g1 - g0) / 1e9))
+                _idle_under(span_edges, span_names, g0, g1, under)
     ndev = max(len(trace["devices"]), 1)
     return Reduced(window_s=(hi - lo) / 1e9, fits=len(fits), busy_s=busy,
                    collective_s=coll, gaps=gaps,
-                   op_seconds={k: v / ndev / 1e9 for k, v in op_ns.items()})
+                   op_seconds={k: v / ndev / 1e9 for k, v in op_ns.items()},
+                   idle_under=under)

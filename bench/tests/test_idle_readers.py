@@ -71,6 +71,18 @@ def test_lost_program_spans_read_as_missing(name):
     assert _read(name, dict(HAND, host=host)) is None
 
 
+@pytest.mark.parametrize("shift_ms", [0, 3, 55])
+def test_idle_under_spans_adds_up_to_the_gaps(shift_ms):
+    """Cutting the gaps at the host spans' edges loses no idle time,
+    also where every program span is moved off the gaps' edges."""
+    host = [[n, s + (0 if n.startswith("bench.") else shift_ms * MS), d]
+            for n, s, d in HAND["host"]]
+    r = tracing.reduce_trace(dict(HAND, host=host))
+    assert sum(r.idle_under.values()) == pytest.approx(
+        sum(s for _, s in r.gaps))
+    assert set(r.idle_under) >= {"bench.fit", "plan.bucket"}
+
+
 def test_readers_are_listed_for_their_cells():
     refit = {m["name"] for m in spec.load_cell("cl100k-l5.refit").per_layer}
     stream = {m["name"] for m in spec.load_cell("cl100k-l5.stream").per_layer}

@@ -17,6 +17,23 @@ def test_label_idle_reads_gaps_under_the_label_span_only():
     assert _read("dispatch_idle_ms.fit", trace) == pytest.approx(26 / 4)
 
 
+def test_label_idle_takes_its_part_of_a_gap_under_other_spans():
+    """A ``plan.labels`` span at 55..65 ms covers the first 5 ms of chip
+    0's 60..120 ms gap, whose middle lies under ``bench.fit``: the label
+    step reads those 5 ms, and the plan total grows by them."""
+    host = HAND["host"] + [["plan.labels", 55 * MS, 10 * MS]]
+    trace = dict(HAND, host=host)
+    assert _read("label_idle_ms.fit", trace) == pytest.approx(5 / 4)
+    assert _read("dispatch_idle_ms.fit", trace) == pytest.approx(31 / 4)
+
+
+def test_label_idle_is_zero_when_the_chips_are_busy_under_the_span():
+    """A ``plan.labels`` span at 0..10 ms, where both chips are busy, is
+    a reading of 0 idle ms, not a missing one."""
+    host = HAND["host"] + [["plan.labels", 0 * MS, 10 * MS]]
+    assert _read("label_idle_ms.fit", dict(HAND, host=host)) == 0.0
+
+
 @pytest.mark.parametrize("keep", ["plan", "bench"])
 def test_label_idle_is_missing_without_the_label_span(keep):
     """A program without the span gives no number, rather than 0: with
